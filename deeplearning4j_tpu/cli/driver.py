@@ -323,7 +323,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    from deeplearning4j_tpu.utils.compile_cache import ensure_compile_cache
+
     args = build_parser().parse_args(argv)
+    ensure_compile_cache()
     return args.func(args)
 
 

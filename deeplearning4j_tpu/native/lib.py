@@ -6,6 +6,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 from typing import Optional, Tuple
 
 import numpy as np
@@ -84,7 +85,9 @@ def _get_lib() -> Optional[ctypes.CDLL]:
                     ["make", "-C", _NATIVE_DIR],
                     check=True, capture_output=True, timeout=120,
                 )
-            except (subprocess.SubprocessError, OSError):
+            except (subprocess.SubprocessError, OSError) as exc:
+                warnings.warn(f"native library build failed ({exc}); using "
+                              "the pure-python fallbacks", RuntimeWarning)
                 return None
         if not os.path.exists(_SO_PATH):
             return None

@@ -223,7 +223,7 @@ def make_train_epoch(conf: MultiLayerConfiguration, n_steps: int,
     xs: (n_steps, batch, features), ys: (n_steps, batch, classes). Keeps the
     loop on the TPU — one dispatch per epoch chunk instead of per step, which
     matters when host→device dispatch latency rivals step compute (small
-    models, remote-tunnel setups). The per-step RNG key is folded from the
+    models). The per-step RNG key is folded from the
     step index, matching make_train_step semantics.
     """
     step = _raw_train_step(conf, policy)

@@ -90,8 +90,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from deeplearning4j_tpu.compat import shard_map
-
 Array = jax.Array
 
 EXPERT_AXIS = "expert"
@@ -386,7 +384,7 @@ def moe_apply(router_w: Array, expert_params, x: Array, mesh: Mesh,
             return _dispatch_replicated(params, rw, xs, capacity, axis,
                                         expert_fn, top_k, group)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_spec, P(), tok_spec), out_specs=tok_spec,
         check_vma=False,

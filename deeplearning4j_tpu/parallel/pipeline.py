@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.compat import shard_map
-
 Array = jax.Array
 
 PIPE_AXIS = "pipe"
@@ -159,7 +157,7 @@ def pipeline_apply(stage_params, x_mbs: Array, stage_fn: Callable,
         local = jax.tree_util.tree_map(lambda a: a[0], params)
         return _pipeline_body(local, x, stage_fn, axis, overlap=overlap)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_spec, x_spec), out_specs=x_spec,
         check_vma=False,

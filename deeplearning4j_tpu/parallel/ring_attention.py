@@ -35,8 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.compat import shard_map
-
 Array = jax.Array
 
 _NEG_INF = -1e30
@@ -200,7 +198,7 @@ def ring_attention(q: Array, k: Array, v: Array, mesh: Mesh, axis: str,
     spec = P(batch_axis, None, axis, None)
     fn = partial(_ring_attention_sharded, axis_name=axis, causal=causal,
                  impl=impl, prefetch=prefetch)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )
@@ -249,7 +247,7 @@ def ulysses_attention(q: Array, k: Array, v: Array, mesh: Mesh, axis: str,
     spec = P(None, None, axis, None)
     fn = partial(_ulysses_sharded, axis_name=axis, causal=causal,
                  impl=attn_impl)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )

@@ -33,8 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.compat import shard_map
-
 from deeplearning4j_tpu.datasets.iterator import DataSetIterator
 from deeplearning4j_tpu.nn import functional as F
 from deeplearning4j_tpu.nn.conf import MultiLayerConfiguration
@@ -292,7 +290,7 @@ def make_sync_train_step(conf: MultiLayerConfiguration, mesh: Mesh,
     out_specs = ((P(), state_spec, P(), P())
                  if (with_metrics or guard is not None)
                  else (P(), state_spec, P()))
-    sharded = shard_map(
+    sharded = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P(), state_spec, P(), P(DATA_AXIS), P(DATA_AXIS),
@@ -337,7 +335,7 @@ def make_local_fit_step(conf: MultiLayerConfiguration, mesh: Mesh,
                                    (params, states, scores[-1])), DATA_AXIS)
         return params, states, score
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_fit,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS), P()),

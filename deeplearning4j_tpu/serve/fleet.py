@@ -349,10 +349,12 @@ def replica_main(argv=None) -> int:
     ``FLEET_REPLICA_READY <id>`` once registered — spawners block on it.
     """
     from deeplearning4j_tpu.scaleout.remote_tracker import TrackerUnavailable
+    from deeplearning4j_tpu.utils.compile_cache import ensure_compile_cache
 
     args = build_parser().parse_args(argv)
     if (args.synthetic is None) == (args.checkpoint is None):
         raise SystemExit("exactly one of --synthetic / --checkpoint")
+    ensure_compile_cache()
     if args.synthetic is not None:
         engine = _build_synthetic_engine(args.synthetic, args.seed, args)
     else:

@@ -63,7 +63,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     from deeplearning4j_tpu.tune.cache import TuningCache
     from deeplearning4j_tpu.tune.search import search
     from deeplearning4j_tpu.tune.space import get_space
+    from deeplearning4j_tpu.utils.compile_cache import ensure_compile_cache
 
+    ensure_compile_cache()
     cache = TuningCache(args.cache) if (args.store or args.cache) else None
     summaries = []
     for name in (args.seam or list(_SEAMS)):

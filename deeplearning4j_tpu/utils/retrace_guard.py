@@ -6,10 +6,10 @@ size, a donated-buffer layout flip — and suddenly every training step
 pays an XLA compile. On a fast chip that turns a 2 ms step into seconds
 without any error. This module counts real XLA backend compilations via
 ``jax.monitoring`` (the ``/jax/core/compile/backend_compile_duration``
-event fires exactly once per backend compile; older jaxlibs fall back to
-the ``/jax/compilation_cache/compile_requests_use_cache`` event, and as a
-last resort to ``jax_log_compiles`` log capture) and raises when a guarded
-region compiles more than its budget.
+event wraps the whole compile-or-fetch, so it fires exactly once per program
+a process asks XLA for, also when the persistent compilation cache serves it:
+a retrace that hits that cache still counts) and raises when a guarded region
+compiles more than its budget.
 
 Usage (context manager)::
 
@@ -24,7 +24,7 @@ pytest fixture; tests/test_retrace_guard.py pins compile budgets for the
 composed LM / pipeline / DP-sync steps.
 
 ISSUE 9: the guard also records the ABSTRACT SIGNATURE of each compile —
-the ``Compiling <fn> with global shapes and types [ShapedArray(...)]``
+the ``Compiling <fn> with global shapes and types (ShapedArray(...), ...)``
 line jax's pjit lowering logs carries exactly the shapes/dtypes/weak-types
 that keyed the cache miss. A logging filter on that logger captures the
 signatures into a bounded ring (and swallows the log record, so there is
@@ -49,11 +49,10 @@ class RetraceBudgetExceeded(AssertionError):
 
 _lock = threading.Lock()
 _counter = {"n": 0}
-_installed = {"mode": None}
+_installed = {"done": False}
 
 # one real XLA compile -> exactly one of these fires
 _DURATION_EVENT_SUFFIX = "backend_compile_duration"
-_CACHE_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 
 
 def _on_duration(name: str, secs: float, **kw) -> None:
@@ -62,28 +61,13 @@ def _on_duration(name: str, secs: float, **kw) -> None:
             _counter["n"] += 1
 
 
-def _on_event(name: str, **kw) -> None:
-    if name == _CACHE_EVENT:
-        with _lock:
-            _counter["n"] += 1
-
-
-class _LogCompilesHandler(logging.Handler):
-    """jax_log_compiles capture — last-resort counter for jaxlibs whose
-    monitoring module predates the compile events."""
-
-    def emit(self, record: logging.LogRecord) -> None:
-        if "Compiling" in record.getMessage():
-            with _lock:
-                _counter["n"] += 1
-
-
 # ------------------------------------------------- compile signatures ----
 
 # pjit's per-compile log line (fires at DEBUG even with jax_log_compiles
 # off, so capturing it costs no stderr noise)
 _COMPILING_RE = re.compile(
-    r"Compiling ([^\s]+) with global shapes and types \[(.*)\]\."
+    r"Compiling ([^\s]+) with global shapes and types \((.*)\)\. "
+    r"Argument mapping"
 )
 _PXLA_LOGGER = "jax._src.interpreters.pxla"
 _SIG_RING_MAX = 64
@@ -92,12 +76,8 @@ _sig_seq = {"n": 0}
 
 
 class _CompileSignatureFilter(logging.Filter):
-    """Records each compile's (fn name, abstract signature) into the ring.
-
-    Returns False for the matched records when the compile COUNTER does
-    not depend on them (duration/event modes) — captured, not printed;
-    in the last-resort 'log' counter mode the record must keep flowing to
-    the counting handler, so it passes through."""
+    """Records each compile's (fn name, abstract signature) into the ring
+    and swallows the matched record: captured, not printed."""
 
     def filter(self, record: logging.LogRecord) -> bool:
         m = _COMPILING_RE.search(record.getMessage())
@@ -108,8 +88,7 @@ class _CompileSignatureFilter(logging.Filter):
             _sig_ring.append({"seq": _sig_seq["n"], "name": m.group(1),
                               "signature": m.group(2)})
             del _sig_ring[:-_SIG_RING_MAX]
-            suppress = _installed["mode"] != "log"
-        return not suppress
+        return False
 
 
 def recent_compiles(since_seq: int = 0) -> list:
@@ -137,38 +116,21 @@ def signature_diff(prev: str, cur: str) -> str:
     return "; ".join(changes) if changes else "signatures identical"
 
 
-def _install() -> str:
-    """Register the process-wide compile listener once; returns the mode
-    actually installed ('duration' | 'event' | 'log')."""
-    with _lock:
-        if _installed["mode"] is not None:
-            return _installed["mode"]
+def _install() -> None:
+    """Register the process-wide compile listener once."""
     import jax
 
-    mode = None
-    mon = getattr(jax, "monitoring", None)
-    if mon is not None and hasattr(mon, "register_event_duration_secs_listener"):
-        mon.register_event_duration_secs_listener(_on_duration)
-        mode = "duration"
-    elif mon is not None and hasattr(mon, "register_event_listener"):
-        mon.register_event_listener(_on_event)
-        mode = "event"
-    else:
-        jax.config.update("jax_log_compiles", True)
-        handler = _LogCompilesHandler()
-        for logger_name in ("jax._src.dispatch",
-                            "jax._src.interpreters.pxla"):
-            logging.getLogger(logger_name).addHandler(handler)
-        mode = "log"
-    # signature recorder (ISSUE 9): pjit logs its per-compile abstract
-    # signature at DEBUG; enable that level on just this logger and let
-    # the filter capture (and, outside 'log' mode, swallow) the records
-    pxla_logger = logging.getLogger(_PXLA_LOGGER)
-    pxla_logger.setLevel(logging.DEBUG)
-    pxla_logger.addFilter(_CompileSignatureFilter())
     with _lock:
-        _installed["mode"] = mode
-    return mode
+        if _installed["done"]:
+            return
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        # signature recorder (ISSUE 9): pjit logs its per-compile abstract
+        # signature at DEBUG; enable that level on just this logger and let
+        # the filter capture and swallow the records
+        pxla_logger = logging.getLogger(_PXLA_LOGGER)
+        pxla_logger.setLevel(logging.DEBUG)
+        pxla_logger.addFilter(_CompileSignatureFilter())
+        _installed["done"] = True
 
 
 def compiles_so_far() -> int:
@@ -208,7 +170,7 @@ class retrace_guard:
     def _signature_report(self) -> str:
         """What recompiled in this region + the diff vs each program's
         previous compile (ISSUE 9) — empty when the pjit log line was not
-        observed (ancient jaxlib, non-pjit compile paths)."""
+        observed (non-pjit compile paths)."""
         if not self.compiled:
             return ""
         lines = ["", "compiled in this region:"]

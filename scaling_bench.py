@@ -66,9 +66,7 @@ def _child_main(n: int, batch: int, mode: str, warmup: int = WARMUP,
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    from deeplearning4j_tpu.compat import set_host_device_count
-
-    set_host_device_count(n)
+    jax.config.update("jax_num_cpu_devices", n)
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.models.zoo import mnist_mlp

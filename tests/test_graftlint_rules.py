@@ -248,16 +248,6 @@ def test_env_read_clean_blessed():
     assert "env-read-in-trace" not in _rules_hit(src)
 
 
-def test_env_read_clean_in_compat():
-    src = """
-    import os
-
-    def bridge():
-        return os.environ.get("ANYTHING_GOES")
-    """
-    assert "env-read-in-trace" not in _rules_hit(src, path="compat.py")
-
-
 # ------------------------------------------------------------ missing-donate ----
 
 def test_missing_donate_bad():

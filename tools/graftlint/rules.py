@@ -398,13 +398,12 @@ def nondet_pytree(ctx: ModuleContext) -> Iterable[Finding]:
 # -------------------------------------------------------- env-read-in-trace ----
 
 _BLESSED_ENV_PREFIX = "DL4J_TPU_"
-_BLESSED_FILES = ("compat.py",)
 
 
 @register("env-read-in-trace")
 def env_read(ctx: ModuleContext) -> Iterable[Finding]:
-    """os.environ/os.getenv reads outside the blessed seams (compat.py, or
-    keys under the documented ``DL4J_TPU_*`` namespace — currently
+    """os.environ/os.getenv reads outside the blessed seam (keys under
+    the documented ``DL4J_TPU_*`` namespace — currently
     ``DL4J_TPU_ATTN_IMPL`` (ops/flash_attention.py attention-core chain),
     ``DL4J_TPU_MOE_IMPL`` (parallel/moe.py dispatch chain:
     alltoall | alltoall_2d | replicated),
@@ -418,8 +417,6 @@ def env_read(ctx: ModuleContext) -> Iterable[Finding]:
     trace/resolve time, never inside a traced body). Ad-hoc env reads are invisible config:
     they fork behavior between hosts and leak into traced code paths
     where a retrace won't see the change."""
-    if ctx.path.replace("\\", "/").rsplit("/", 1)[-1] in _BLESSED_FILES:
-        return []
     out: List[Finding] = []
 
     def blessed(key_node) -> bool:
@@ -447,8 +444,8 @@ def env_read(ctx: ModuleContext) -> Iterable[Finding]:
         if hit and not blessed(key_node):
             out.append(_finding(
                 ctx, "env-read-in-trace", node,
-                f"environment read ({hit}) outside the blessed seams",
-                "route through compat.py or a DL4J_TPU_*-namespaced knob; "
+                f"environment read ({hit}) outside the blessed seam",
+                "route through a DL4J_TPU_*-namespaced knob; "
                 "if this seam is deliberate, baseline it with a why"))
     return out
 
