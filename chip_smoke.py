@@ -18,7 +18,10 @@ user calls, at the flagship's full width (depth as the benchmark cuts it):
 It needs a TPU: with any other platform it exits non-zero before it builds
 anything, and it has no switch that admits the CPU. A failed check raises,
 which is a non-zero exit; only a run in which every phase passed prints the
-closing JSON line. Wall times are set-up information (they include
+two closing lines: ``summary: {...}`` (per-phase detail, also written to
+``chiprun_out/chip_smoke/summary.json``, ending in ``"claim": null``) and,
+last, ``{"ok": true, "device": {"platform", "kind", "count"}}`` with those
+keys and no others. Wall times are set-up information (they include
 compilation); nothing here is a rate or a utilization.
 """
 
@@ -471,6 +474,16 @@ def _versions() -> dict:
     return out
 
 
+def verdict_line(device: dict) -> str:
+    """The last line of standard output: these two keys and no others, the
+    device as JAX reports it. Whoever checks the run parses this line alone;
+    the per-phase detail goes on the ``summary:`` line before it."""
+    return json.dumps({"ok": True,
+                       "device": {"platform": str(device["platform"]),
+                                  "kind": str(device["kind"]),
+                                  "count": int(device["count"])}})
+
+
 def main() -> int:
     t_start = time.monotonic()
     import jax
@@ -562,7 +575,8 @@ def main() -> int:
               encoding="utf-8") as f:
         json.dump(summary, f, indent=1)
     faulthandler.cancel_dump_traceback_later()
-    print(json.dumps(summary))
+    print("summary: " + json.dumps(summary))
+    print(verdict_line(device), flush=True)
     return 0
 
 

@@ -1,6 +1,7 @@
 """chip_smoke.py on the CPU: its device gate refuses, and its phases pass at
 toy widths when driven as functions (the chip runs them at the flagship's)."""
 
+import json
 import os
 import sys
 
@@ -21,6 +22,14 @@ def test_device_gate_refuses_the_cpu(capsys):
     said = capsys.readouterr()
     assert "'cpu'" in said.err
     assert said.out == ""  # no phase ran, no summary line
+
+
+def test_verdict_line_holds_the_two_keys_and_no_others():
+    line = chip_smoke.verdict_line(
+        {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    assert "\n" not in line
+    assert json.loads(line) == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
 
 
 @pytest.fixture(scope="module")
