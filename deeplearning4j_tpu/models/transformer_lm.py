@@ -65,6 +65,17 @@ Array = jax.Array
 DATA_AXIS = "data"
 SEQ_AXIS = "sp"
 
+# The named scopes of the shared blocks: every flagship program (train,
+# prefill, decode, verify, chunk) carries them in the ``op_name`` of its
+# compiled instructions, which is where a device trace is mapped back to
+# the model (benchmark/trace/reduce.py). No other scope in the package
+# begins with ``lm_``, so that needle means "under any of them". A scope
+# is metadata: outputs, instruction numbering and the compile-cache key do
+# not change with it, so an executable cached before a scope existed comes
+# back without it.
+LM_SCOPES = ("lm_embed", "lm_attn", "lm_cache_write", "lm_moe", "lm_loss",
+             "lm_update", "lm_sample")
+
 
 def _init_block(key: Array, d_model: int, n_heads: int, n_experts: int,
                 d_ff: int) -> dict:
@@ -137,11 +148,12 @@ def dense_moe(router_w: Array, experts: dict, x: Array,
 
 
 def _attn_block(params: dict, h: Array, n_heads: int, attn_core) -> Array:
-    hn = _layernorm(h, params["ln_g"], params["ln_b"])
-    q = _split_heads(hn @ params["wq"], n_heads)
-    k = _split_heads(hn @ params["wk"], n_heads)
-    v = _split_heads(hn @ params["wv"], n_heads)
-    return h + _merge_heads(attn_core(q, k, v)) @ params["wo"]
+    with jax.named_scope("lm_attn"):
+        hn = _layernorm(h, params["ln_g"], params["ln_b"])
+        q = _split_heads(hn @ params["wq"], n_heads)
+        k = _split_heads(hn @ params["wk"], n_heads)
+        v = _split_heads(hn @ params["wv"], n_heads)
+        return h + _merge_heads(attn_core(q, k, v)) @ params["wo"]
 
 
 def _decoder_block(layer_params: dict, h: Array, n_heads: int, attn_core,
@@ -149,10 +161,27 @@ def _decoder_block(layer_params: dict, h: Array, n_heads: int, attn_core,
     """One decoder block on (B, T, d) → (h, moe_in) with moe_in the
     (B·T, d) pre-MoE activations (the load-balance aux input)."""
     h = _attn_block(layer_params, h, n_heads, attn_core)
-    h2 = _layernorm(h, layer_params["ln2_g"], layer_params["ln2_b"])
-    flat = h2.reshape(-1, h2.shape[-1])
-    moe_out = moe_fn(layer_params["router"], layer_params["experts"], flat)
-    return h + moe_out.reshape(h.shape), flat
+    # at the call site, so that the scope is entered once and the mesh
+    # path's moe_apply lies inside it with its moe_all2all_* scopes
+    with jax.named_scope("lm_moe"):
+        h2 = _layernorm(h, layer_params["ln2_g"], layer_params["ln2_b"])
+        flat = h2.reshape(-1, h2.shape[-1])
+        moe_out = moe_fn(layer_params["router"], layer_params["experts"],
+                         flat)
+        return h + moe_out.reshape(h.shape), flat
+
+
+def _lm_hidden(params: dict, tokens: Array, n_heads: int, attn_core,
+               moe_fn) -> tuple:
+    """``lm_forward`` up to the decoder: (h (B, T, d), moe_in)."""
+    with jax.named_scope("lm_embed"):
+        h = params["embed"][tokens]  # (B, T, d)
+
+    def step(h, layer_params):
+        h, flat = _decoder_block(layer_params, h, n_heads, attn_core, moe_fn)
+        return h, flat
+
+    return jax.lax.scan(step, h, params["blocks"])
 
 
 def lm_forward(params: dict, tokens: Array, n_heads: int, attn_core,
@@ -165,27 +194,30 @@ def lm_forward(params: dict, tokens: Array, n_heads: int, attn_core,
     as ONE ``lax.scan`` over the stacked per-layer params — compile time
     stays O(1) in depth and the per-layer collectives (ring ppermute, MoE
     psum) trace once."""
-    h = params["embed"][tokens]  # (B, T, d)
-
-    def step(h, layer_params):
-        h, flat = _decoder_block(layer_params, h, n_heads, attn_core, moe_fn)
-        return h, flat
-
-    h, moe_ins = jax.lax.scan(step, h, params["blocks"])
+    h, moe_ins = _lm_hidden(params, tokens, n_heads, attn_core, moe_fn)
     return h @ params["dec_w"] + params["dec_b"], moe_ins
+
+
+def _lm_loss_terms(params: dict, h: Array, moe_ins: Array, targets: Array,
+                   aux_weight: float) -> tuple:
+    """The decoder matmul to V, the NLL and the load-balance aux term, all
+    under ``lm_loss``: (loss, task, aux)."""
+    with jax.named_scope("lm_loss"):
+        logits = h @ params["dec_w"] + params["dec_b"]
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        task = jnp.mean(nll)
+        aux = jnp.mean(jax.vmap(load_balance_loss)(
+            params["blocks"]["router"], moe_ins))
+        return task + aux_weight * aux, task, aux
 
 
 def lm_loss(params: dict, tokens: Array, targets: Array, n_heads: int,
             attn_core, moe_fn, aux_weight: float = 1e-2) -> Array:
     """Next-token softmax cross-entropy + the Switch load-balance aux
     (averaged over layers, so the weight is depth-independent)."""
-    logits, moe_ins = lm_forward(params, tokens, n_heads, attn_core, moe_fn)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    task = jnp.mean(nll)
-    aux = jnp.mean(jax.vmap(load_balance_loss)(params["blocks"]["router"],
-                                               moe_ins))
-    return task + aux_weight * aux
+    h, moe_ins = _lm_hidden(params, tokens, n_heads, attn_core, moe_fn)
+    return _lm_loss_terms(params, h, moe_ins, targets, aux_weight)[0]
 
 
 def lm_loss_and_metrics(params: dict, tokens: Array, targets: Array,
@@ -201,13 +233,8 @@ def lm_loss_and_metrics(params: dict, tokens: Array, targets: Array,
     router-load fraction (mean over layers; sums to 1 per step), and — when
     the builder passes ``moe_drop_fn(router_w, moe_in)`` (the composed
     capacity paths do) — the capacity-overflow share ``moe_dropped_frac``."""
-    logits, moe_ins = lm_forward(params, tokens, n_heads, attn_core, moe_fn)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    task = jnp.mean(nll)
-    aux = jnp.mean(jax.vmap(load_balance_loss)(params["blocks"]["router"],
-                                               moe_ins))
-    loss = task + aux_weight * aux
+    h, moe_ins = _lm_hidden(params, tokens, n_heads, attn_core, moe_fn)
+    loss, task, aux = _lm_loss_terms(params, h, moe_ins, targets, aux_weight)
     load = jnp.mean(
         jax.vmap(lambda rw, xin: router_load_fraction(rw, xin, top_k))(
             params["blocks"]["router"], moe_ins), axis=0)  # (E,)
@@ -464,14 +491,15 @@ def _make_opt_step(loss_fn, lr: float, with_metrics: bool, optimizer,
         def step(params, opt_state, tokens, targets):
             loss, grads = jax.value_and_grad(loss_fn)(params, tokens,
                                                       targets)
-            if guard is None:
-                new_params, new_state = opt_update(
-                    optimizer, params, grads, opt_state, lr, zero=zero)
-                return new_params, new_state, loss
-            new_params, new_state, gm = guarded_opt_update(
-                params, grads, opt_state, loss, lr, optimizer, guard,
-                zero=zero)
-            return new_params, new_state, loss, gm
+            with jax.named_scope("lm_update"):
+                if guard is None:
+                    new_params, new_state = opt_update(
+                        optimizer, params, grads, opt_state, lr, zero=zero)
+                    return new_params, new_state, loss
+                new_params, new_state, gm = guarded_opt_update(
+                    params, grads, opt_state, loss, lr, optimizer, guard,
+                    zero=zero)
+                return new_params, new_state, loss, gm
 
         return _seam(step)
 
@@ -481,14 +509,15 @@ def _make_opt_step(loss_fn, lr: float, with_metrics: bool, optimizer,
     def step(params, opt_state, tokens, targets):
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, tokens, targets)
-        if guard is None:
-            new_params, new_state, om = opt_update(
-                optimizer, params, grads, opt_state, lr, zero=zero,
-                with_metrics=True)
-        else:
-            new_params, new_state, om = guarded_opt_update(
-                params, grads, opt_state, loss, lr, optimizer, guard,
-                zero=zero, with_metrics=True)
+        with jax.named_scope("lm_update"):
+            if guard is None:
+                new_params, new_state, om = opt_update(
+                    optimizer, params, grads, opt_state, lr, zero=zero,
+                    with_metrics=True)
+            else:
+                new_params, new_state, om = guarded_opt_update(
+                    params, grads, opt_state, loss, lr, optimizer, guard,
+                    zero=zero, with_metrics=True)
         # optimizer block LAST: its true ‖Δp‖/‖p‖ update_ratio overrides
         # the lr·‖g‖ SGD proxy train_step_metrics emits
         metrics = {**metrics,
@@ -544,8 +573,9 @@ def _make_sgd_step(loss_fn, lr: float, with_metrics: bool,
             def step(params, tokens, targets):
                 loss, grads = jax.value_and_grad(loss_fn)(params, tokens,
                                                           targets)
-                return jax.tree_util.tree_map(lambda p, g: p - lr * g,
-                                              params, grads), loss
+                with jax.named_scope("lm_update"):
+                    return jax.tree_util.tree_map(lambda p, g: p - lr * g,
+                                                  params, grads), loss
 
             return _seam(step)
 
@@ -553,8 +583,9 @@ def _make_sgd_step(loss_fn, lr: float, with_metrics: bool,
         def step(params, tokens, targets):
             loss, grads = jax.value_and_grad(loss_fn)(params, tokens,
                                                       targets)
-            new_params, gm = guarded_sgd_update(params, grads, loss, lr,
-                                                guard)
+            with jax.named_scope("lm_update"):
+                new_params, gm = guarded_sgd_update(params, grads, loss, lr,
+                                                    guard)
             return new_params, loss, gm
 
         return _seam(step)
@@ -565,13 +596,14 @@ def _make_sgd_step(loss_fn, lr: float, with_metrics: bool,
     def step(params, tokens, targets):
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, tokens, targets)
-        if guard is None:
-            new_params = jax.tree_util.tree_map(lambda p, g: p - lr * g,
-                                                params, grads)
-            gm = {}
-        else:
-            new_params, gm = guarded_sgd_update(params, grads, loss, lr,
-                                                guard)
+        with jax.named_scope("lm_update"):
+            if guard is None:
+                new_params = jax.tree_util.tree_map(lambda p, g: p - lr * g,
+                                                    params, grads)
+                gm = {}
+            else:
+                new_params, gm = guarded_sgd_update(params, grads, loss, lr,
+                                                    guard)
         metrics = {**metrics,
                    **train_step_metrics(params, grads, lr, loss=loss),
                    **gm}
@@ -816,18 +848,20 @@ def make_pp_loss(stage_fn, mesh: Mesh, pipe_axis: str,
 
     def loss(trained, toks_mbs, tgt_mbs):
         stacked, embed, dec_w, dec_b = trained
-        x_mbs = embed[toks_mbs]  # (M, mb, T, d)
+        with jax.named_scope("lm_embed"):
+            x_mbs = embed[toks_mbs]  # (M, mb, T, d)
         outs = pipeline_apply(stacked, x_mbs, stage_fn, mesh, pipe_axis,
                               batch_axis=batch_axis, overlap=overlap)
-        logits = outs @ dec_w + dec_b
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, tgt_mbs[..., None], -1)[..., 0]
-        if with_metrics:
-            return jnp.mean(nll), {
-                "microbatch_loss": jnp.mean(nll, axis=tuple(
-                    range(1, nll.ndim))),  # (M,)
-            }
-        return jnp.mean(nll)
+        with jax.named_scope("lm_loss"):
+            logits = outs @ dec_w + dec_b
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, tgt_mbs[..., None], -1)[..., 0]
+            if with_metrics:
+                return jnp.mean(nll), {
+                    "microbatch_loss": jnp.mean(nll, axis=tuple(
+                        range(1, nll.ndim))),  # (M,)
+                }
+            return jnp.mean(nll)
 
     return loss
 
@@ -872,19 +906,28 @@ def _decoder_block_kv(layer_params: dict, h: Array, n_heads: int, attn_core,
     is IDENTICAL to _attn_block + _decoder_block's dense path — prefill
     logits must stay bit-identical to lm_forward's (pinned in
     tests/test_serve.py)."""
-    hn = _layernorm(h, layer_params["ln_g"], layer_params["ln_b"])
-    q = _split_heads(hn @ layer_params["wq"], n_heads)
-    k = _split_heads(hn @ layer_params["wk"], n_heads)
-    v = _split_heads(hn @ layer_params["wv"], n_heads)
-    # .astype keeps the scan carry dtype stable under serve_dtype="bf16"
-    # (the dense core's f32 score scale widens its output); identity at f32
-    h = h + (_merge_heads(attn_core(q, k, v))
-             @ layer_params["wo"]).astype(h.dtype)
-    h2 = _layernorm(h, layer_params["ln2_g"], layer_params["ln2_b"])
-    flat = h2.reshape(-1, h2.shape[-1])
-    moe_out = dense_moe(layer_params["router"], layer_params["experts"],
-                        flat, top_k)
-    return h + moe_out.reshape(h.shape).astype(h.dtype), k, v
+    with jax.named_scope("lm_attn"):
+        hn = _layernorm(h, layer_params["ln_g"], layer_params["ln_b"])
+        q = _split_heads(hn @ layer_params["wq"], n_heads)
+        k = _split_heads(hn @ layer_params["wk"], n_heads)
+        v = _split_heads(hn @ layer_params["wv"], n_heads)
+        # .astype keeps the scan carry dtype stable under serve_dtype="bf16"
+        # (the dense core's f32 score scale widens its output); identity at
+        # f32
+        h = h + (_merge_heads(attn_core(q, k, v))
+                 @ layer_params["wo"]).astype(h.dtype)
+    return _dense_moe_ffn(layer_params, h, top_k), k, v
+
+
+def _dense_moe_ffn(layer_params: dict, h: Array, top_k: int) -> Array:
+    """The serving blocks' FFN half under ``lm_moe``: second layer norm,
+    the dense MoE, the residual in the carry's dtype."""
+    with jax.named_scope("lm_moe"):
+        h2 = _layernorm(h, layer_params["ln2_g"], layer_params["ln2_b"])
+        flat = h2.reshape(-1, h2.shape[-1])
+        moe_out = dense_moe(layer_params["router"], layer_params["experts"],
+                            flat, top_k)
+        return h + moe_out.reshape(h.shape).astype(h.dtype)
 
 
 def lm_prefill(params: dict, tokens: Array, n_heads: int, top_k: int = 2,
@@ -897,7 +940,8 @@ def lm_prefill(params: dict, tokens: Array, n_heads: int, top_k: int = 2,
     produce garbage K/V that decode's position mask never reads."""
     core = lambda q, k, v: attention_core(q, k, v, causal=True,  # noqa: E731
                                           impl=attn_impl)
-    h = params["embed"][tokens]
+    with jax.named_scope("lm_embed"):
+        h = params["embed"][tokens]
 
     def step(h, layer_params):
         h, k, v = _decoder_block_kv(layer_params, h, n_heads, core, top_k)
@@ -920,29 +964,28 @@ def _decode_block(layer_params: dict, h: Array, ck: Array, cv: Array,
     one. W=1 is the decode hot path; W=k+1 is the speculative verify step
     (ISSUE 16) — the same math, so verify logits at offset i are exactly
     what i sequential decode steps over the same tokens would produce."""
-    hn = _layernorm(h, layer_params["ln_g"], layer_params["ln_b"])
-    q = _split_heads(hn @ layer_params["wq"], n_heads)    # (S, H, W, Dh)
-    k_new = _split_heads(hn @ layer_params["wk"], n_heads)
-    v_new = _split_heads(hn @ layer_params["wv"], n_heads)
-    write = jax.vmap(
-        lambda c, kn, p: jax.lax.dynamic_update_slice_in_dim(
-            c, kn.astype(c.dtype), p, axis=1))
-    ck = write(ck, k_new, positions)
-    cv = write(cv, v_new, positions)
-    scores = jnp.einsum("shqd,shkd->shqk", q, ck) / jnp.sqrt(
-        q.shape[-1] * 1.0)                                # (S, H, W, T_max)
-    pos_q = positions[:, None] + jnp.arange(h.shape[1])[None, :]  # (S, W)
-    mask = (jnp.arange(ck.shape[2])[None, None, None, :]
-            <= pos_q[:, None, :, None])
-    scores = jnp.where(mask, scores, -1e30)
-    o = jnp.einsum("shqk,shkd->shqd", jax.nn.softmax(scores, -1), cv)
-    # f32 score math, carry-dtype residual (identity at f32 — parity-safe)
-    h = h + (_merge_heads(o) @ layer_params["wo"]).astype(h.dtype)
-    h2 = _layernorm(h, layer_params["ln2_g"], layer_params["ln2_b"])
-    flat = h2.reshape(-1, h2.shape[-1])                   # (S, d)
-    moe_out = dense_moe(layer_params["router"], layer_params["experts"],
-                        flat, top_k)
-    return h + moe_out.reshape(h.shape).astype(h.dtype), ck, cv
+    with jax.named_scope("lm_attn"):
+        hn = _layernorm(h, layer_params["ln_g"], layer_params["ln_b"])
+        q = _split_heads(hn @ layer_params["wq"], n_heads)  # (S, H, W, Dh)
+        k_new = _split_heads(hn @ layer_params["wk"], n_heads)
+        v_new = _split_heads(hn @ layer_params["wv"], n_heads)
+        with jax.named_scope("lm_cache_write"):
+            write = jax.vmap(
+                lambda c, kn, p: jax.lax.dynamic_update_slice_in_dim(
+                    c, kn.astype(c.dtype), p, axis=1))
+            ck = write(ck, k_new, positions)
+            cv = write(cv, v_new, positions)
+        scores = jnp.einsum("shqd,shkd->shqk", q, ck) / jnp.sqrt(
+            q.shape[-1] * 1.0)                            # (S, H, W, T_max)
+        pos_q = positions[:, None] + jnp.arange(h.shape[1])[None, :]  # (S, W)
+        mask = (jnp.arange(ck.shape[2])[None, None, None, :]
+                <= pos_q[:, None, :, None])
+        scores = jnp.where(mask, scores, -1e30)
+        o = jnp.einsum("shqk,shkd->shqd", jax.nn.softmax(scores, -1), cv)
+        # f32 score math, carry-dtype residual (identity at f32:
+        # parity-safe)
+        h = h + (_merge_heads(o) @ layer_params["wo"]).astype(h.dtype)
+    return _dense_moe_ffn(layer_params, h, top_k), ck, cv
 
 
 def lm_decode_step(params: dict, cache: dict, tokens: Array,
@@ -951,7 +994,8 @@ def lm_decode_step(params: dict, cache: dict, tokens: Array,
     ``positions`` (S,) in the cache and next-token logits (S, V) come back
     with the updated cache. The layer stack scans the stacked block params
     AND the cache's layer axis together, so depth costs one trace."""
-    h = params["embed"][tokens][:, None, :]               # (S, 1, d)
+    with jax.named_scope("lm_embed"):
+        h = params["embed"][tokens][:, None, :]           # (S, 1, d)
 
     def step(h, xs):
         layer_params, ck, cv = xs
@@ -970,10 +1014,11 @@ def sample_tokens(logits: Array, key: Array, temperature: Array) -> Array:
     temperature-scaled categorical — selected in-graph so ONE compiled
     step serves any mix of greedy and sampling requests (per-slot
     temperature vector; no retrace when the mix changes)."""
-    greedy = jnp.argmax(logits, axis=-1)
-    scaled = logits / jnp.maximum(temperature, 1e-6)[..., None]
-    sampled = jax.random.categorical(key, scaled)
-    return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
+    with jax.named_scope("lm_sample"):
+        greedy = jnp.argmax(logits, axis=-1)
+        scaled = logits / jnp.maximum(temperature, 1e-6)[..., None]
+        sampled = jax.random.categorical(key, scaled)
+        return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
 
 
 def make_decode_step(n_heads: int, top_k: int = 2, donate_cache: bool = True,
@@ -1016,10 +1061,11 @@ def make_prefill_step(n_heads: int, top_k: int = 2,
         params = transform(params)
         logits, ks, vs = lm_prefill(params, tokens, n_heads, top_k,
                                     attn_impl)
-        ck = jax.lax.dynamic_update_slice(
-            cache["k"], ks.astype(cache["k"].dtype), (0, slot, 0, 0, 0))
-        cv = jax.lax.dynamic_update_slice(
-            cache["v"], vs.astype(cache["v"].dtype), (0, slot, 0, 0, 0))
+        with jax.named_scope("lm_cache_write"):
+            ck = jax.lax.dynamic_update_slice(
+                cache["k"], ks.astype(cache["k"].dtype), (0, slot, 0, 0, 0))
+            cv = jax.lax.dynamic_update_slice(
+                cache["v"], vs.astype(cache["v"].dtype), (0, slot, 0, 0, 0))
         last = jax.lax.dynamic_index_in_dim(logits[0], last_idx, 0,
                                             keepdims=False)
         k = jax.random.fold_in(key, step_idx)
@@ -1041,7 +1087,8 @@ def lm_verify_step(params: dict, cache: dict, tokens: Array,
     all k proposals. The caller must guarantee ``positions + W <=
     T_max`` (``dynamic_update_slice`` clamps out-of-range starts, which
     would silently overwrite live earlier positions)."""
-    h = params["embed"][tokens]                           # (S, W, d)
+    with jax.named_scope("lm_embed"):
+        h = params["embed"][tokens]                       # (S, W, d)
 
     def step(h, xs):
         layer_params, ck, cv = xs
@@ -1106,7 +1153,8 @@ def make_chunk_prefill_step(n_heads: int, top_k: int = 2,
     def chunk(params, cache, tokens, start, last_idx, slot, temp, key,
               step_idx):
         params = transform(params)
-        h = params["embed"][tokens]                       # (1, W, d)
+        with jax.named_scope("lm_embed"):
+            h = params["embed"][tokens]                   # (1, W, d)
         pos = jnp.asarray(start, jnp.int32)[None]         # (1,)
 
         def step(h, xs):
@@ -1115,8 +1163,11 @@ def make_chunk_prefill_step(n_heads: int, top_k: int = 2,
             cv_s = jax.lax.dynamic_index_in_dim(cv, slot, 0, keepdims=True)
             h, ck_s, cv_s = _decode_block(layer_params, h, ck_s, cv_s,
                                           pos, n_heads, top_k)
-            ck = jax.lax.dynamic_update_slice_in_dim(ck, ck_s, slot, axis=0)
-            cv = jax.lax.dynamic_update_slice_in_dim(cv, cv_s, slot, axis=0)
+            with jax.named_scope("lm_cache_write"):
+                ck = jax.lax.dynamic_update_slice_in_dim(ck, ck_s, slot,
+                                                         axis=0)
+                cv = jax.lax.dynamic_update_slice_in_dim(cv, cv_s, slot,
+                                                         axis=0)
             return h, (ck, cv)
 
         h, (cks, cvs) = jax.lax.scan(

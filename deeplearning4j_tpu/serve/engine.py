@@ -124,6 +124,7 @@ from deeplearning4j_tpu.serve.speculative import (
     resolve_speculative,
 )
 from deeplearning4j_tpu.telemetry import trace as _trace
+from deeplearning4j_tpu.telemetry.runprof import StepTiming, resolve_runprof
 from deeplearning4j_tpu.utils.lockwatch import make_condition, make_rlock
 
 _UNSET = object()
@@ -200,7 +201,6 @@ class DecodeEngine:
                  prefill_chunk: Optional[int] = None,
                  speculative=None, runprof=None, tuned=None):
         from deeplearning4j_tpu.telemetry.registry import default_registry
-        from deeplearning4j_tpu.telemetry.runprof import resolve_runprof
 
         # tuned= (ISSUE 20): adopt the autotuner's "serve" seam —
         # min_bucket and slots (scheduling knobs; greedy decode stays
@@ -301,9 +301,23 @@ class DecodeEngine:
         self.spec_verify_steps = 0
         self.spec_accepted_total = 0
         self._spec_proposed_total = 0
-        # the counter the full-prefix-hit pin asserts against exists (at
-        # 0) from construction; spec instruments likewise when armed
-        self.registry.counter("serve_prefill_dispatches_total")
+        # every serve_* instrument of the per-token path is bound once
+        # here: the registry's get-or-create under its rlock stays off the
+        # tick (the ledger's idle gaps found it there, PR 24). The
+        # counter the full-prefix-hit pin asserts against exists (at 0)
+        # from construction; spec instruments likewise when armed
+        reg = self.registry
+        self._c_requests = reg.counter("serve_requests_total")
+        self._c_tokens = reg.counter("serve_tokens_total")
+        self._c_prefill_dispatches = reg.counter(
+            "serve_prefill_dispatches_total")
+        self._c_completed: dict = {}    # reason -> counter, born at first use
+        self._g_queue_depth = reg.gauge("serve_queue_depth")
+        self._g_active_slots = reg.gauge("serve_active_slots")
+        self._h_prefill_ms = reg.histogram("serve_prefill_ms")
+        self._h_decode_step_ms = reg.histogram("serve_decode_step_ms")
+        self._h_request_ms = reg.histogram("serve_request_ms")
+        self._h_first_token_ms = reg.histogram("serve_first_token_ms")
         # runtime profiler (ISSUE 17): the scheduler loop phase-times
         # each decode tick into the runprof rings/gauges when armed —
         # instruments pre-created HERE so the first flush's increment
@@ -317,13 +331,17 @@ class DecodeEngine:
         if self._runprof is not None:
             self._runprof.arm("serve_decode")
         if self.spec is not None:
-            for name in ("serve_spec_verify_steps_total",
-                         "serve_spec_accepted_tokens_total",
-                         "serve_spec_draft_prefills_total",
-                         "serve_spec_draft_steps_total"):
-                self.registry.counter(name)
-            self.registry.histogram("serve_spec_accepted_per_verify")
-            self.registry.histogram("serve_verify_step_ms")
+            self._c_spec_verify_steps = reg.counter(
+                "serve_spec_verify_steps_total")
+            self._c_spec_accepted = reg.counter(
+                "serve_spec_accepted_tokens_total")
+            self._c_spec_draft_prefills = reg.counter(
+                "serve_spec_draft_prefills_total")
+            self._c_spec_draft_steps = reg.counter(
+                "serve_spec_draft_steps_total")
+            self._h_spec_accepted = reg.histogram(
+                "serve_spec_accepted_per_verify")
+            self._h_verify_step_ms = reg.histogram("serve_verify_step_ms")
             # serve_spec_accept_rate stays UNBORN until the warmup floor
             # of verify steps: the serve_spec_accept_collapse rule
             # (op "<") must read "not yet speculating" as no-data
@@ -340,6 +358,7 @@ class DecodeEngine:
         self._temps = np.zeros((self.n_slots,), np.float32)
         self._rid = itertools.count()
         self._step_idx = 0
+        self._tick = None  # the open ``tick`` phase; set under the lock
         self._thread: Optional[threading.Thread] = None
         self._running = False
         # aggregate accounting for stats()/bench
@@ -479,9 +498,8 @@ class DecodeEngine:
             self.requests_total += 1
             if self._t_first_activity is None:
                 self._t_first_activity = req.t_submit
-            self.registry.counter("serve_requests_total").inc()
-            self.registry.gauge("serve_queue_depth").set(
-                float(len(self._queue)))
+            self._c_requests.inc()
+            self._g_queue_depth.set(float(len(self._queue)))
             self._work.notify_all()
         return req
 
@@ -556,17 +574,17 @@ class DecodeEngine:
         padded[0, :n] = req.prompt
         if req.prefill_span is not None:
             req.prefill_span.set_attr("bucket", bucket)
-        t0 = time.perf_counter()
-        self._cache, tok = self._prefill(
-            self.params, self._cache, padded, n - 1, slot,
-            np.float32(req.temperature), self._key, self._step_idx)
-        self._step_idx += 1
-        self.registry.counter("serve_prefill_dispatches_total").inc()
-        tok = int(np.asarray(tok))  # graftlint: allow[blocking-under-lock] deliberate: the scheduler lock IS the serialization — slot state may only change together with the fenced prefill result
-        now = time.perf_counter()
-        req.prefill_suffix_ms += (now - t0) * 1000.0
-        req.prefill_ms += (now - t0) * 1000.0
-        self._complete_prefill(req, slot, tok, now, mode="full")
+        with _trace.phase("tick.prefill", self._tick.tick, rid=req.rid,
+                          prompt_len=n, bucket=bucket) as ph:
+            self._cache, tok = self._prefill(
+                self.params, self._cache, padded, n - 1, slot,
+                np.float32(req.temperature), self._key, self._step_idx)
+            self._step_idx += 1
+            self._c_prefill_dispatches.inc()
+            tok = int(np.asarray(tok))  # graftlint: allow[blocking-under-lock] deliberate: the scheduler lock IS the serialization — slot state may only change together with the fenced prefill result
+        req.prefill_suffix_ms += ph.ms
+        req.prefill_ms += ph.ms
+        self._complete_prefill(req, slot, tok, ph.t1, mode="full")
 
     def _draft_admit(self, req: ServeRequest, slot: int, n: int) -> None:
         """Seed the DRAFT cache for an admitted slot (speculative only):
@@ -580,7 +598,7 @@ class DecodeEngine:
             self._draft_params, self._draft_cache, padded, n - 1, slot,
             np.float32(0.0), self._key, self._step_idx)
         self._step_idx += 1
-        self.registry.counter("serve_spec_draft_prefills_total").inc()
+        self._c_spec_draft_prefills.inc()
 
     def _chunk_plan(self, req: ServeRequest, plen: int) -> list:
         """Chunk schedule covering prompt positions [plen, n): a list of
@@ -615,23 +633,24 @@ class DecodeEngine:
         admission with its sampled first token."""
         toks, start, last_idx = plan[idx]
         final = idx == len(plan) - 1
-        t0 = time.perf_counter()
-        self._cache, tok = self._chunk(
-            self.params, self._cache, toks, np.int32(start),
-            np.int32(last_idx), np.int32(slot),
-            np.float32(req.temperature), self._key, self._step_idx)
-        self._step_idx += 1
-        self.registry.counter("serve_prefill_dispatches_total").inc()
-        req.prefill_chunks += 1
-        if final:
-            tok = int(np.asarray(tok))  # graftlint: allow[blocking-under-lock] deliberate: same fencing contract as the classic prefill — slot state changes only with the fenced result
-        now = time.perf_counter()
-        req.prefill_suffix_ms += (now - t0) * 1000.0
-        req.prefill_ms += (now - t0) * 1000.0
+        with _trace.phase("tick.prefill", self._tick.tick, rid=req.rid,
+                          prompt_len=len(req.prompt),
+                          bucket=toks.shape[1]) as ph:
+            self._cache, tok = self._chunk(
+                self.params, self._cache, toks, np.int32(start),
+                np.int32(last_idx), np.int32(slot),
+                np.float32(req.temperature), self._key, self._step_idx)
+            self._step_idx += 1
+            self._c_prefill_dispatches.inc()
+            req.prefill_chunks += 1
+            if final:
+                tok = int(np.asarray(tok))  # graftlint: allow[blocking-under-lock] deliberate: same fencing contract as the classic prefill — slot state changes only with the fenced result
+        req.prefill_suffix_ms += ph.ms
+        req.prefill_ms += ph.ms
         if final:
             self._chunking.pop(slot, None)
             self._complete_prefill(
-                req, slot, tok, now,
+                req, slot, tok, ph.t1,
                 mode="suffix" if req.cached_tokens else "chunked")
         else:
             # shield: next chunk overwrites [next_start, next_start + W)
@@ -649,8 +668,7 @@ class DecodeEngine:
                     req.prompt,
                     self._cache["k"][:, slot, :, :span, :],
                     self._cache["v"][:, slot, :, :span, :])
-        self.registry.histogram("serve_prefill_ms").observe(
-            req.prefill_ms, exemplar=req.trace_id)
+        self._h_prefill_ms.observe(req.prefill_ms, exemplar=req.trace_id)
         self._finish_prefill_span(req, mode=mode)
         self._positions[slot] = len(req.prompt)
         if req.span is not None:
@@ -689,7 +707,7 @@ class DecodeEngine:
             req.decode_span.add_event("accept", token=tok,
                                       n=len(req.generated))
         self.tokens_total += 1
-        self.registry.counter("serve_tokens_total").inc()
+        self._c_tokens.inc()
         if len(req.generated) >= req.max_new_tokens:
             self._finish(req, "max_new_tokens", now)
         elif int(self._positions[req.slot]) >= self.max_len:
@@ -743,16 +761,19 @@ class DecodeEngine:
             self._positions[req.slot] = 0
             self._temps[req.slot] = 0.0
             req.slot = None
-        self.registry.counter("serve_completed_total",
-                              {"reason": reason}).inc()
+        completed = self._c_completed.get(reason)
+        if completed is None:
+            completed = self._c_completed[reason] = self.registry.counter(
+                "serve_completed_total", {"reason": reason})
+        completed.inc()
         # trace exemplars (ISSUE 15): the request's trace id rides its
         # latency observation into the bucket, so /metrics (OpenMetrics
         # exemplar syntax) and a firing serve_latency_slo_burn alert can
         # name the exact offending traces (None when tracing is off)
-        self.registry.histogram("serve_request_ms").observe(
-            (now - req.t_submit) * 1000.0, exemplar=req.trace_id)
+        self._h_request_ms.observe((now - req.t_submit) * 1000.0,
+                                   exemplar=req.trace_id)
         if req.t_first is not None:
-            self.registry.histogram("serve_first_token_ms").observe(
+            self._h_first_token_ms.observe(
                 (req.t_first - req.t_submit) * 1000.0,
                 exemplar=req.trace_id)
         req.done.set()
@@ -765,104 +786,115 @@ class DecodeEngine:
 
     def step(self) -> int:
         """One scheduler iteration: admit into free slots, then one fused
-        decode step over every slot. Returns tokens emitted (0 = idle)."""
+        decode step over every slot. Returns tokens emitted (0 = idle).
+
+        The iteration is one ``tick`` phase (telemetry/trace.py) with its
+        children ``tick.admit`` > ``tick.prefill``, ``tick.decode`` and
+        ``tick.accept``. That record is the tick's only clock: the decode
+        histogram, the ``engine.step`` span and the runprof timing below
+        all read it."""
         tracer = _trace.get_tracer()
         step_span = (tracer.start_span("engine.step", parent=False)
                      if tracer is not None else None)
-        t_sched0 = time.perf_counter()  # runprof phase clock (ISSUE 17)
-        with self._lock:
+        decode = None       # the tick.decode phase, where a step ran
+        decode_ms = 0.0
+        with _trace.phase("tick") as tick, self._lock:
+            self._tick = tick
             tokens_before = self.tokens_total
-            free = self._free_slots()
-            admitted = 0
-            while self._queue and free:
-                req = self._queue.pop(0)
-                self._admit(req, free.pop(0))
-                admitted += 1
-            self.registry.gauge("serve_queue_depth").set(
-                float(len(self._queue)))
-            # ---- chunked prefill: ONE chunk per mid-prefill slot per
-            # iteration, so a long admission interleaves with decode
-            # ticks instead of head-of-line-blocking them ----
-            for slot in list(self._chunking):
-                st = self._chunking[slot]
-                self._run_chunk(st["req"], slot, st["plan"], st["idx"])
-                if slot in self._chunking:
-                    st["idx"] += 1
+            dispatches = self._c_prefill_dispatches.value
+            with _trace.phase("tick.admit", tick.tick) as admit:
+                free = self._free_slots()
+                admitted = 0
+                while self._queue and free:
+                    req = self._queue.pop(0)
+                    self._admit(req, free.pop(0))
+                    admitted += 1
+                # ---- chunked prefill: ONE chunk per mid-prefill slot per
+                # iteration, so a long admission interleaves with decode
+                # ticks instead of head-of-line-blocking them ----
+                for slot in list(self._chunking):
+                    st = self._chunking[slot]
+                    self._run_chunk(st["req"], slot, st["plan"], st["idx"])
+                    if slot in self._chunking:
+                        st["idx"] += 1
+                admit.attrs["admitted"] = admitted
+                admit.attrs["prefill_dispatches"] = int(
+                    self._c_prefill_dispatches.value - dispatches)
+            self._g_queue_depth.set(float(len(self._queue)))
             active = [r for r in self._slots
                       if r is not None and r.slot not in self._chunking]
-            self.registry.gauge("serve_active_slots").set(
-                float(len(active)))
-            if not active:
-                if step_span is not None:
-                    step_span.set_attr("admissions", admitted)
-                    step_span.set_attr("occupancy", 0)
-                    step_span.set_attr("idle", not self._chunking)
-                    step_span.end()
-                return self.tokens_total - tokens_before
+            self._g_active_slots.set(float(len(active)))
             # ---- speculative eligibility: the verify dispatch writes
             # k+1 positions per slot; near the page end (or while a slot
             # is mid-chunk-prefill) fall back to the plain decode tick —
             # dynamic_update_slice clamps out-of-range starts, which
             # would silently overwrite live earlier positions ----
             spec_tick = (
-                self.spec is not None and not self._chunking
+                bool(active) and self.spec is not None
+                and not self._chunking
                 and all(int(self._positions[r.slot]) + self.spec.k + 1
                         <= self.max_len for r in active))
             if spec_tick:
-                decode_ms = self._spec_step(active, step_span)
-                # spec ticks interleave k+1 draft dispatches with their
-                # fences; no clean dispatch/device split — attribute the
-                # whole measured wall to the device phase
-                rp_dispatch_ms, rp_device_ms = 0.0, decode_ms
-            else:
-                t0 = time.perf_counter()
-                self._cache, toks = self._decode(
-                    self.params, self._cache, self._tokens,
-                    self._positions, self._temps, self._key,
-                    self._step_idx)
-                self._step_idx += 1
-                t_disp = time.perf_counter()  # enqueue back; device runs
-                toks = np.asarray(toks)  # graftlint: allow[blocking-under-lock] deliberate: retirement must see the fenced decode tokens; submit() blocks here only between decode steps
-                now = time.perf_counter()
-                decode_ms = (now - t0) * 1000.0
-                rp_dispatch_ms = (t_disp - t0) * 1000.0
-                rp_device_ms = (now - t_disp) * 1000.0
-                self.registry.histogram("serve_decode_step_ms").observe(
-                    decode_ms)
+                with _trace.phase("tick.decode", tick.tick,
+                                  occupancy=len(active),
+                                  speculative=True) as decode:
+                    # k+1 draft dispatches interleaved with their fences
+                    # and the acceptance: no finer split
+                    decode_ms = self._spec_step(active, step_span)
+            elif active:
+                with _trace.phase("tick.decode", tick.tick,
+                                  occupancy=len(active)) as decode:
+                    self._cache, toks = self._decode(
+                        self.params, self._cache, self._tokens,
+                        self._positions, self._temps, self._key,
+                        self._step_idx)
+                    self._step_idx += 1
+                    # enqueue back; the device runs until the fence
+                    decode.attrs["t_disp"] = time.perf_counter()
+                    toks = np.asarray(toks)  # graftlint: allow[blocking-under-lock] deliberate: retirement must see the fenced decode tokens; submit() blocks here only between decode steps
+                decode_ms = decode.ms
+                self._h_decode_step_ms.observe(decode_ms)
                 self.decode_steps += 1
                 self._occupancy_sum += len(active)
-                for req in active:
-                    slot = req.slot
-                    if req.decode_span is not None:
-                        req.decode_ms += decode_ms
-                    self._positions[slot] += 1
-                    self._accept_token(req, int(toks[slot]), now)
+                with _trace.phase("tick.accept", tick.tick):
+                    for req in active:
+                        slot = req.slot
+                        if req.decode_span is not None:
+                            req.decode_ms += decode_ms
+                        self._positions[slot] += 1
+                        self._accept_token(req, int(toks[slot]), decode.t1)
             occupancy_after = sum(r is not None for r in self._slots)
-            self.registry.gauge("serve_active_slots").set(
-                float(occupancy_after))
-            if step_span is not None:
-                step_span.set_attr("admissions", admitted)
-                step_span.set_attr("occupancy", len(active))
-                step_span.set_attr("retired",
-                                   len(active) - occupancy_after)
-                step_span.set_attr("queue_depth", len(self._queue))
+            if active:
+                self._g_active_slots.set(float(occupancy_after))
+            tick.attrs.update(
+                admitted=admitted, occupancy=len(active),
+                retired=len(active) - occupancy_after if active else 0,
+                queue_depth=len(self._queue))
+            if decode is None:
+                tick.attrs["idle"] = not self._chunking
+            emitted = self.tokens_total - tokens_before
+        if step_span is not None:
+            for key, value in tick.attrs.items():
+                step_span.set_attr(
+                    "admissions" if key == "admitted" else key, value)
+            if decode is not None:
                 step_span.set_attr("decode_ms", round(decode_ms, 3))
-                step_span.end()
-            if self._runprof is not None:
-                from deeplearning4j_tpu.telemetry.runprof import StepTiming
-                t_rp_end = time.perf_counter()
-                # host phase = this tick's scheduler work (admission,
-                # chunked prefill, retirement) — everything outside the
-                # decode dispatch+fence
-                sched_ms = max(
-                    0.0, (t_rp_end - t_sched0) * 1000.0 - decode_ms)
-                self._runprof.record(StepTiming(
-                    label="serve_decode", t_unix=time.time(),
-                    wall_ms=decode_ms, host_ms=sched_ms,
-                    dispatch_ms=rp_dispatch_ms, device_ms=rp_device_ms,
-                    trace_id=(step_span.trace_id
-                              if step_span is not None else None)))
-            return self.tokens_total - tokens_before
+            step_span.end()
+        if self._runprof is not None and decode is not None:
+            # a speculative tick has no clean dispatch/device split: the
+            # whole measured wall goes to the device phase. The host phase
+            # is the tick's scheduler work (admission, chunked prefill,
+            # retirement): everything outside the decode dispatch + fence
+            t_disp = decode.attrs.get("t_disp", decode.t0)
+            self._runprof.record(StepTiming(
+                label="serve_decode", t_unix=time.time(),
+                wall_ms=decode_ms,
+                host_ms=max(0.0, tick.ms - decode_ms),
+                dispatch_ms=(t_disp - decode.t0) * 1000.0,
+                device_ms=decode_ms - (t_disp - decode.t0) * 1000.0,
+                trace_id=(step_span.trace_id
+                          if step_span is not None else None)))
+        return emitted
 
     def _spec_step(self, active: List[ServeRequest], step_span) -> float:
         """One speculative iteration (called under the scheduler lock):
@@ -892,7 +924,7 @@ class DecodeEngine:
                 drafts[:, j] = dt
                 cur = dt.copy()
             dpos += 1
-        self.registry.counter("serve_spec_draft_steps_total").inc(k + 1)
+        self._c_spec_draft_steps.inc(k + 1)
         t1 = time.perf_counter()
         vt = np.concatenate([self._tokens[:, None], drafts], axis=1)
         self._cache, vtoks = self._verify(
@@ -905,11 +937,10 @@ class DecodeEngine:
         verify_ms = (now - t1) * 1000.0
         # trace exemplar on the verify latency observation (ISSUE 16):
         # a slow verify is attributable to a real request's trace
-        self.registry.histogram("serve_verify_step_ms").observe(
-            verify_ms, exemplar=active[0].trace_id)
-        self.registry.histogram("serve_decode_step_ms").observe(
-            draft_ms + verify_ms)
-        self.registry.counter("serve_spec_verify_steps_total").inc()
+        self._h_verify_step_ms.observe(verify_ms,
+                                       exemplar=active[0].trace_id)
+        self._h_decode_step_ms.observe(draft_ms + verify_ms)
+        self._c_spec_verify_steps.inc()
         self.spec_verify_steps += 1
         self.decode_steps += 1
         self._occupancy_sum += len(active)
@@ -923,11 +954,8 @@ class DecodeEngine:
                                                    vtoks[slot])
             self.spec_accepted_total += a
             self._spec_proposed_total += k
-            self.registry.counter(
-                "serve_spec_accepted_tokens_total").inc(a)
-            self.registry.histogram(
-                "serve_spec_accepted_per_verify").observe(
-                float(a), exemplar=req.trace_id)
+            self._c_spec_accepted.inc(a)
+            self._h_spec_accepted.observe(float(a), exemplar=req.trace_id)
             if req.decode_span is not None:
                 req.decode_ms += draft_ms + verify_ms
                 req.decode_span.add_event("verify", accepted=a,

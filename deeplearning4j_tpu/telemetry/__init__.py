@@ -15,7 +15,10 @@ Three layers, one subsystem:
   the elastic control plane (context propagation over the tracker frame
   protocol and blob metas) + a per-process crash flight recorder dumped
   on error/SIGTERM and checkpointed write-ahead at round boundaries —
-  merged into round timelines by tools/trace_report.py;
+  merged into round timelines by tools/trace_report.py; and the serving
+  tick's phases (``phase`` / ``phases_between``, ISSUE 25): each a
+  ``TraceAnnotation`` on the profiler's clock and a record in an
+  always-on process-global ring;
 - **watch** (history.py + alerts.py, ISSUE 15): a bounded time-series
   history sampled from the registry (range/rate/delta queries, windowed
   histogram-delta percentiles, crash-readable JSONL spill) and a
@@ -101,6 +104,8 @@ from deeplearning4j_tpu.telemetry.trace import (
     get_tracer,
     maybe_span,
     parse_traceparent,
+    phase,
+    phases_between,
     set_tracer,
 )
 from deeplearning4j_tpu.telemetry.step_log import (
@@ -180,6 +185,8 @@ __all__ = [
     "maybe_span",
     "merge_snapshots",
     "parse_traceparent",
+    "phase",
+    "phases_between",
     "read_spill",
     "render_snapshot",
     "replay_spill",

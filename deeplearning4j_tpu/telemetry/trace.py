@@ -45,6 +45,7 @@ a dict lookup and a no-op context manager.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import math
 import os
@@ -53,10 +54,11 @@ import sys
 import threading
 
 from deeplearning4j_tpu.utils.lockwatch import make_lock
+from deeplearning4j_tpu.utils.profiling import annotate
 import time
 import uuid
 from collections import deque
-from typing import Dict, Iterator, List, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
 
 SCHEMA = "dl4j-tpu-trace-v1"
 
@@ -449,6 +451,110 @@ def maybe_span(name: str, parent=None,
         return
     with tracer.span(name, parent=parent, attrs=attrs) as sp:
         yield sp
+
+
+# ------------------------------------------------ tick phases (ISSUE 25) ----
+# The serving tick's phases on two clocks at once: a ``TraceAnnotation``
+# (the profiler's own clock, in the xplane whenever a profiler session is
+# live) and a ``perf_counter`` record in a process-global ring. The ring is
+# always on: it is the flight recorder of the last few thousand ticks that
+# an operator reads after a stall, and it is how a reader that runs after
+# the engine is gone (the benchmark's per-layer metrics) still finds the
+# window. One record per span, read by every consumer: the engine's
+# histogram, its ``engine.step`` span and its runprof timing take their
+# numbers from it and stamp nothing themselves.
+
+PHASE_RING = 16_384
+
+
+class PhaseRing:
+    """Bounded ring of ended phases ``(name, tick, t0, t1, attrs)``, in the
+    order they ended. No lock beyond the GIL: ``deque.append`` is atomic,
+    and ``evicted_t1`` (the end of the newest entry the ring has dropped)
+    may lag by one entry under two writers."""
+
+    def __init__(self, maxlen: int = PHASE_RING):
+        self.entries: deque = deque(maxlen=maxlen)
+        self.evicted_t1 = float("-inf")
+
+    def append(self, entry: tuple) -> None:
+        ring = self.entries
+        if len(ring) == ring.maxlen:
+            self.evicted_t1 = ring[0][3]
+        ring.append(entry)
+
+
+_phase_ring = PhaseRing()
+_tick_ids = itertools.count(1)
+
+
+class phase:
+    """``with phase("tick") as t: ... with phase("tick.decode", t.tick):``
+
+    A span of the serving tick. ``tick`` is the id of the ``tick`` span
+    that caused this one; None opens a new tick and draws its id. ``attrs``
+    may be filled in until the span ends (``t.attrs["admitted"] = n``).
+    Costs two clock reads, an inactive ``TraceMe`` and an append."""
+
+    __slots__ = ("name", "tick", "attrs", "t0", "t1", "_annotation")
+
+    def __init__(self, name: str, tick: Optional[int] = None, **attrs):
+        self.name = name
+        self.tick = next(_tick_ids) if tick is None else tick
+        self.attrs = attrs
+        self.t0 = self.t1 = None
+
+    def __enter__(self) -> "phase":
+        self._annotation = annotate(self.name, tick=self.tick)
+        self._annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        _phase_ring.append((self.name, self.tick, self.t0, self.t1,
+                            self.attrs))
+
+    @property
+    def ms(self) -> float:
+        return (self.t1 - self.t0) * 1000.0
+
+
+def phases_between(lo: float, hi: float) -> Tuple[List[tuple], bool]:
+    """``(entries, wrapped)``: the ring's phases that overlap ``[lo, hi]``
+    (``perf_counter`` seconds), clipped to it, oldest first; ``wrapped`` is
+    True when the ring has dropped a phase that ended after ``lo``, so the
+    entries may not be all there were."""
+    ring = _phase_ring
+    out = [(name, tick, max(t0, lo), min(t1, hi), attrs)
+           for name, tick, t0, t1, attrs in list(ring.entries)
+           if t1 > lo and t0 < hi]
+    return out, ring.evicted_t1 > lo
+
+
+def phase_self_seconds(entries: List[tuple]) -> List[float]:
+    """Self time of each entry: its span minus the part of it that the
+    other spans of its tick lying inside it cover (children and their
+    children alike, counted once)."""
+    by_tick: Dict[int, List[tuple]] = {}
+    for e in entries:
+        by_tick.setdefault(e[1], []).append(e)
+    out = []
+    for e in entries:
+        _, tick, t0, t1, _ = e
+        covered, edge = 0.0, t0
+        for _, _, a, b, _ in sorted(
+                (o for o in by_tick[tick]
+                 if o is not e and o[2] >= t0 and o[3] <= t1
+                 and (o[3] - o[2]) < (t1 - t0)),
+                key=lambda o: o[2]):
+            a = max(a, edge)
+            if b > a:
+                covered += b - a
+                edge = b
+        out.append((t1 - t0) - covered)
+    return out
 
 
 def current_trace_context() -> Optional[Dict[str, str]]:

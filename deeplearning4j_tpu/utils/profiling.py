@@ -32,10 +32,14 @@ def trace(log_dir: str, *, create_perfetto_link: bool = False):
         yield
 
 
-def annotate(name: str):
+def annotate(name: str, **attrs):
     """Scoped host annotation shown on the trace timeline
-    (e.g. ``with annotate("pretrain-layer0"): ...``)."""
-    return jax.profiler.TraceAnnotation(name)
+    (e.g. ``with annotate("pretrain-layer0"): ...``), on the profiler's
+    own clock; ``attrs`` ride along as the event's arguments. The one
+    place a ``TraceAnnotation`` is made: ``telemetry.trace.phase`` opens
+    the serving tick's spans through it. An inactive ``TraceMe`` when no
+    profiler session is live."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 def named_scope(name: str):

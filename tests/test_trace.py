@@ -462,3 +462,197 @@ class TestTraceparent:
         tracer.close()
         recs = _read_records(str(tmp_path / "spans_srv.jsonl"))
         assert recs[0]["trace_id"] == "a" * 32
+
+
+# ------------------------------------------------- tick phases (ISSUE 25) ----
+
+@pytest.fixture
+def phase_ring(monkeypatch):
+    """A ring of this test's own in place of the process's."""
+    ring = tr.PhaseRing()
+    monkeypatch.setattr(tr, "_phase_ring", ring)
+    return ring
+
+
+def _tiny_engine(**kw):
+    import jax
+
+    from deeplearning4j_tpu.models.transformer_lm import init_lm_params
+    from deeplearning4j_tpu.serve import DecodeEngine
+
+    params = init_lm_params(jax.random.PRNGKey(0), 31, 8, 2, 2, 16,
+                            n_layers=1)
+    kw.setdefault("registry", MetricsRegistry())
+    return DecodeEngine(params, 2, n_slots=2, max_len=16, serve_dtype=None,
+                        tuned=False, **kw)
+
+
+class TestPhases:
+    def test_nesting_records_the_parent_tick_and_attrs(self, phase_ring):
+        with tr.phase("tick") as tick:
+            with tr.phase("tick.admit", tick.tick) as admit:
+                with tr.phase("tick.prefill", tick.tick, rid=7, bucket=8):
+                    pass
+                admit.attrs["admitted"] = 1  # filled in before the end
+            with tr.phase("tick.decode", tick.tick, occupancy=2):
+                pass
+        with tr.phase("tick") as second:
+            pass
+        assert second.tick != tick.tick  # a tick with no parent draws an id
+        entries, wrapped = tr.phases_between(0.0, float("inf"))
+        assert not wrapped
+        # in the order they ended: children before their parent
+        assert [e[0] for e in entries] == [
+            "tick.prefill", "tick.admit", "tick.decode", "tick", "tick"]
+        assert {e[1] for e in entries[:4]} == {tick.tick}
+        by_name = {e[0]: e for e in entries[:4]}
+        assert by_name["tick.prefill"][4] == {"rid": 7, "bucket": 8}
+        assert by_name["tick.admit"][4] == {"admitted": 1}
+        assert by_name["tick.decode"][4] == {"occupancy": 2}
+        # each child lies inside its parent, on perf_counter
+        _, _, t0, t1, _ = by_name["tick"]
+        for name in ("tick.admit", "tick.decode"):
+            assert t0 <= by_name[name][2] <= by_name[name][3] <= t1
+        assert by_name["tick.admit"][2] <= by_name["tick.prefill"][2]
+        assert by_name["tick.prefill"][3] <= by_name["tick.admit"][3]
+        assert tick.ms == pytest.approx((t1 - t0) * 1000.0)
+
+    def test_self_time_is_the_span_minus_its_children(self):
+        entries = [("tick.prefill", 1, 1.0, 4.0, {}),
+                   ("tick.admit", 1, 0.5, 4.5, {}),
+                   ("tick.decode", 1, 5.0, 8.0, {}),
+                   ("tick.accept", 1, 8.0, 8.5, {}),
+                   ("tick", 1, 0.0, 10.0, {}),
+                   # another tick's spans cover nothing of this one
+                   ("tick.decode", 2, 0.0, 10.0, {}),
+                   ("tick", 2, 0.0, 11.0, {})]
+        selfs = tr.phase_self_seconds(entries)
+        assert selfs == pytest.approx([3.0, 1.0, 3.0, 0.5,
+                                       10.0 - 4.0 - 3.0 - 0.5, 10.0, 1.0])
+
+    def test_phases_between_clips_and_reports_a_wrapped_ring(
+            self, monkeypatch):
+        ring = tr.PhaseRing(maxlen=3)
+        monkeypatch.setattr(tr, "_phase_ring", ring)
+        for i in range(3):
+            ring.append(("tick", i, float(i), i + 1.0, {}))
+        entries, wrapped = tr.phases_between(0.5, 2.25)
+        assert not wrapped
+        assert [(e[2], e[3]) for e in entries] == [(0.5, 1.0), (1.0, 2.0),
+                                                   (2.0, 2.25)]
+        assert tr.phases_between(1.0, 2.0)[0] == [("tick", 1, 1.0, 2.0, {})]
+        ring.append(("tick", 3, 3.0, 4.0, {}))  # drops the span that ended at 1
+        assert tr.phases_between(0.5, 4.0)[1], "a span after 0.5 is gone"
+        entries, wrapped = tr.phases_between(1.0, 4.0)
+        assert not wrapped and len(entries) == 3
+
+    def test_phase_annotates_through_utils_profiling(self, phase_ring,
+                                                     monkeypatch):
+        """``utils.profiling.annotate`` is the one place a TraceAnnotation
+        is made: ``phase`` opens its span through it, with the tick id as
+        the event's argument, and no other module of the package makes
+        one."""
+        seen = []
+
+        class Spy:
+            def __init__(self, name, **kw):
+                seen.append((name, kw))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return None
+
+        monkeypatch.setattr(tr, "annotate", Spy)
+        with tr.phase("tick") as tick:
+            with tr.phase("tick.decode", tick.tick, occupancy=1):
+                pass
+        assert seen == [("tick", {"tick": tick.tick}),
+                        ("tick.decode", {"tick": tick.tick})]
+        package = os.path.join(REPO, "deeplearning4j_tpu")
+        makers = []
+        for root, _, files in os.walk(package):
+            for name in files:
+                if name.endswith(".py"):
+                    path = os.path.join(root, name)
+                    with open(path) as fh:
+                        if "TraceAnnotation(" in fh.read():
+                            makers.append(os.path.relpath(path, package))
+        assert makers == [os.path.join("utils", "profiling.py")]
+
+    def test_engine_leaves_one_tick_tree_a_step(self, phase_ring):
+        eng = _tiny_engine()
+        reqs = [eng.submit([1, 2, 3], max_new_tokens=3),
+                eng.submit([4, 5, 6, 7, 8], max_new_tokens=2),
+                eng.submit([9, 10], max_new_tokens=2)]  # waits for a slot
+        steps = 0
+        while eng.has_work():
+            eng.step()
+            steps += 1
+        entries, wrapped = tr.phases_between(0.0, float("inf"))
+        assert not wrapped
+        ticks = [e for e in entries if e[0] == "tick"]
+        assert len(ticks) == steps
+        for _, tick, t0, t1, attrs in ticks:
+            kids = [e for e in entries if e[1] == tick and e[0] != "tick"]
+            names = [e[0] for e in kids]
+            assert names.count("tick.admit") == 1
+            assert names.count("tick.decode") == 1
+            assert names.count("tick.accept") == 1
+            assert all(t0 <= e[2] and e[3] <= t1 for e in kids)
+            admit = next(e for e in kids if e[0] == "tick.admit")
+            assert admit[4]["admitted"] == attrs["admitted"] \
+                == admit[4]["prefill_dispatches"] \
+                == names.count("tick.prefill")
+            decode = next(e for e in kids if e[0] == "tick.decode")
+            assert decode[4]["occupancy"] == attrs["occupancy"]
+            assert decode[2] <= decode[4]["t_disp"] <= decode[3]
+        assert sum(t[4]["admitted"] for t in ticks) == 3
+        assert sum(t[4]["retired"] for t in ticks) == 3
+        prefills = [e for e in entries if e[0] == "tick.prefill"]
+        assert sorted(e[4]["rid"] for e in prefills) == sorted(
+            r.rid for r in reqs)
+        for e in prefills:
+            req = next(r for r in reqs if r.rid == e[4]["rid"])
+            assert e[4]["prompt_len"] == len(req.prompt)
+            assert e[4]["bucket"] == eng.bucket_for(len(req.prompt))
+            # the prefill's fence is the request's first token
+            assert req.t_admit <= e[2] and e[3] == req.t_first
+
+    def test_one_record_feeds_histogram_span_and_runprof(
+            self, phase_ring, tmp_path, no_global_tracer):
+        from deeplearning4j_tpu.telemetry.runprof import RunProfiler
+
+        reg = MetricsRegistry()
+        prof = RunProfiler(registry=reg, update_every=1)
+        tracer = tr.Tracer("serve", trace_dir=str(tmp_path), registry=reg)
+        tr.set_tracer(tracer)
+        try:
+            eng = _tiny_engine(registry=reg, runprof=prof)
+            eng.generate([1, 2, 3], max_new_tokens=4)
+        finally:
+            tr.set_tracer(None)
+            tracer.close()
+        entries, _ = tr.phases_between(0.0, float("inf"))
+        ticks = {e[1]: e for e in entries if e[0] == "tick"}
+        decodes = [e for e in entries if e[0] == "tick.decode"]
+        ms = [(e[3] - e[2]) * 1000.0 for e in decodes]
+        assert reg.histogram("serve_decode_step_ms").count == len(ms)
+        assert reg.histogram("serve_decode_step_ms").sum == pytest.approx(
+            sum(ms))
+        timings = prof.timings("serve_decode")
+        assert [t.wall_ms for t in timings] == pytest.approx(ms)
+        for t, e in zip(timings, decodes):
+            tick = ticks[e[1]]
+            assert t.dispatch_ms == pytest.approx(
+                (e[4]["t_disp"] - e[2]) * 1000.0)
+            assert t.dispatch_ms + t.device_ms == pytest.approx(t.wall_ms)
+            assert t.host_ms == pytest.approx(
+                (tick[3] - tick[2]) * 1000.0 - t.wall_ms)
+        steps = [r for r in _read_records(tmp_path / "spans_serve.jsonl")
+                 if r["ev"] == "E" and r["name"] == "engine.step"]
+        assert [s["attrs"]["decode_ms"] for s in steps] == [
+            round(x, 3) for x in ms]
+        assert [s["attrs"]["occupancy"] for s in steps] == [
+            e[4]["occupancy"] for e in decodes]
