@@ -41,6 +41,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.nn.layers.attention import (
@@ -885,6 +886,11 @@ def make_pp_loss(stage_fn, mesh: Mesh, pipe_axis: str,
 # The cache is a fixed-size paged buffer: leaf shape (L, S, H, T_max, Dh)
 # where S is the engine's slot count; slot s's page is overwritten on
 # readmission (eviction costs nothing — the mask hides stale positions).
+# Through the layer loop of decode, verify and chunked prefill
+# (``_cached_layers``) both leaves travel WHOLE as the loop's carry: layer
+# l writes its new rows into the carry in place and reads its own slab
+# back out of it, so a step moves the rows it stores and the positions it
+# attends, never a copy of the cache.
 # Sampling (greedy vs temperature, selected IN-GRAPH from a per-slot
 # temperature vector so one executable serves both) is fused into the same
 # jitted step as the forward — one dispatch per decode iteration.
@@ -894,7 +900,10 @@ def init_kv_cache(n_layers: int, n_slots: int, n_heads: int, head_dim: int,
     """Zeroed paged KV cache for ``n_slots`` concurrent requests:
     ``{"k","v"}`` leaves of shape (L, S, H, T_max, Dh). Zeros (not garbage)
     so masked-out positions can never inject non-finite values through the
-    0-weight attention terms."""
+    0-weight attention terms. The layer axis leads because the serving
+    programs carry each leaf whole through their layer loop and index it
+    by layer there (``_cached_layers``); the donated leaves are updated in
+    place, so one cache is all the memory a step needs for it."""
     shape = (n_layers, n_slots, n_heads, max_len, head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -951,62 +960,112 @@ def lm_prefill(params: dict, tokens: Array, n_heads: int, top_k: int = 2,
     return h @ params["dec_w"] + params["dec_b"], ks, vs
 
 
+_CACHE_LAYOUT = Layout(major_to_minor=(0, 1, 2, 3, 4))
+
+
+def _write_cache_rows(ck: Array, cv: Array, k_new: Array, v_new: Array,
+                      layer: Array, slot0: Array, positions: Array) -> tuple:
+    """Stores k_new/v_new (n, H, W, Dh) into the whole cache leaves ck/cv
+    (L, S, H, T_max, Dh) at ``[layer, slot0 + i, :, positions[i] :
+    positions[i] + W, :]``: one ``dynamic_update_slice`` a slot and leaf
+    (start clamped so the window fits, as ever), each in place in the
+    buffer it is handed. The layout constraint keeps the leaves in the
+    row-major layout the cache arrives and leaves in. Left free, the v5e
+    compiler re-lays a loop-carried cache with positions major to heads,
+    which makes a one-row update contiguous and costs four whole-cache
+    transposing copies a step round the layer loop."""
+    def one_slot(i, leaves):
+        at = (layer, slot0 + i, 0, positions[i], 0)
+        return tuple(
+            with_layout_constraint(
+                jax.lax.dynamic_update_slice(
+                    c, jax.lax.dynamic_slice_in_dim(new, i, 1)[None]
+                    .astype(c.dtype), at),
+                _CACHE_LAYOUT)
+            for c, new in zip(leaves, (k_new, v_new)))
+
+    return jax.lax.fori_loop(0, positions.shape[0], one_slot, (ck, cv))
+
+
 def _decode_block(layer_params: dict, h: Array, ck: Array, cv: Array,
-                  positions: Array, n_heads: int, top_k: int) -> tuple:
-    """One decoder block for W new tokens per slot. h: (S, W, d); ck/cv:
-    (S, H, T_max, Dh). Writes this step's K/V at ``positions``..``positions
-    + W - 1`` FIRST, then attends with the per-query mask ``index <=
-    position + offset`` — so every freshly written position is visible to
-    the queries at or after it and stale cache beyond them never is. The
-    attention math mirrors ring_attention.reference_attention (same score
-    scale, same -1e30 mask, jax.nn.softmax): the masked terms underflow to
-    exact zeros, so the padded reduction is bitwise the oracle's unpadded
-    one. W=1 is the decode hot path; W=k+1 is the speculative verify step
-    (ISSUE 16) — the same math, so verify logits at offset i are exactly
-    what i sequential decode steps over the same tokens would produce."""
+                  layer: Array, slot0: Array, positions: Array, n_heads: int,
+                  top_k: int) -> tuple:
+    """One decoder block for W new tokens per slot. h: (S, W, d), the rows
+    of slots ``slot0``..``slot0 + S - 1`` (every slot from 0 in decode and
+    verify, the one slot of a prefill chunk); ck/cv: the WHOLE cache
+    leaves (L, n_slots, H, T_max, Dh), of which this block touches those
+    slots' pages of layer ``layer`` alone. Writes this step's K/V at
+    ``positions``..``positions + W - 1`` FIRST, in place
+    (``_write_cache_rows``), then slices the pages back out and attends
+    with the per-query mask ``index <= position + offset`` — so every
+    freshly written position is visible to the queries at or after it and
+    stale cache beyond them never is. The attention math mirrors
+    ring_attention.reference_attention (same score scale, same -1e30 mask,
+    jax.nn.softmax): the masked terms underflow to exact zeros, so the
+    padded reduction is bitwise the oracle's unpadded one. W=1 is the
+    decode hot path; W=k+1 is the speculative verify step (ISSUE 16) —
+    the same math, so verify logits at offset i are exactly what i
+    sequential decode steps over the same tokens would produce."""
     with jax.named_scope("lm_attn"):
         hn = _layernorm(h, layer_params["ln_g"], layer_params["ln_b"])
         q = _split_heads(hn @ layer_params["wq"], n_heads)  # (S, H, W, Dh)
         k_new = _split_heads(hn @ layer_params["wk"], n_heads)
         v_new = _split_heads(hn @ layer_params["wv"], n_heads)
         with jax.named_scope("lm_cache_write"):
-            write = jax.vmap(
-                lambda c, kn, p: jax.lax.dynamic_update_slice_in_dim(
-                    c, kn.astype(c.dtype), p, axis=1))
-            ck = write(ck, k_new, positions)
-            cv = write(cv, v_new, positions)
-        scores = jnp.einsum("shqd,shkd->shqk", q, ck) / jnp.sqrt(
+            ck, cv = _write_cache_rows(ck, cv, k_new, v_new, layer, slot0,
+                                       positions)
+        pages = lambda c: jax.lax.dynamic_slice(  # noqa: E731
+            c, (layer, slot0, 0, 0, 0), (1, h.shape[0]) + c.shape[2:])[0]
+        ck_l, cv_l = pages(ck), pages(cv)                 # (S, H, T_max, Dh)
+        scores = jnp.einsum("shqd,shkd->shqk", q, ck_l) / jnp.sqrt(
             q.shape[-1] * 1.0)                            # (S, H, W, T_max)
         pos_q = positions[:, None] + jnp.arange(h.shape[1])[None, :]  # (S, W)
-        mask = (jnp.arange(ck.shape[2])[None, None, None, :]
+        mask = (jnp.arange(ck_l.shape[2])[None, None, None, :]
                 <= pos_q[:, None, :, None])
         scores = jnp.where(mask, scores, -1e30)
-        o = jnp.einsum("shqk,shkd->shqd", jax.nn.softmax(scores, -1), cv)
+        o = jnp.einsum("shqk,shkd->shqd", jax.nn.softmax(scores, -1), cv_l)
         # f32 score math, carry-dtype residual (identity at f32:
         # parity-safe)
         h = h + (_merge_heads(o) @ layer_params["wo"]).astype(h.dtype)
     return _dense_moe_ffn(layer_params, h, top_k), ck, cv
 
 
+def _cached_layers(params: dict, cache: dict, h: Array, positions: Array,
+                   n_heads: int, top_k: int, slot0=0) -> tuple:
+    """The one layer loop of decode, verify and chunked prefill: h (S, W,
+    d), the rows of slots ``slot0``..``slot0 + S - 1``, through every
+    block, each attending over the cache. The loop scans the stacked block
+    params with the layer's index and CARRIES ``(h, cache k, cache v)``,
+    both leaves whole: a scanned cache would be sliced a layer at a time
+    on the way in and stacked into a second cache on the way out, six
+    whole-cache copies a step for a few rows stored. Returns (cache, h)."""
+    slot0 = jnp.asarray(slot0, jnp.int32)
+
+    def step(carry, xs):
+        h, ck, cv = carry
+        layer_params, layer = xs
+        return _decode_block(layer_params, h, ck, cv, layer, slot0,
+                             positions, n_heads, top_k), None
+
+    layers = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+    (h, ck, cv), _ = jax.lax.scan(
+        step, (h, cache["k"], cache["v"]), (params["blocks"], layers))
+    return {"k": ck, "v": cv}, h
+
+
 def lm_decode_step(params: dict, cache: dict, tokens: Array,
                    positions: Array, n_heads: int, top_k: int = 2) -> tuple:
     """One decode iteration over every slot: tokens (S,) int32 land at
     ``positions`` (S,) in the cache and next-token logits (S, V) come back
-    with the updated cache. The layer stack scans the stacked block params
-    AND the cache's layer axis together, so depth costs one trace."""
+    with the updated cache. The cache rides through the layer loop as its
+    carry (``_cached_layers``): layer l stores S rows at ``[l, s, :,
+    positions[s], :]`` and every other element comes back untouched, in
+    the same buffers when the caller donated them."""
     with jax.named_scope("lm_embed"):
         h = params["embed"][tokens][:, None, :]           # (S, 1, d)
-
-    def step(h, xs):
-        layer_params, ck, cv = xs
-        h, ck, cv = _decode_block(layer_params, h, ck, cv, positions,
-                                  n_heads, top_k)
-        return h, (ck, cv)
-
-    h, (cks, cvs) = jax.lax.scan(
-        step, h, (params["blocks"], cache["k"], cache["v"]))
+    cache, h = _cached_layers(params, cache, h, positions, n_heads, top_k)
     logits = (h @ params["dec_w"] + params["dec_b"])[:, 0, :]
-    return {"k": cks, "v": cvs}, logits
+    return cache, logits
 
 
 def sample_tokens(logits: Array, key: Array, temperature: Array) -> Array:
@@ -1089,17 +1148,8 @@ def lm_verify_step(params: dict, cache: dict, tokens: Array,
     would silently overwrite live earlier positions)."""
     with jax.named_scope("lm_embed"):
         h = params["embed"][tokens]                       # (S, W, d)
-
-    def step(h, xs):
-        layer_params, ck, cv = xs
-        h, ck, cv = _decode_block(layer_params, h, ck, cv, positions,
-                                  n_heads, top_k)
-        return h, (ck, cv)
-
-    h, (cks, cvs) = jax.lax.scan(
-        step, h, (params["blocks"], cache["k"], cache["v"]))
-    logits = h @ params["dec_w"] + params["dec_b"]        # (S, W, V)
-    return {"k": cks, "v": cvs}, logits
+    cache, h = _cached_layers(params, cache, h, positions, n_heads, top_k)
+    return cache, h @ params["dec_w"] + params["dec_b"]   # (S, W, V)
 
 
 def make_verify_step(n_heads: int, top_k: int = 2, donate_cache: bool = True,
@@ -1156,27 +1206,13 @@ def make_chunk_prefill_step(n_heads: int, top_k: int = 2,
         with jax.named_scope("lm_embed"):
             h = params["embed"][tokens]                   # (1, W, d)
         pos = jnp.asarray(start, jnp.int32)[None]         # (1,)
-
-        def step(h, xs):
-            layer_params, ck, cv = xs
-            ck_s = jax.lax.dynamic_index_in_dim(ck, slot, 0, keepdims=True)
-            cv_s = jax.lax.dynamic_index_in_dim(cv, slot, 0, keepdims=True)
-            h, ck_s, cv_s = _decode_block(layer_params, h, ck_s, cv_s,
-                                          pos, n_heads, top_k)
-            with jax.named_scope("lm_cache_write"):
-                ck = jax.lax.dynamic_update_slice_in_dim(ck, ck_s, slot,
-                                                         axis=0)
-                cv = jax.lax.dynamic_update_slice_in_dim(cv, cv_s, slot,
-                                                         axis=0)
-            return h, (ck, cv)
-
-        h, (cks, cvs) = jax.lax.scan(
-            step, h, (params["blocks"], cache["k"], cache["v"]))
+        cache, h = _cached_layers(params, cache, h, pos, n_heads, top_k,
+                                  slot0=slot)
         logits = (h @ params["dec_w"] + params["dec_b"])[0]  # (W, V)
         last = jax.lax.dynamic_index_in_dim(logits, last_idx, 0,
                                             keepdims=False)
         k = jax.random.fold_in(key, step_idx)
-        return {"k": cks, "v": cvs}, sample_tokens(last, k, temp)
+        return cache, sample_tokens(last, k, temp)
 
     return chunk
 
