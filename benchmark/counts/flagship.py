@@ -70,9 +70,3 @@ def decode_step_bytes(dims: dict, positions, weight_bytes: int = 2,
     kv = sum(2 * (p + 1) * d * kv_bytes for p in positions)
     ends = (live * d + d * v + v) * weight_bytes
     return n_layers * (per_layer + kv) + ends
-
-
-def roofline_seconds(flops: float, nbytes: float, peaks: dict) -> float:
-    """The least time the chip could take: the larger of operations over
-    peak operations per second and bytes over peak bytes per second."""
-    return max(flops / peaks["bf16_flops"], nbytes / peaks["hbm_bytes_per_s"])

@@ -1,5 +1,11 @@
-"""Drives ``serve/engine.py``'s DecodeEngine in-process with generated
-traffic: one thread that submits what is due and calls ``engine.step()``.
+"""Drives a serving engine in-process with generated traffic: one thread
+that submits what is due and calls ``engine.step()``. The engine, its
+counters, its steps' records and the comparison that decides ``correct`` are
+the cell's model's (``models/<model>.py``); of the engine this file uses
+``submit(prompt, max_new_tokens=)``, ``step()``, ``has_work()``,
+``run_until_idle()``, ``bucket_for(n)``, ``n_slots`` and ``max_len``, and of a
+request its stamps (``t_submit``, ``t_admit``, ``t_first``, ``t_done``,
+``t_tokens``), ``prompt``, ``generated`` and ``done``.
 
 The traffic generator is general: a traffic file gives the loop (open at a
 fixed rate, or closed with a fixed number of clients) and the two length
@@ -17,8 +23,8 @@ import time
 
 import numpy as np
 
-from benchmark.counts import flagship as counts
-from benchmark.reference import flagship_ref as ref
+from benchmark.harness import registry
+from benchmark.trace.reduce import scopes_by_program
 
 DRAIN_SECONDS = 60.0
 _NORMAL = statistics.NormalDist()
@@ -84,36 +90,19 @@ class Driver:
         self.traffic = cell["traffic_data"]
         self.seed = int(seed)
         self.devices = devices
-        self.dims = ref.dims_of(self.config)
+        self.model = registry.load_model(cell)
+        self.dims = self.model.dims_of(self.config)
         self.engine = None
 
     # -- set-up ---------------------------------------------------------------
     def setup(self, tamper=None) -> dict:
         """``tamper(driver)`` lets a test break the timed path once it is
         built and before anything runs through it."""
-        import jax
-        import jax.numpy as jnp
-
-        from deeplearning4j_tpu.models.transformer_lm import init_lm_params
-        from deeplearning4j_tpu.serve.engine import DecodeEngine
         from deeplearning4j_tpu.telemetry.registry import MetricsRegistry
 
-        d, s = self.dims, self.config["serve"]
-        dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32}[s["serve_dtype"]]
-
-        def make(key):
-            p = init_lm_params(key, d["vocab"], d["d_model"], d["n_heads"],
-                               d["n_experts"], d["d_ff"], d["n_layers"])
-            return jax.tree_util.tree_map(lambda w: w.astype(dtype), p)
-
-        params = jax.jit(make)(ref.seed_key(self.seed))
         self.registry = MetricsRegistry()
-        self.engine = DecodeEngine(
-            params, d["n_heads"], n_slots=s["n_slots"], max_len=s["max_len"],
-            top_k=d["top_k"], serve_dtype=s["serve_dtype"],
-            min_bucket=s["min_bucket"], registry=self.registry, tuned=False,
-            runprof=False, seed=self.seed & 0x7FFFFFFF)
-        del params
+        self.engine = self.model.build_serve(self.config, self.seed,
+                                             self.registry)
         if tamper is not None:
             tamper(self)
         t0 = time.perf_counter()
@@ -123,14 +112,17 @@ class Driver:
     def _grid(self) -> int:
         return int(self.traffic.get("grid", 256))
 
+    def _buckets(self) -> list:
+        """The prefill buckets this traffic can reach."""
+        lens = length_grid(self.traffic["prompt_len"], self._grid())
+        return sorted({self.engine.bucket_for(int(n)) for n in lens})
+
     def _warm_up(self) -> None:
         """One request through each prefill bucket this traffic can reach,
         and the decode step: nothing else is compiled."""
         e = self.engine
-        lens = length_grid(self.traffic["prompt_len"], self._grid())
-        buckets = sorted({e.bucket_for(int(n)) for n in lens})
         rng = np.random.default_rng(0)
-        for b in buckets:
+        for b in self._buckets():
             n = min(b, e.max_len - 1)
             e.submit(rng.integers(0, self.dims["vocab"], size=n).tolist(),
                      max_new_tokens=2)
@@ -211,17 +203,7 @@ class Driver:
                 "traced": (traced.t0, traced.t1)}
 
     def _counters(self) -> dict:
-        e = self.engine
-        return {
-            "decode_steps": e.decode_steps,
-            "occupancy_sum": e._occupancy_sum,
-            "prefill_dispatches": self.registry.counter(
-                "serve_prefill_dispatches_total").value,
-            "decode_ms_sum": self.registry.histogram(
-                "serve_decode_step_ms").snapshot()["sum"],
-            "prefill_ms_sum": self.registry.histogram(
-                "serve_prefill_ms").snapshot()["sum"],
-        }
+        return self.model.serve_counters(self.engine, self.registry)
 
     # -- what the window showed -------------------------------------------------
     def end_to_end(self, rec: dict) -> dict:
@@ -247,7 +229,10 @@ class Driver:
 
     def summary(self, rec: dict) -> dict:
         """The window as plain numbers, for the per-layer readers: the
-        program's objects do not outlive ``release``."""
+        program's objects do not outlive ``release``. ``steps`` is the
+        model's record of each step, ``[stamp, slots]``, which its own
+        counts of work read; ``scopes``, of a traced run alone, names the
+        operations of the programs the window ran."""
         reqs, due = rec["requests"], rec["due"]
         e = self.engine
         rows = []
@@ -258,9 +243,14 @@ class Driver:
                 "t_tokens": list(r.t_tokens), "prompt_len": len(r.prompt),
                 "bucket": e.bucket_for(len(r.prompt)),
                 "generated": len(r.generated), "done": r.done.is_set()})
-        return {"kind": "serve", "loop": rec["loop"], "t0": rec["t0"],
-                "t_end": rec["t_end"], "seconds": rec["seconds"],
-                "traced": rec["traced"], "requests": rows,
+        scopes = None
+        if rec["traced"][0] is not None:
+            scopes = scopes_by_program(
+                self.model.serve_programs(e, self._buckets()))
+        return {"kind": "serve", "scopes": scopes, "loop": rec["loop"],
+                "t0": rec["t0"], "t_end": rec["t_end"],
+                "seconds": rec["seconds"], "traced": rec["traced"],
+                "requests": rows, "steps": self.model.serve_steps(reqs),
                 "ticks": rec["ticks"], "counters": rec["counters"],
                 "dims": self.dims, "n_slots": e.n_slots,
                 "max_len": e.max_len}
@@ -289,58 +279,28 @@ class Driver:
         return [longest] + [rest[i] for i in picks]
 
     def check(self, rec: dict, sampled: list, control_via=None) -> tuple:
-        """After ``release``: how far a served token's logit lies below the
-        reference's best, over the sampled requests: the widest such gap
-        (a wrong token reads several units) and the mean (the noise of the
-        arithmetic, which a lower precision multiplies)."""
-        limits = self.config["correct"]
-        attempted, failed = self.attempted_failed(rec)
-        # no finished request to follow reads as a gap no limit admits
-        gaps = self.gaps(sampled, control_via) if sampled else [1e30]
-        compared = {
-            "requests_never_finished": [float(failed), 0.0],
-            "widest_logit_gap": [float(np.max(gaps)),
-                                 float(limits["widest_logit_gap"])],
-            "mean_logit_gap": [float(np.mean(gaps)),
-                               float(limits["mean_logit_gap"])]}
+        """After ``release``: no request of an open loop left unfinished,
+        and the model's own comparison of the sampled requests with its
+        plain reference (with ``control_via``, of the control in that
+        type)."""
+        _, failed = self.attempted_failed(rec)
+        compared = {"requests_never_finished": [float(failed), 0.0],
+                    **self.model.serve_compare(self.config, self.traffic,
+                                               self.seed, sampled,
+                                               control_via)}
         return all(v <= lim for v, lim in compared.values()), compared
 
-    def gaps(self, sampled: list, control_via=None) -> np.ndarray:
-        """One shape whatever the seed drew (``check_requests`` rows as wide
-        as the mix's longest request can be), so that the reference compiles
-        once a cell."""
-        rows = [list(r.prompt) + list(r.generated) for r in sampled]
-        n_rows = max(int(self.traffic["check_requests"]), len(rows))
-        t = self.traffic
-        longest = int(t["prompt_len"]["max"]) + int(t["answer_len"]["max"])
-        width = min(-(-longest // 256) * 256,
-                    int(self.config["serve"]["max_len"]))
-        tokens = np.zeros((n_rows, width), np.int32)
-        lengths = np.ones(n_rows, np.int64)  # a spare row judges nothing
-        prompts = np.ones(n_rows, np.int64)
-        for i, (x, r) in enumerate(zip(rows, sampled)):
-            tokens[i, :len(x)] = x
-            lengths[i], prompts[i] = len(x), len(r.prompt)
-        gaps = ref.serve_logit_gaps(
-            self.seed, self.dims, tokens, lengths, prompts,
-            weights_via=self.config["precision"]["weights"],
-            control_via=control_via, span=int(t["answer_len"]["max"]))
-        return np.concatenate(gaps)
 
-
-def count_work(summary: dict) -> tuple:
-    """(required operations, per-step records) of everything the window
-    processed: each prefill, and each decode step rebuilt from the token
-    stamps (tokens accepted in one step share one stamp)."""
+def required_flops(summary: dict, model) -> float:
+    """Required operations of everything the window processed, by the
+    model's own counts: each prefill, and each step from its record."""
     dims = summary["dims"]
-    flops, steps = 0.0, {}
     lo, hi = summary["t0"], summary["t_end"]
+    flops = 0.0
     for r in summary["requests"]:
         if r["t_first"] is not None and lo <= r["t_first"] <= hi:
-            flops += counts.prefill_flops(dims, r["prompt_len"])
-        for j, stamp in enumerate(r["t_tokens"][1:]):
-            steps.setdefault(stamp, []).append(r["prompt_len"] + j)
-    for stamp, positions in steps.items():
+            flops += model.prefill_flops(dims, r["prompt_len"])
+    for stamp, slots in summary["steps"]:
         if lo <= stamp <= hi:
-            flops += counts.decode_flops(dims, positions)
-    return flops, steps
+            flops += model.step_flops(dims, slots)
+    return flops

@@ -21,3 +21,9 @@ def peaks_for(device_kind: str) -> dict:
         raise KeyError(f"no published peaks for device kind {device_kind!r}; "
                        f"known: {sorted(PEAKS)}. Add a row with its source.")
     return PEAKS[device_kind]
+
+
+def roofline_seconds(flops: float, nbytes: float, peaks: dict) -> float:
+    """The least time the chip could take: the larger of operations over
+    peak operations per second and bytes over peak bytes per second."""
+    return max(flops / peaks["bf16_flops"], nbytes / peaks["hbm_bytes_per_s"])

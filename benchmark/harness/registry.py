@@ -1,9 +1,17 @@
-"""Finds everything by the name BENCHMARK.json gives it.
+"""Finds everything by the name a data file gives it.
 
-A cell names a configuration and a traffic mix; each is a data file. A
-per-layer metric ``x`` or ``x.suffix`` is read by ``metrics/x.py``. The
-driver comes from the traffic file's ``kind``. Nothing here lists names, so a
-later PR adds files and entries and edits nothing.
+A cell of BENCHMARK.json names a configuration and a traffic mix; each is a
+data file. The configuration's ``model`` is ``models/<model>.py``: the one
+place that knows an architecture (its program, its plain reference, its
+counts of work, the faults its tests plant). The traffic file's ``driver`` is
+``drivers/<driver>.py``: the loop that offers that kind of load. A per-layer
+metric ``x`` or ``x.suffix`` is read by ``metrics/x.py``. This file holds no
+table of names and reads no key of a configuration beyond ``model``, so a
+later PR adds files and entries and edits nothing; a name with no file is
+refused with the list of the files there are.
+
+``ROOT`` and ``BENCH_DIR`` are where BENCHMARK.json and the benchmark's
+directories are looked for (a test points them at a copy).
 """
 
 from __future__ import annotations
@@ -11,11 +19,11 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 BENCH_DIR = os.path.join(ROOT, "benchmark")
-DRIVERS = {"serve": "serve_engine", "train": "train_step"}
 
 
 def load_benchmark() -> dict:
@@ -40,34 +48,43 @@ def load_cell(bench: dict, name: str) -> dict:
     config = _load_json(os.path.join(ROOT, entry["file"]))
     traffic = _load_json(os.path.join(BENCH_DIR, "traffic",
                                       cell["traffic"] + ".json"))
-    if traffic["kind"] not in DRIVERS:
-        raise KeyError(f"traffic kind {traffic['kind']!r} has no driver; "
-                       f"known: {sorted(DRIVERS)}")
     return {**cell, "config_data": config, "traffic_data": traffic}
 
 
-def _import_file(path: str, name: str):
-    spec = importlib.util.spec_from_file_location(name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
+def load_part(kind: str, name: str):
+    """The module ``<kind>/<name>.py`` of the benchmark, whatever files a
+    later PR has put there. A model's file finds its reference and its
+    counts this way too. Loaded once a path: its jitted functions keep
+    their compiled programs from one cell to the next."""
+    folder = os.path.join(BENCH_DIR, kind)
+    path = os.path.join(folder, name + ".py")
+    if not os.path.exists(path):
+        there = sorted(f[:-3] for f in os.listdir(folder)
+                       if f.endswith(".py") and f != "__init__.py")
+        raise FileNotFoundError(f"no {path}: benchmark/{kind}/ has {there}")
+    key = f"benchmark_{kind}_{name}"
+    module = sys.modules.get(key)
+    if module is None or module.__file__ != path:
+        spec = importlib.util.spec_from_file_location(key, path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        sys.modules[key] = module
     return module
 
 
-def load_driver(kind: str):
-    return _import_file(os.path.join(BENCH_DIR, "drivers",
-                                     DRIVERS[kind] + ".py"),
-                        "benchmark_driver_" + kind)
+def load_driver(cell: dict):
+    return load_part("drivers", cell["traffic_data"]["driver"])
+
+
+def load_model(cell: dict):
+    return load_part("models", cell["config_data"]["model"])
 
 
 def metric_reader(metric_name: str):
     """``read(run) -> float | None`` of the metric's own file; the part
     after the first dot only says which cells' end-to-end metric it moves."""
     base = metric_name.split(".", 1)[0]
-    path = os.path.join(BENCH_DIR, "metrics", base + ".py")
-    if not os.path.exists(path):
-        raise FileNotFoundError(f"per-layer metric {metric_name!r} has no "
-                                f"reader at {path}")
-    return _import_file(path, "benchmark_metric_" + base).read
+    return load_part("metrics", base).read
 
 
 def metrics_for(bench: dict, group: str, cell_name: str) -> list:

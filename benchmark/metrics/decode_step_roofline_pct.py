@@ -1,22 +1,21 @@
 """The least time the chip could take for the decode steps of the traced
 part (the larger of required operations over peak and required bytes over
-bandwidth, each step) over the decode program's device time there."""
+bandwidth, each step from its own record by the counts of the cell's model)
+over the decode program's device time there."""
 
-from benchmark.counts import flagship as counts
-from benchmark.drivers.serve_engine import count_work
+from benchmark.harness.peaks import roofline_seconds
 
 
 def read(run):
-    t, s = run["trace"], run["summary"]
+    t, s, model = run["trace"], run["summary"], run["model"]
     if run["peaks"] is None or s["traced"][0] is None:
         return None
     device_s = t["by_program"].get("jit_step", 0.0) * t["devices"]
     if device_s <= 0:
         return None
     lo, hi = s["traced"]
-    _, steps = count_work(s)
-    least = sum(counts.roofline_seconds(
-        counts.decode_flops(s["dims"], pos),
-        counts.decode_step_bytes(s["dims"], pos), run["peaks"])
-        for stamp, pos in steps.items() if lo <= stamp <= hi)
+    least = sum(roofline_seconds(model.step_flops(s["dims"], slots),
+                                 model.step_bytes(s["dims"], slots),
+                                 run["peaks"])
+                for stamp, slots in s["steps"] if lo <= stamp <= hi)
     return 100.0 * least / device_s if least > 0 else None

@@ -1,15 +1,16 @@
 """The whole serve step's share of the chip's peak: required operations of
-every prompt and output token processed in the window (top-k experts only,
-real prompt tokens only) over chips x peak x window."""
+every prompt and output token processed in the window (by the counts of the
+cell's model: top-k experts only, real prompt tokens only) over chips x peak
+x window."""
 
-from benchmark.drivers.serve_engine import count_work
+from benchmark.drivers.serve_engine import required_flops
 
 
 def read(run):
     if run["peaks"] is None:
         return None
     s = run["summary"]
-    flops, _ = count_work(s)
+    flops = required_flops(s, run["model"])
     if flops <= 0:
         return None
     return 100.0 * flops / (run["device"]["count"]

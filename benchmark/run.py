@@ -34,8 +34,7 @@ def run_cell(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
     object. ``tamper(driver)`` lets a test break the timed path."""
     t_start = T_START if t_start is None else t_start
     counter = window.CompileCounter()
-    driver = registry.load_driver(cell["traffic_data"]["kind"]).Driver(
-        cell, seed, devices)
+    driver = registry.load_driver(cell).Driver(cell, seed, devices)
     setup = driver.setup(tamper)
     requests, hits = counter.snapshot()
     traced = window.TracedPart(trace, cell["name"])
@@ -66,7 +65,8 @@ def run_cell(cell: dict, bench: dict, seed: int, seconds: float, trace: bool,
         device["busy_s"] = tsum["busy_s"]
         device["window_s"] = tsum["window_s"]
         breakdown = trace_reduce.breakdown(tsum)
-        run = {"cell": cell, "summary": summary, "trace": tsum,
+        run = {"cell": cell, "summary": summary, "model": driver.model,
+               "trace": tsum,
                "values": values, "device": device,
                "peaks": peaks_for(devices[0].device_kind) if
                devices[0].platform != "cpu" else None,

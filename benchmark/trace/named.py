@@ -73,13 +73,9 @@ def lm_share_pct(run: dict, needle: str):
     """Device time of operations whose scope holds ``needle`` over busy
     time, in percent. A program whose cached executable predates the
     scopes reads 0 (and ``unscoped_share_of_busy_pct`` 100): metadata is
-    not part of the compile cache's key. The import is the probe: a program
-    that names no ``lm_*`` scope has no ``LM_SCOPES``."""
-    try:
-        from deeplearning4j_tpu.models.transformer_lm import LM_SCOPES  # noqa: F401
-    except ImportError:
-        return None
-    if not run["summary"].get("scopes"):
+    not part of the compile cache's key. The cell's model says whether its
+    program names scopes at all (a commit before them names none)."""
+    if not run["model"].program_scopes() or not run["summary"].get("scopes"):
         return None
     t = run["trace"]
     return 100.0 * scope_seconds(t, needle) / t["busy_s"]

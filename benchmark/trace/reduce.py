@@ -36,6 +36,26 @@ def scopes_from_hlo(hlo_text: str) -> dict:
         re.MULTILINE)}
 
 
+def scopes_by_program(hlo_texts: list) -> dict:
+    """{"<program>/<instruction>": ``op_name``} over several compiled
+    programs, for a window that runs more than one: two programs number
+    their instructions alike. Where two texts of one program (a prefill at
+    two bucket sizes) give one instruction different ``op_name``s, it keeps
+    the path the two share."""
+    out = {}
+    for text in hlo_texts:
+        program = re.match(r"HloModule ([\w.\-]+)", text).group(1)
+        for name, scope in scopes_from_hlo(text).items():
+            key = program + "/" + name
+            if key in out and out[key] != scope:
+                a, b = out[key].split("/"), scope.split("/")
+                n = next((i for i, (x, y) in enumerate(zip(a, b)) if x != y),
+                         min(len(a), len(b)))
+                scope = "/".join(a[:n])
+            out[key] = scope
+    return out
+
+
 def load_events(xplane_path: str) -> dict:
     """{"devices": [{"name", "ops": [[name, start, dur]], "modules":
     [[name, start, dur]]}], "host": [[thread, name, start, dur]]}"""
@@ -108,12 +128,18 @@ def leaves(ops: list) -> list:
 
 
 def reduce_events(events: dict, scopes: dict = None) -> dict:
-    """``scopes`` is ``scopes_from_hlo`` of the traced programs, where a
-    reader wants device time by named scope."""
+    """``scopes`` is ``scopes_from_hlo`` of the traced program, or
+    ``scopes_by_program`` of several, where a reader wants device time by
+    named scope."""
     scopes = scopes or {}
+
+    def scope_of(program, name):
+        return scopes.get(program + "/" + name, scopes.get(name, ""))
+
     lo, hi = _window(events)
     window_s = (hi - lo) / 1e9
     busy, by_op, by_program, program_calls = [], {}, {}, {}
+    op_scopes = {}
     scoped = []
     fullest = None
     for di, dev in enumerate(events["devices"]):
@@ -132,12 +158,15 @@ def reduce_events(events: dict, scopes: dict = None) -> dict:
             a, b = max(s, lo), min(s + d, hi)
             if b > a:
                 clipped.append((a, b))
-                scoped.append((scopes.get(name, ""), program_at(s), a, b, di))
+                program = program_at(s)
+                scoped.append((scope_of(program, name), program, a, b, di))
         for name, s, d in leaves(dev["ops"]):
             a, b = max(s, lo), min(s + d, hi)
             if b > a:
-                key = program_at(s) + "/" + name
+                program = program_at(s)
+                key = program + "/" + name
                 by_op[key] = by_op.get(key, 0.0) + (b - a) / 1e9
+                op_scopes[key] = scope_of(program, name)
         for s, e, program in modules:
             a, b = max(s, lo), min(e, hi)
             if b > a:
@@ -160,6 +189,7 @@ def reduce_events(events: dict, scopes: dict = None) -> dict:
         "busy_s_fullest": fullest[0],
         "devices": n_dev,
         "by_op": {k: v / n_dev for k, v in by_op.items()},
+        "op_scopes": op_scopes,
         "by_program": {k: v / n_dev for k, v in by_program.items()},
         "program_calls": program_calls,
         "scoped": scoped,
@@ -214,5 +244,12 @@ def top(d: dict, n: int = 10) -> list:
 
 
 def breakdown(summary: dict) -> dict:
-    return {"device_ops": top(summary["by_op"]),
+    """The ten longest operations and idle gaps. An operation whose scope
+    is known carries it: ``jit_step/fusion.2 <while/body/lm_moe/dot_general>``
+    (its ``op_name`` without the leading ``jit(...)``)."""
+    def label(key):
+        scope = summary["op_scopes"].get(key, "").partition("/")[2]
+        return f"{key} <{scope}>" if scope else key
+
+    return {"device_ops": [[label(k), v] for k, v in top(summary["by_op"])],
             "idle_gaps": top(summary["idle_gaps"])}

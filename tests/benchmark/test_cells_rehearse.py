@@ -21,33 +21,25 @@ from benchmark.harness import peaks, registry  # noqa: E402
 
 BENCH = registry.load_benchmark()
 CELLS = [c["name"] for c in BENCH["workloads"]]
-TINY = {"hidden_size": 32, "intermediate_size": 16, "num_attention_heads": 2,
-        "vocab_size": 64}
 
 
-def tiny_cell(name: str) -> dict:
-    """The cell as BENCHMARK.json names it, its files read by name, with the
-    widths and lengths cut so that a CPU holds it."""
-    cell = copy.deepcopy(registry.load_cell(BENCH, name))
-    config, traffic = cell["config_data"], cell["traffic_data"]
-    config.update(TINY)
-    config["num_key_value_heads"] = 2
-    for key in ("num_experts", "num_local_experts"):
-        if key in config:
-            config[key] = 4
-    config["num_experts_per_tok"] = 2
-    if traffic["kind"] == "serve":
-        config["serve"].update(n_slots=4, max_len=64, min_bucket=8,
-                               serve_dtype="f32")
-        config["precision"]["weights"] = "float32"
-        config["correct"] = {"widest_logit_gap": 1e-3, "mean_logit_gap": 1e-4}
-        traffic["prompt_len"].update(min=4, max=40, median=12)
-        traffic["answer_len"].update(min=4, max=8, median=6)
-        traffic.update(rate_per_s=40.0, clients=6, grid=16, check_requests=12)
-    else:
-        traffic.update(seq_len=64, batch_sequences=4, pool=4)
-        config["correct"] = {"loss_gap": 1e-4, "grad_norm_gap": 1e-3,
-                             "change_norm_gap": 1e-3}
+def overlay(base: dict, cut: dict) -> None:
+    """``cut`` laid over ``base``: a group into the group, a value in the
+    value's place."""
+    for key, value in cut.items():
+        if isinstance(value, dict) and isinstance(base.get(key), dict):
+            overlay(base[key], value)
+        else:
+            base[key] = value
+
+
+def tiny_cell(name: str, bench: dict = BENCH) -> dict:
+    """The cell as BENCHMARK.json names it, its files read by name, cut to
+    what a CPU holds by the ``rehearse`` block that its configuration file
+    and its traffic file each carry: this function knows no key of either."""
+    cell = copy.deepcopy(registry.load_cell(bench, name))
+    for data in (cell["config_data"], cell["traffic_data"]):
+        overlay(data, data.pop("rehearse"))
     return cell
 
 
@@ -78,6 +70,32 @@ def test_cell_rehearses_and_is_correct(name, results):
     results[name] = line
 
 
+def test_a_traced_serve_summary_names_the_scopes_of_its_programs():
+    """What the serve driver hands the trace reduction in a traced run: the
+    decode program's and the reached prefill programs' own scopes, by
+    program and instruction."""
+    from benchmark.harness import window
+
+    name = next(n for n in CELLS if registry.load_cell(BENCH, n)[
+        "traffic_data"]["kind"] == "serve")
+    cell = tiny_cell(name)
+    driver = registry.load_driver(cell).Driver(cell, 3, cpu_devices(1))
+    driver.setup()
+    rec = driver.window(0.2, window.TracedPart(False, name))
+    assert driver.summary(rec)["scopes"] is None  # not traced: none kept
+    rec["traced"] = (rec["t0"], rec["t_end"])
+    scopes = driver.summary(rec)["scopes"]
+    driver.release()
+    programs = {key.split("/")[0] for key in scopes}
+    assert programs == {"jit_step", "jit_prefill"}
+    wanted = set(driver.model.program_scopes()) - {"lm_loss", "lm_update"}
+    for program in programs:
+        named = {part for key, scope in scopes.items()
+                 if key.startswith(program + "/")
+                 for part in scope.split("/") if part.startswith("lm_")}
+        assert named >= wanted - {"lm_cache_write"}, program
+
+
 @pytest.mark.parametrize("name", CELLS)
 def test_every_metric_of_the_cell_has_its_reader(name):
     per_layer = registry.metrics_for(BENCH, "per_layer", name)
@@ -92,8 +110,9 @@ def test_every_metric_of_the_cell_has_its_reader(name):
 @pytest.mark.parametrize("traffic", sorted(
     {c["traffic"] for c in BENCH["workloads"]}))
 def test_two_seeds_offer_the_same_work(traffic):
-    spec = json.load(open(os.path.join(registry.BENCH_DIR, "traffic",
-                                       traffic + ".json")))
+    with open(os.path.join(registry.BENCH_DIR, "traffic",
+                           traffic + ".json")) as f:
+        spec = json.load(f)
     if spec["kind"] != "serve":
         # a train job has one shape: what the seed draws is the rows
         assert spec["seq_len"] * spec["batch_sequences"] >= 4096

@@ -18,15 +18,22 @@ from benchmark import run as bench_run  # noqa: E402
 from benchmark.harness import registry, window  # noqa: E402
 from test_cells_rehearse import BENCH, cpu_devices, tiny_cell  # noqa: E402
 
-SERVE = [c["name"] for c in BENCH["workloads"]
-         if registry.load_cell(BENCH, c["name"])["traffic_data"]["kind"]
-         == "serve"]
-TRAIN = [c["name"] for c in BENCH["workloads"] if c["name"] not in SERVE]
+CELLS = {c["name"]: registry.load_cell(BENCH, c["name"])
+         for c in BENCH["workloads"]}
+SERVE = [n for n, c in CELLS.items() if c["traffic_data"]["kind"] == "serve"]
+TRAIN = [n for n in CELLS if n not in SERVE]
+# the faults each cell can have, planted by its model's own file: they
+# reach into the program's attributes, which another model names otherwise
+FAULTS = [(n, f) for n in TRAIN
+          for f in registry.load_model(CELLS[n]).faults(CELLS[n])]
 
 
-def run(name, tamper=None, seed=7):
-    cell = tiny_cell(name)
-    out = bench_run.run_cell(cell, BENCH, seed=seed, seconds=0.5, trace=False,
+def run(name, fault=None, seed=7, bench=BENCH):
+    """One rehearsal of the cell through ``run_cell``, with the named fault
+    of its model planted under the timed path; the result line's object."""
+    cell = tiny_cell(name, bench)
+    tamper = registry.load_model(cell).faults(cell)[fault] if fault else None
+    out = bench_run.run_cell(cell, bench, seed=seed, seconds=0.5, trace=False,
                              devices=cpu_devices(cell["chips"]),
                              tamper=tamper)
     return json.loads(out["line"])
@@ -35,7 +42,7 @@ def run(name, tamper=None, seed=7):
 def driven(name, seed=7):
     """A driver after its window, released: ready for ``check``."""
     cell = tiny_cell(name)
-    driver = registry.load_driver(cell["traffic_data"]["kind"]).Driver(
+    driver = registry.load_driver(cell).Driver(
         cell, seed, cpu_devices(cell["chips"]))
     driver.setup()
     rec = driver.window(0.5, window.TracedPart(False, name))
@@ -46,24 +53,9 @@ def driven(name, seed=7):
 
 # ------------------------------------------------------------------ serve ----
 
-def alter_a_token(driver):
-    """A token altered where it is produced: every third decode step hands
-    back the next id for every slot."""
-    decode, calls = driver.engine._decode, [0]
-
-    def broken(*args):
-        cache, toks = decode(*args)
-        calls[0] += 1
-        if calls[0] % 3 == 0:
-            toks = (toks + 1) % driver.dims["vocab"]
-        return cache, toks
-
-    driver.engine._decode = broken
-
-
 @pytest.mark.parametrize("name", SERVE)
 def test_an_altered_token_is_not_correct(name):
-    line = run(name, tamper=alter_a_token)
+    line = run(name, fault="alter_a_token")
     gap, limit = line["compared"]["widest_logit_gap"]
     assert not line["correct"] and gap > limit
 
@@ -90,55 +82,14 @@ def test_a_request_that_never_finishes_is_not_correct():
 
 # ------------------------------------------------------------------ train ----
 
-def state_unchanged(driver):
-    step = driver.step
-    driver.step = lambda p, tok, tgt: (p, step(_copy(p), tok, tgt)[1])
-
-
-def _copy(tree):
-    import jax
-
-    return jax.tree_util.tree_map(lambda x: x + 0, tree)  # the step donates
-
-
-def half_the_batch(driver):
-    step = driver.step
-
-    def broken(p, tok, tgt):
-        half = tok.shape[0] // 2
-        return step(p, tok[:half], tgt[:half])
-
-    driver.step = broken
-
-
-def no_exchange(driver):
-    """The exchange between chips left out: each data row's chips train
-    alone, on the first row's batch, as if nothing crossed the mesh."""
-    step = driver.step
-
-    def broken(p, tok, tgt):
-        import jax.numpy as jnp
-
-        half = tok.shape[0] // 2
-        return step(p, jnp.concatenate([tok[:half]] * 2),
-                    jnp.concatenate([tgt[:half]] * 2))
-
-    driver.step = broken
-
-
-FAULTS = [(n, f) for n in TRAIN for f in (state_unchanged, half_the_batch)]
-FAULTS += [(n, no_exchange) for n in TRAIN
-           if registry.load_cell(BENCH, n)["chips"] > 1]
-
-
 @pytest.mark.parametrize("name,fault", FAULTS,
-                         ids=[f"{n}-{f.__name__}" for n, f in FAULTS])
+                         ids=[f"{n}-{f}" for n, f in FAULTS])
 def test_a_broken_step_is_not_correct(name, fault):
-    line = run(name, tamper=fault)
+    line = run(name, fault=fault)
     assert not line["correct"], line["compared"]
     over = [k for k, (v, lim) in line["compared"].items() if v > lim]
     assert over
-    if fault is state_unchanged:
+    if fault == "state_unchanged":
         # a leaf that has not moved reads 1 by the worst-leaf measure
         assert line["compared"]["change_norm_gap"][0] == pytest.approx(1.0)
 

@@ -164,6 +164,7 @@ def train_run() -> dict:
     scoped = [(scope, "jit_step", int(a * 1e9), int(b * 1e9), 0)
               for scope, a, b in spans]
     return {"summary": {"scopes": {"fusion.1": spans[0][0]}},
+            "model": registry.load_part("models", "flagship"),
             "trace": {"devices": 1, "busy_s": 10.0, "window_s": 10.0,
                       "scoped": scoped}}
 

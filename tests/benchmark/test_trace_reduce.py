@@ -65,6 +65,34 @@ def test_gaps_go_to_the_innermost_host_frame():
                     "$time sleep": pytest.approx(300e-6)}
 
 
+def test_scopes_of_two_programs_are_kept_apart():
+    """A window that runs two programs: both number their instructions
+    alike, so a scope is looked up by program and instruction; two texts of
+    one program that disagree keep the path they share."""
+    def text(program, *lines):
+        return f"HloModule {program}, is_scheduled=true\n" + "".join(
+            f'  %{name} = f32[8]{{0}} fusion(f32[8]{{0}} %p), '
+            f'metadata={{op_name="{scope}"}}\n' for name, scope in lines)
+
+    scopes = tr.scopes_by_program([
+        text("jit_step", ("fusion.1", "jit(step)/while/body/lm_moe/dot")),
+        text("jit_prefill", ("fusion.1", "jit(prefill)/lm_attn/dot_general"),
+             ("fusion.2", "jit(prefill)/lm_moe/dot")),
+        text("jit_prefill", ("fusion.1", "jit(prefill)/lm_attn/add"),
+             ("fusion.2", "jit(prefill)/lm_loss/dot"))])
+    assert scopes == {"jit_step/fusion.1": "jit(step)/while/body/lm_moe/dot",
+                      "jit_prefill/fusion.1": "jit(prefill)/lm_attn",
+                      "jit_prefill/fusion.2": "jit(prefill)"}
+    s = tr.reduce_events(by_hand(), scopes)
+    # fusion.1 ran 150 us in jit_step and 100 us in jit_prefill
+    assert tr.scope_seconds(s, "lm_moe") == pytest.approx(150e-6)
+    assert tr.scope_seconds(s, "lm_attn") == pytest.approx(100e-6)
+    assert tr.breakdown(s)["device_ops"][0] == [
+        "jit_step/fusion.1 <while/body/lm_moe/dot>", pytest.approx(150e-6)]
+    assert ["jit_step/fusion.2", pytest.approx(100e-6)] in \
+        tr.breakdown(s)["device_ops"]
+
+
 def test_scopes_come_from_the_compiled_text():
     hlo = ('  %fusion.2 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop, '
            'metadata={op_name="jit(step)/blockwise_q_block_0/dot_general"}\n'
