@@ -8,6 +8,9 @@ user calls, at the flagship's full width (depth as the benchmark cuts it):
   save    Checkpointer.save with lm_checkpoint_meta
   serve   the CLI (``predict --model <ckpt dir>``), then the same prompts
           again on one warm engine: same tokens, nothing compiled
+  blockdiff a block-diffusion model (the benchmark's cell at its rehearsal
+          size) through the same engine: prefill, two blocks a request,
+          every recorded forward against the plain reference
   kernels every Pallas kernel at a shape that takes its Pallas branch,
           forward and gradient against the lax reference, and a check that
           the lowered module holds a Mosaic custom call
@@ -52,6 +55,11 @@ SERVE = dict(slots=8, max_len=2048, max_new_tokens=32,
 KERNELS = dict(dense=(512, 1024, 1024), lstm=((256, 512), (256, 2048)),
                flash=(4, 4, 2048, 128))
 LEGACY_VOCAB = 512
+# the benchmark's block-diffusion cell at its rehearsal size: prompts of
+# every remainder mod the block length (one shorter than a block), two
+# blocks of answer each
+BLOCKDIFF = dict(cell="serve-blockdiff-sat", prompt_lens=(5, 8, 10, 3, 7),
+                 max_new_tokens=8, seed=3)
 
 # TPU default precision runs an f32 matmul as one bf16 MXU pass (eps 2^-8 per
 # operand), in Mosaic and in XLA alike, and the two round in different
@@ -63,6 +71,12 @@ TOL_ELEMENTWISE = 1e-4
 # one-chip against 2 x 2 mesh: same bf16-pass rounding in another order, and
 # a near-tied router logit may send a token to another expert
 TOL_MESH_LOSS = 2e-2
+
+# the rehearsal model is float32, which the chip multiplies in one bf16 pass:
+# its logits (of order 1) stand a few 1e-2 from the reference's, so a token
+# may be another near-tied one; an altered token reads 3.3 and 0.49 (CPU)
+TOL_BLOCKDIFF = {"widest_logit_gap": 0.25, "mean_logit_gap": 0.02,
+                 "widest_confidence_gap": 0.25, "schedule_faults": 0.0}
 
 HARD_LIMIT_S = 1100  # the contract allows 1200 s
 
@@ -278,6 +292,60 @@ def phase_serve(root: str, dims: dict, serve: dict, work_dir: str) -> dict:
     return {"requests": len(prompts), "tokens_per_request": new,
             "prefill_buckets_used": buckets,
             "serve_dtype": stats["serve_dtype"]}
+
+
+def _overlay(base: dict, cut: dict) -> None:
+    for key, value in cut.items():
+        if isinstance(value, dict) and isinstance(base.get(key), dict):
+            _overlay(base[key], value)
+        else:
+            base[key] = value
+
+
+def phase_blockdiff(spec: dict, tol: dict) -> dict:
+    """A block-diffusion model through the normal serving path, at the
+    rehearsal size of the benchmark's cell and built by the cell's own model
+    file: prefill of the prompt's whole blocks, two blocks of answer a
+    request (denoising forwards, a commit between them), and agreement of
+    every recorded forward with the plain reference."""
+    from benchmark.harness import registry
+    from deeplearning4j_tpu.telemetry.registry import MetricsRegistry
+    from deeplearning4j_tpu.utils.retrace_guard import retrace_guard
+
+    cell = registry.load_cell(registry.load_benchmark(), spec["cell"])
+    config, traffic = cell["config_data"], cell["traffic_data"]
+    for data in (config, traffic):
+        _overlay(data, data.pop("rehearse"))
+    model = registry.load_model(cell)
+    engine = model.build_serve(config, spec["seed"], MetricsRegistry())
+    prompts = seeded_prompts(spec["prompt_lens"], config["vocab_size"])
+    new = spec["max_new_tokens"]
+
+    def generate():
+        reqs = [engine.submit(p, max_new_tokens=new) for p in prompts]
+        engine.run_until_idle()
+        check(all(r.done.is_set() and r.finish_reason == "max_new_tokens"
+                  and len(r.generated) == new for r in reqs),
+              f"requests did not complete: "
+              f"{[(r.rid, r.finish_reason, len(r.generated)) for r in reqs]}")
+        return reqs
+
+    reqs = generate()
+    kinds = [[f[2] for f in r.forwards] for r in reqs]
+    check(all("commit" in k and k[-1] == "denoise" for k in kinds),
+          f"a request's forwards are not blocks with a commit between: {kinds}")
+    with retrace_guard(0, label="block diffusion: same prompts again"):
+        again = generate()
+    check([r.generated for r in again] == [r.generated for r in reqs],
+          "the same prompts gave other tokens the second time")
+    compared = model.serve_compare(config, traffic, spec["seed"], reqs)
+    for name, (value, _) in compared.items():
+        check(value <= tol[name],
+              f"block diffusion against the reference: {name} {value:.4g} "
+              f"over {tol[name]}")
+    return {"requests": len(reqs), "forwards": sum(map(len, kinds)),
+            "compared": {k: float(f"{v:.4g}") for k, (v, _) in
+                         compared.items()}}
 
 
 def _expects_mosaic() -> bool:
@@ -545,6 +613,8 @@ def main() -> int:
     del params
     phases["serve"] = run("serve", phase_serve, ckpt_root, FLAGSHIP, SERVE,
                           OUT_DIR)
+    phases["blockdiff"] = run("blockdiff", phase_blockdiff, BLOCKDIFF,
+                              TOL_BLOCKDIFF)
     # the kernels are called directly: the use_fused_dense() auto gate, off on
     # a host with more than one chip, is not in their way
     phases["kernels"] = run("kernels", phase_kernels, KERNELS)

@@ -37,7 +37,7 @@ transposes (expert grads reduce over token axes automatically).
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -76,53 +76,136 @@ SEQ_AXIS = "sp"
 # back without it.
 LM_SCOPES = ("lm_embed", "lm_attn", "lm_cache_write", "lm_moe", "lm_loss",
              "lm_update", "lm_sample")
+# What only the block-diffusion step names: its sampling, confidence and
+# choice of positions. Apart from LM_SCOPES, whose every name every flagship
+# serve program carries (tests/benchmark holds them to that). The rotary term
+# has no scope of its own: it is 0.5% of a block step on the v5e (PR 28).
+LM_UNMASK_SCOPE = "lm_unmask"
+LM_SPEC_SCOPES = (LM_UNMASK_SCOPE,)
+
+
+class BlockSpec(NamedTuple):
+    """What kind of decoder block the serving programs run: static and
+    hashable, read at trace time by the one set of block functions
+    (``_init_block``, ``lm_prefill``, ``_cached_layers`` and what they
+    call), so that the default compiles to exactly the flagship's programs
+    and another model is the same functions under another spec. The query
+    head count stays the ``n_heads`` argument it always was. The training
+    step factories do not take a spec yet."""
+    norm: str = "layernorm"     # "layernorm" (gain, bias, eps 1e-5) | "rmsnorm"
+    norm_eps: float = 1e-5      # of the RMSNorm
+    final_norm: bool = False    # a norm between the last block and the head
+    rope_theta: Optional[float] = None  # rotary positions on q and k; None: no positions
+    qk_norm: bool = False       # RMSNorm over each head of q and k, gains shared by heads
+    n_kv_heads: Optional[int] = None    # K/V heads (the cache's); None: one a query head
+    head_dim: Optional[int] = None      # None: d_model // n_heads
+    ffn: str = "relu"           # experts: "relu" (w1, w2) | "swiglu" (wg, wu, wd)
+    norm_topk_prob: bool = True  # gates renormalised over the chosen k
+    bias: bool = True           # expert and output biases
+    accum_f32: bool = False     # router logits, scores and logits leave their matmul in float32
+    attn_mask: str = "causal"   # "causal" | "block": i sees j iff j // B <= i // B
+    block_length: int = 1       # B
+    generation: str = "autoregressive"  # | "block_diffusion" (serve/engine.py)
+    denoising_steps: int = 1    # D: B // D positions unmasked a denoising forward
+    mask_token_id: Optional[int] = None
+
+    def kv_heads(self, n_heads: int) -> int:
+        return self.n_kv_heads or n_heads
+
+    def head_size(self, d_model: int, n_heads: int) -> int:
+        return self.head_dim or d_model // n_heads
+
+
+FLAGSHIP_SPEC = BlockSpec()
 
 
 def _init_block(key: Array, d_model: int, n_heads: int, n_experts: int,
-                d_ff: int) -> dict:
+                d_ff: int, spec: BlockSpec = FLAGSHIP_SPEC,
+                init_scale: Optional[float] = None) -> dict:
     ks = jax.random.split(key, 6)
     n = jax.random.normal
-    s_d = 1.0 / (d_model ** 0.5)
-    return {
-        "ln_g": jnp.ones((d_model,)), "ln_b": jnp.zeros((d_model,)),
-        "wq": n(ks[0], (d_model, d_model)) * s_d,
-        "wk": n(ks[1], (d_model, d_model)) * s_d,
-        "wv": n(ks[2], (d_model, d_model)) * s_d,
-        "wo": n(ks[3], (d_model, d_model)) * s_d,
-        "ln2_g": jnp.ones((d_model,)), "ln2_b": jnp.zeros((d_model,)),
-        "router": n(ks[4], (d_model, n_experts)) * s_d,
-        "experts": {
-            "w1": n(ks[5], (n_experts, d_model, d_ff)) * s_d,
-            "b1": jnp.zeros((n_experts, d_ff)),
-            "w2": n(jax.random.fold_in(ks[5], 1),
-                    (n_experts, d_ff, d_model)) / (d_ff ** 0.5),
-            "b2": jnp.zeros((n_experts, d_model)),
-        },
-    }
+    s_d = 1.0 / (d_model ** 0.5) if init_scale is None else init_scale
+
+    def down(k):  # the flagship divides: a product would round otherwise
+        w = n(k, (n_experts, d_ff, d_model))
+        return w / (d_ff ** 0.5) if init_scale is None else w * init_scale
+
+    hd = spec.head_size(d_model, n_heads)
+    d_q, d_kv = n_heads * hd, spec.kv_heads(n_heads) * hd
+    # the leaves in the order the flagship's init always made them: its
+    # jitted init then lowers to the text it had, and is found compiled
+    layernorm = spec.norm == "layernorm"
+    p = {"ln_g": jnp.ones((d_model,))}
+    if layernorm:
+        p["ln_b"] = jnp.zeros((d_model,))
+    p["wq"] = n(ks[0], (d_model, d_q)) * s_d
+    p["wk"] = n(ks[1], (d_model, d_kv)) * s_d
+    p["wv"] = n(ks[2], (d_model, d_kv)) * s_d
+    p["wo"] = n(ks[3], (d_q, d_model)) * s_d
+    p["ln2_g"] = jnp.ones((d_model,))
+    if layernorm:
+        p["ln2_b"] = jnp.zeros((d_model,))
+    p["router"] = n(ks[4], (d_model, n_experts)) * s_d
+    if spec.qk_norm:
+        p["q_g"], p["k_g"] = jnp.ones((hd,)), jnp.ones((hd,))
+    if spec.ffn == "swiglu":
+        p["experts"] = {
+            "wg": n(ks[5], (n_experts, d_model, d_ff)) * s_d,
+            "wu": n(jax.random.fold_in(ks[5], 1),
+                    (n_experts, d_model, d_ff)) * s_d,
+            "wd": down(jax.random.fold_in(ks[5], 2)),
+        }
+    else:
+        ex = p["experts"] = {
+            "w1": n(ks[5], (n_experts, d_model, d_ff)) * s_d}
+        if spec.bias:
+            ex["b1"] = jnp.zeros((n_experts, d_ff))
+        ex["w2"] = down(jax.random.fold_in(ks[5], 1))
+        if spec.bias:
+            ex["b2"] = jnp.zeros((n_experts, d_model))
+    return p
 
 
 def init_lm_params(key: Array, vocab: int, d_model: int, n_heads: int,
-                   n_experts: int, d_ff: int, n_layers: int = 1) -> dict:
+                   n_experts: int, d_ff: int, n_layers: int = 1,
+                   spec: BlockSpec = FLAGSHIP_SPEC,
+                   init_scale: Optional[float] = None) -> dict:
     """Embedding + ``n_layers`` stacked decoder blocks + vocab decoder.
 
     ``params["blocks"]`` leaves carry a leading (n_layers, ...) axis — the
     scan/pipeline-stage layout (lm_forward scans it; make_pp_stages slices
-    it at layer boundaries)."""
-    if d_model % n_heads:
+    it at layer boundaries). ``spec`` says which leaves a block has;
+    ``init_scale`` draws every matrix at that one scale (None: the
+    flagship's own, 1/sqrt(fan-in) and 0.1 for the embedding)."""
+    hd = spec.head_size(d_model, n_heads)
+    if spec.head_dim is None and d_model % n_heads:
         raise ValueError(f"d_model {d_model} % n_heads {n_heads} != 0")
+    if n_heads % spec.kv_heads(n_heads):
+        raise ValueError(f"n_heads {n_heads} % n_kv_heads "
+                         f"{spec.n_kv_heads} != 0")
+    if spec.rope_theta is not None and hd % 2:
+        raise ValueError(f"rotary positions need an even head size, got {hd}")
     if n_layers < 1:
         raise ValueError(f"n_layers must be >= 1, got {n_layers}")
     ks = jax.random.split(key, 3 + n_layers)
     n = jax.random.normal
-    s_d = 1.0 / (d_model ** 0.5)
-    blocks = [_init_block(ks[3 + i], d_model, n_heads, n_experts, d_ff)
+    s_d = 1.0 / (d_model ** 0.5) if init_scale is None else init_scale
+    blocks = [_init_block(ks[3 + i], d_model, n_heads, n_experts, d_ff, spec,
+                          init_scale)
               for i in range(n_layers)]
-    return {
-        "embed": n(ks[0], (vocab, d_model)) * 0.1,
+    out = {
+        "embed": n(ks[0], (vocab, d_model))
+        * (0.1 if init_scale is None else init_scale),
         "blocks": jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *blocks),
         "dec_w": n(ks[1], (d_model, vocab)) * s_d,
-        "dec_b": jnp.zeros((vocab,)),
     }
+    if spec.bias:
+        out["dec_b"] = jnp.zeros((vocab,))
+    if spec.final_norm:
+        out["lnf_g"] = jnp.ones((d_model,))
+        if spec.norm == "layernorm":
+            out["lnf_b"] = jnp.zeros((d_model,))
+    return out
 
 
 def lm_n_layers(params: dict) -> int:
@@ -130,17 +213,25 @@ def lm_n_layers(params: dict) -> int:
 
 
 def expert_fn(p: dict, t: Array) -> Array:
-    """One expert's FFN on its (C, d) token slice."""
+    """One expert's FFN on its (C, d) token slice: which kind is read off
+    the leaves the spec's init gave it (three matrices: SiLU-gated, no
+    bias; two: ReLU, with biases where the spec has them)."""
+    if "wg" in p:
+        return (jax.nn.silu(t @ p["wg"]) * (t @ p["wu"])) @ p["wd"]
+    if "b1" not in p:
+        return jax.nn.relu(t @ p["w1"]) @ p["w2"]
     return jax.nn.relu(t @ p["w1"] + p["b1"]) @ p["w2"] + p["b2"]
 
 
 def dense_moe(router_w: Array, experts: dict, x: Array,
-              top_k: int = 2) -> Array:
+              top_k: int = 2, spec: BlockSpec = FLAGSHIP_SPEC) -> Array:
     """Differentiable single-device MoE (every expert on every token,
     gate-combined; no capacity drops) — the parity oracle for moe_apply
     with ample capacity, and the FFN of the pp-staged path where the
     expert axis is not sharded."""
-    idx, gates = _routing(x @ router_w, top_k)
+    logits = (jnp.matmul(x, router_w, preferred_element_type=jnp.float32)
+              if spec.accum_f32 else x @ router_w)
+    idx, gates = _routing(logits, top_k, spec.norm_topk_prob)
     y_all = jax.vmap(lambda p: expert_fn(p, x))(experts)  # (E, N, d)
     n_experts = router_w.shape[1]
     onehot = jax.nn.one_hot(idx, n_experts)  # (N, k, E)
@@ -895,69 +986,164 @@ def make_pp_loss(stage_fn, mesh: Mesh, pipe_axis: str,
 # temperature vector so one executable serves both) is fused into the same
 # jitted step as the forward — one dispatch per decode iteration.
 
-def init_kv_cache(n_layers: int, n_slots: int, n_heads: int, head_dim: int,
-                  max_len: int, dtype=jnp.float32) -> dict:
+def init_kv_cache(n_layers: int, n_slots: int, n_kv_heads: int,
+                  head_dim: int, max_len: int, dtype=jnp.float32) -> dict:
     """Zeroed paged KV cache for ``n_slots`` concurrent requests:
-    ``{"k","v"}`` leaves of shape (L, S, H, T_max, Dh). Zeros (not garbage)
-    so masked-out positions can never inject non-finite values through the
-    0-weight attention terms. The layer axis leads because the serving
-    programs carry each leaf whole through their layer loop and index it
-    by layer there (``_cached_layers``); the donated leaves are updated in
-    place, so one cache is all the memory a step needs for it."""
-    shape = (n_layers, n_slots, n_heads, max_len, head_dim)
+    ``{"k","v"}`` leaves of shape (L, S, H_kv, T_max, Dh), H_kv the K/V
+    head count (the query head count unless the spec groups them). Zeros
+    (not garbage) so masked-out positions can never inject non-finite
+    values through the 0-weight attention terms. The layer axis leads
+    because the serving programs carry each leaf whole through their layer
+    loop and index it by layer there (``_cached_layers``); the donated
+    leaves are updated in place, so one cache is all the memory a step
+    needs for it."""
+    shape = (n_layers, n_slots, n_kv_heads, max_len, head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+# ---- the block's parts, each reading the spec at trace time; under the
+# default spec each emits the operations the flagship's block always had ----
+
+def _rmsnorm(x: Array, g: Array, eps: float) -> Array:
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (y * g).astype(x.dtype)
+
+
+def _norm(p: dict, name: str, x: Array, spec: BlockSpec) -> Array:
+    if spec.norm == "layernorm":
+        return _layernorm(x, p[name + "_g"], p[name + "_b"])
+    return _rmsnorm(x, p[name + "_g"], spec.norm_eps)
+
+
+def _rope(x: Array, positions: Array, theta: float) -> Array:
+    """Rotary positions, rotate-half over the head size, no scaling. x:
+    (B, H, T, Dh); positions: (T,) or one row a batch row, (B, T)."""
+    hd = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    ang = jnp.concatenate([ang, ang], axis=-1)            # (..., T, Dh)
+    if positions.ndim == 2:
+        ang = ang[:, None]                                # (B, 1, T, Dh)
+    x32 = x.astype(jnp.float32)
+    rot = jnp.concatenate([-x32[..., hd // 2:], x32[..., :hd // 2]], -1)
+    return (x32 * jnp.cos(ang) + rot * jnp.sin(ang)).astype(x.dtype)
+
+
+def _qkv(p: dict, hn: Array, n_heads: int, spec: BlockSpec,
+         positions: Optional[Array]) -> tuple:
+    """The three projections of the normed input as heads: q (B, H, T, Dh),
+    k and v (B, H_kv, T, Dh), with the spec's q/k norm and, at
+    ``positions`` (read under a rotary spec alone), its rotary term."""
+    n_kv = spec.kv_heads(n_heads)
+    q = _split_heads(hn @ p["wq"], n_heads)
+    k = _split_heads(hn @ p["wk"], n_kv)
+    v = _split_heads(hn @ p["wv"], n_kv)
+    if spec.qk_norm:
+        q = _rmsnorm(q, p["q_g"], spec.norm_eps)
+        k = _rmsnorm(k, p["k_g"], spec.norm_eps)
+    if spec.rope_theta is not None:
+        q = _rope(q, positions, spec.rope_theta)
+        k = _rope(k, positions, spec.rope_theta)
+    return q, k, v
+
+
+def _scores(q: Array, k: Array, spec: BlockSpec) -> Array:
+    kw = {"preferred_element_type": jnp.float32} if spec.accum_f32 else {}
+    return jnp.einsum("shqd,shkd->shqk", q, k, **kw) / jnp.sqrt(
+        q.shape[-1] * 1.0)
+
+
+def _lm_head(params: dict, h: Array, spec: BlockSpec) -> Array:
+    if spec.final_norm:
+        h = _norm(params, "lnf", h, spec)
+    logits = (jnp.matmul(h, params["dec_w"],
+                         preferred_element_type=jnp.float32)
+              if spec.accum_f32 else h @ params["dec_w"])
+    return logits + params["dec_b"] if spec.bias else logits
+
+
+def _prefill_core(spec: BlockSpec, attn_impl: Optional[str]):
+    """``attn_core(q, k, v)`` of the prompt pass: the selection seam's
+    causal core, or under the block mask (i sees j iff j // B <= i // B)
+    the dense masked one; grouped K/V heads are repeated to the query
+    heads here, so what the block hands the cache stays H_kv wide."""
+    def core(q, k, v):
+        group = q.shape[1] // k.shape[1]
+        if group > 1:
+            k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+        if spec.attn_mask != "block":
+            return attention_core(q, k, v, causal=True, impl=attn_impl)
+        blk = jnp.arange(q.shape[2]) // spec.block_length
+        scores = jnp.where(blk[None, :] <= blk[:, None],
+                           _scores(q, k, spec), -1e30)
+        return jnp.einsum("shqk,shkd->shqd", jax.nn.softmax(scores, -1), v)
+
+    return core
+
+
 def _decoder_block_kv(layer_params: dict, h: Array, n_heads: int, attn_core,
-                      top_k: int) -> tuple:
+                      top_k: int, spec: BlockSpec = FLAGSHIP_SPEC) -> tuple:
     """``_decoder_block`` with the dense MoE FFN, additionally returning the
-    layer's projected K/V (B, H, T, Dh) for cache seeding. The op sequence
-    is IDENTICAL to _attn_block + _decoder_block's dense path — prefill
-    logits must stay bit-identical to lm_forward's (pinned in
+    layer's projected K/V (B, H_kv, T, Dh) for cache seeding. The op
+    sequence is IDENTICAL to _attn_block + _decoder_block's dense path —
+    prefill logits must stay bit-identical to lm_forward's (pinned in
     tests/test_serve.py)."""
     with jax.named_scope("lm_attn"):
-        hn = _layernorm(h, layer_params["ln_g"], layer_params["ln_b"])
-        q = _split_heads(hn @ layer_params["wq"], n_heads)
-        k = _split_heads(hn @ layer_params["wk"], n_heads)
-        v = _split_heads(hn @ layer_params["wv"], n_heads)
+        hn = _norm(layer_params, "ln", h, spec)
+        q, k, v = _qkv(layer_params, hn, n_heads, spec,
+                       jnp.arange(h.shape[1])
+                       if spec.rope_theta is not None else None)
         # .astype keeps the scan carry dtype stable under serve_dtype="bf16"
         # (the dense core's f32 score scale widens its output); identity at
         # f32
         h = h + (_merge_heads(attn_core(q, k, v))
                  @ layer_params["wo"]).astype(h.dtype)
-    return _dense_moe_ffn(layer_params, h, top_k), k, v
+    return _dense_moe_ffn(layer_params, h, top_k, spec), k, v
 
 
-def _dense_moe_ffn(layer_params: dict, h: Array, top_k: int) -> Array:
+def _dense_moe_ffn(layer_params: dict, h: Array, top_k: int,
+                   spec: BlockSpec = FLAGSHIP_SPEC) -> Array:
     """The serving blocks' FFN half under ``lm_moe``: second layer norm,
     the dense MoE, the residual in the carry's dtype."""
     with jax.named_scope("lm_moe"):
-        h2 = _layernorm(h, layer_params["ln2_g"], layer_params["ln2_b"])
+        h2 = _norm(layer_params, "ln2", h, spec)
         flat = h2.reshape(-1, h2.shape[-1])
         moe_out = dense_moe(layer_params["router"], layer_params["experts"],
-                            flat, top_k)
+                            flat, top_k, spec)
         return h + moe_out.reshape(h.shape).astype(h.dtype)
 
 
-def lm_prefill(params: dict, tokens: Array, n_heads: int, top_k: int = 2,
-               attn_impl: Optional[str] = None) -> tuple:
-    """Prompt pass: tokens (B, T_pad) → (logits (B, T_pad, V), ks, vs) with
-    ks/vs (L, B, H, T_pad, Dh) — every layer's projected K/V, ready to seed
-    cache pages. Attention routes through the core-selection seam exactly
-    like the training paths (``attn_impl`` forces dense/blockwise/flash);
-    causal masking makes right-padding exact: positions >= the real length
-    produce garbage K/V that decode's position mask never reads."""
-    core = lambda q, k, v: attention_core(q, k, v, causal=True,  # noqa: E731
-                                          impl=attn_impl)
+def _prefill_hidden(params: dict, tokens: Array, n_heads: int, top_k: int,
+                    attn_impl: Optional[str], spec: BlockSpec) -> tuple:
+    """The prompt pass up to the head: (h (B, T_pad, d), ks, vs)."""
+    core = _prefill_core(spec, attn_impl)
     with jax.named_scope("lm_embed"):
         h = params["embed"][tokens]
 
     def step(h, layer_params):
-        h, k, v = _decoder_block_kv(layer_params, h, n_heads, core, top_k)
+        h, k, v = _decoder_block_kv(layer_params, h, n_heads, core, top_k,
+                                    spec)
         return h, (k, v)
 
     h, (ks, vs) = jax.lax.scan(step, h, params["blocks"])
-    return h @ params["dec_w"] + params["dec_b"], ks, vs
+    return h, ks, vs
+
+
+def lm_prefill(params: dict, tokens: Array, n_heads: int, top_k: int = 2,
+               attn_impl: Optional[str] = None,
+               spec: BlockSpec = FLAGSHIP_SPEC) -> tuple:
+    """Prompt pass: tokens (B, T_pad) → (logits (B, T_pad, V), ks, vs) with
+    ks/vs (L, B, H_kv, T_pad, Dh) — every layer's projected K/V, ready to
+    seed cache pages. Attention routes through the core-selection seam
+    exactly like the training paths (``attn_impl`` forces
+    dense/blockwise/flash); causal masking makes right-padding exact:
+    positions >= the real length produce garbage K/V that decode's position
+    mask never reads. Under the block mask the same holds block for block:
+    no block sees a later one."""
+    h, ks, vs = _prefill_hidden(params, tokens, n_heads, top_k, attn_impl,
+                                spec)
+    return _lm_head(params, h, spec), ks, vs
 
 
 _CACHE_LAYOUT = Layout(major_to_minor=(0, 1, 2, 3, 4))
@@ -989,17 +1175,21 @@ def _write_cache_rows(ck: Array, cv: Array, k_new: Array, v_new: Array,
 
 def _decode_block(layer_params: dict, h: Array, ck: Array, cv: Array,
                   layer: Array, slot0: Array, positions: Array, n_heads: int,
-                  top_k: int) -> tuple:
+                  top_k: int, spec: BlockSpec = FLAGSHIP_SPEC) -> tuple:
     """One decoder block for W new tokens per slot. h: (S, W, d), the rows
     of slots ``slot0``..``slot0 + S - 1`` (every slot from 0 in decode and
     verify, the one slot of a prefill chunk); ck/cv: the WHOLE cache
-    leaves (L, n_slots, H, T_max, Dh), of which this block touches those
+    leaves (L, n_slots, H_kv, T_max, Dh), of which this block touches those
     slots' pages of layer ``layer`` alone. Writes this step's K/V at
     ``positions``..``positions + W - 1`` FIRST, in place
     (``_write_cache_rows``), then slices the pages back out and attends
     with the per-query mask ``index <= position + offset`` — so every
     freshly written position is visible to the queries at or after it and
-    stale cache beyond them never is. The attention math mirrors
+    stale cache beyond them never is. Under the block mask a query sees up
+    to the last row of its own block of B: the block-diffusion step (W = B,
+    ``positions`` a block's first row) attends to the whole block it has
+    just written, and what it wrote stays only until the same block's next
+    forward overwrites it. The attention math mirrors
     ring_attention.reference_attention (same score scale, same -1e30 mask,
     jax.nn.softmax): the masked terms underflow to exact zeros, so the
     padded reduction is bitwise the oracle's unpadded one. W=1 is the
@@ -1007,45 +1197,62 @@ def _decode_block(layer_params: dict, h: Array, ck: Array, cv: Array,
     the same math, so verify logits at offset i are exactly what i
     sequential decode steps over the same tokens would produce."""
     with jax.named_scope("lm_attn"):
-        hn = _layernorm(h, layer_params["ln_g"], layer_params["ln_b"])
-        q = _split_heads(hn @ layer_params["wq"], n_heads)  # (S, H, W, Dh)
-        k_new = _split_heads(hn @ layer_params["wk"], n_heads)
-        v_new = _split_heads(hn @ layer_params["wv"], n_heads)
+        hn = _norm(layer_params, "ln", h, spec)
+        # each query's row, made where it is used: the flagship's program
+        # keeps the order of operations it was compiled with
+        rows = lambda: (positions[:, None]  # noqa: E731
+                        + jnp.arange(h.shape[1])[None, :])        # (S, W)
+        # q (S, H, W, Dh); the new rows (S, H_kv, W, Dh)
+        q, k_new, v_new = _qkv(layer_params, hn, n_heads, spec,
+                               rows() if spec.rope_theta is not None
+                               else None)
         with jax.named_scope("lm_cache_write"):
             ck, cv = _write_cache_rows(ck, cv, k_new, v_new, layer, slot0,
                                        positions)
         pages = lambda c: jax.lax.dynamic_slice(  # noqa: E731
             c, (layer, slot0, 0, 0, 0), (1, h.shape[0]) + c.shape[2:])[0]
-        ck_l, cv_l = pages(ck), pages(cv)                 # (S, H, T_max, Dh)
-        scores = jnp.einsum("shqd,shkd->shqk", q, ck_l) / jnp.sqrt(
-            q.shape[-1] * 1.0)                            # (S, H, W, T_max)
-        pos_q = positions[:, None] + jnp.arange(h.shape[1])[None, :]  # (S, W)
+        ck_l, cv_l = pages(ck), pages(cv)              # (S, H_kv, T_max, Dh)
+        group = n_heads // ck_l.shape[1]
+        if group > 1:
+            # the query heads of one K/V head as further query rows of it
+            q = q.reshape(q.shape[0], ck_l.shape[1], -1, q.shape[-1])
+        scores = _scores(q, ck_l, spec)                # (S, H_kv, G*W, T_max)
+        seen = pos_q = rows()
+        if spec.attn_mask == "block":
+            seen = pos_q // spec.block_length * spec.block_length \
+                + (spec.block_length - 1)
+        if group > 1:
+            seen = jnp.tile(seen, (1, group))
         mask = (jnp.arange(ck_l.shape[2])[None, None, None, :]
-                <= pos_q[:, None, :, None])
+                <= seen[:, None, :, None])
         scores = jnp.where(mask, scores, -1e30)
         o = jnp.einsum("shqk,shkd->shqd", jax.nn.softmax(scores, -1), cv_l)
+        if group > 1:
+            o = o.reshape(o.shape[0], n_heads, -1, o.shape[-1])
         # f32 score math, carry-dtype residual (identity at f32:
         # parity-safe)
         h = h + (_merge_heads(o) @ layer_params["wo"]).astype(h.dtype)
-    return _dense_moe_ffn(layer_params, h, top_k), ck, cv
+    return _dense_moe_ffn(layer_params, h, top_k, spec), ck, cv
 
 
 def _cached_layers(params: dict, cache: dict, h: Array, positions: Array,
-                   n_heads: int, top_k: int, slot0=0) -> tuple:
-    """The one layer loop of decode, verify and chunked prefill: h (S, W,
-    d), the rows of slots ``slot0``..``slot0 + S - 1``, through every
-    block, each attending over the cache. The loop scans the stacked block
-    params with the layer's index and CARRIES ``(h, cache k, cache v)``,
-    both leaves whole: a scanned cache would be sliced a layer at a time
-    on the way in and stacked into a second cache on the way out, six
-    whole-cache copies a step for a few rows stored. Returns (cache, h)."""
+                   n_heads: int, top_k: int, slot0=0,
+                   spec: BlockSpec = FLAGSHIP_SPEC) -> tuple:
+    """The one layer loop of decode, verify, chunked prefill and the
+    block-diffusion step: h (S, W, d), the rows of slots
+    ``slot0``..``slot0 + S - 1``, through every block, each attending over
+    the cache. The loop scans the stacked block params with the layer's
+    index and CARRIES ``(h, cache k, cache v)``, both leaves whole: a
+    scanned cache would be sliced a layer at a time on the way in and
+    stacked into a second cache on the way out, six whole-cache copies a
+    step for a few rows stored. Returns (cache, h)."""
     slot0 = jnp.asarray(slot0, jnp.int32)
 
     def step(carry, xs):
         h, ck, cv = carry
         layer_params, layer = xs
         return _decode_block(layer_params, h, ck, cv, layer, slot0,
-                             positions, n_heads, top_k), None
+                             positions, n_heads, top_k, spec), None
 
     layers = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
     (h, ck, cv), _ = jax.lax.scan(
@@ -1054,7 +1261,8 @@ def _cached_layers(params: dict, cache: dict, h: Array, positions: Array,
 
 
 def lm_decode_step(params: dict, cache: dict, tokens: Array,
-                   positions: Array, n_heads: int, top_k: int = 2) -> tuple:
+                   positions: Array, n_heads: int, top_k: int = 2,
+                   spec: BlockSpec = FLAGSHIP_SPEC) -> tuple:
     """One decode iteration over every slot: tokens (S,) int32 land at
     ``positions`` (S,) in the cache and next-token logits (S, V) come back
     with the updated cache. The cache rides through the layer loop as its
@@ -1063,9 +1271,9 @@ def lm_decode_step(params: dict, cache: dict, tokens: Array,
     the same buffers when the caller donated them."""
     with jax.named_scope("lm_embed"):
         h = params["embed"][tokens][:, None, :]           # (S, 1, d)
-    cache, h = _cached_layers(params, cache, h, positions, n_heads, top_k)
-    logits = (h @ params["dec_w"] + params["dec_b"])[:, 0, :]
-    return cache, logits
+    cache, h = _cached_layers(params, cache, h, positions, n_heads, top_k,
+                              spec=spec)
+    return cache, _lm_head(params, h, spec)[:, 0, :]
 
 
 def sample_tokens(logits: Array, key: Array, temperature: Array) -> Array:
@@ -1081,7 +1289,8 @@ def sample_tokens(logits: Array, key: Array, temperature: Array) -> Array:
 
 
 def make_decode_step(n_heads: int, top_k: int = 2, donate_cache: bool = True,
-                     params_transform=None):
+                     params_transform=None,
+                     spec: BlockSpec = FLAGSHIP_SPEC):
     """The serving engine's hot executable:
     ``step(params, cache, tokens, positions, temps, key, step_idx) ->
     (cache, next_tokens)``. Shapes are FIXED at the slot count — occupancy
@@ -1097,7 +1306,7 @@ def make_decode_step(n_heads: int, top_k: int = 2, donate_cache: bool = True,
     def step(params, cache, tokens, positions, temps, key, step_idx):
         params = transform(params)
         cache, logits = lm_decode_step(params, cache, tokens, positions,
-                                       n_heads, top_k)
+                                       n_heads, top_k, spec)
         k = jax.random.fold_in(key, step_idx)
         return cache, sample_tokens(logits, k, temps)
 
@@ -1106,25 +1315,35 @@ def make_decode_step(n_heads: int, top_k: int = 2, donate_cache: bool = True,
 
 def make_prefill_step(n_heads: int, top_k: int = 2,
                       attn_impl: Optional[str] = None,
-                      donate_cache: bool = True, params_transform=None):
+                      donate_cache: bool = True, params_transform=None,
+                      spec: BlockSpec = FLAGSHIP_SPEC):
     """Admission executable: ``prefill(params, cache, tokens, last_idx,
     slot, temp, key, step_idx) -> (cache, first_token)`` — the prompt pass
     (through the attn_impl seam), the cache-page write at ``slot``, and the
     first sampled token fused into one dispatch. ``tokens`` is (1, T_pad)
     right-padded to the engine's bucket, so compiles are bounded by the
-    bucket count (slot/last_idx are traced)."""
+    bucket count (slot/last_idx are traced). Under block-diffusion
+    generation the prompt pass only stores: the head is not run and the
+    token that comes back is 0, nothing to accept."""
     transform = params_transform or (lambda p: p)
+    stores_only = spec.generation == "block_diffusion"
 
     @partial(jax.jit, donate_argnums=(1,) if donate_cache else ())
     def prefill(params, cache, tokens, last_idx, slot, temp, key, step_idx):
         params = transform(params)
-        logits, ks, vs = lm_prefill(params, tokens, n_heads, top_k,
-                                    attn_impl)
+        if stores_only:
+            _, ks, vs = _prefill_hidden(params, tokens, n_heads, top_k,
+                                        attn_impl, spec)
+        else:
+            logits, ks, vs = lm_prefill(params, tokens, n_heads, top_k,
+                                        attn_impl, spec)
         with jax.named_scope("lm_cache_write"):
             ck = jax.lax.dynamic_update_slice(
                 cache["k"], ks.astype(cache["k"].dtype), (0, slot, 0, 0, 0))
             cv = jax.lax.dynamic_update_slice(
                 cache["v"], vs.astype(cache["v"].dtype), (0, slot, 0, 0, 0))
+        if stores_only:
+            return {"k": ck, "v": cv}, jnp.zeros((), jnp.int32)
         last = jax.lax.dynamic_index_in_dim(logits[0], last_idx, 0,
                                             keepdims=False)
         k = jax.random.fold_in(key, step_idx)
@@ -1134,7 +1353,8 @@ def make_prefill_step(n_heads: int, top_k: int = 2,
 
 
 def lm_verify_step(params: dict, cache: dict, tokens: Array,
-                   positions: Array, n_heads: int, top_k: int = 2) -> tuple:
+                   positions: Array, n_heads: int, top_k: int = 2,
+                   spec: BlockSpec = FLAGSHIP_SPEC) -> tuple:
     """Speculative verify forward (ISSUE 16): W tokens per slot — tokens
     (S, W) int32 land at ``positions``..``positions + W - 1`` in the cache
     and per-position next-token logits (S, W, V) come back with the
@@ -1148,12 +1368,14 @@ def lm_verify_step(params: dict, cache: dict, tokens: Array,
     would silently overwrite live earlier positions)."""
     with jax.named_scope("lm_embed"):
         h = params["embed"][tokens]                       # (S, W, d)
-    cache, h = _cached_layers(params, cache, h, positions, n_heads, top_k)
-    return cache, h @ params["dec_w"] + params["dec_b"]   # (S, W, V)
+    cache, h = _cached_layers(params, cache, h, positions, n_heads, top_k,
+                              spec=spec)
+    return cache, _lm_head(params, h, spec)               # (S, W, V)
 
 
 def make_verify_step(n_heads: int, top_k: int = 2, donate_cache: bool = True,
-                     params_transform=None):
+                     params_transform=None,
+                     spec: BlockSpec = FLAGSHIP_SPEC):
     """The speculative-decoding flagship executable:
     ``verify(params, cache, tokens, positions, temps, key, step_idx) ->
     (cache, toks)`` with tokens (S, W) → toks (S, W) int32. toks[:, i] is
@@ -1170,7 +1392,7 @@ def make_verify_step(n_heads: int, top_k: int = 2, donate_cache: bool = True,
     def verify(params, cache, tokens, positions, temps, key, step_idx):
         params = transform(params)
         cache, logits = lm_verify_step(params, cache, tokens, positions,
-                                       n_heads, top_k)
+                                       n_heads, top_k, spec)
         k = jax.random.fold_in(key, step_idx)
         toks = jnp.stack(
             [sample_tokens(logits[:, i, :], jax.random.fold_in(k, i), temps)
@@ -1180,9 +1402,79 @@ def make_verify_step(n_heads: int, top_k: int = 2, donate_cache: bool = True,
     return verify
 
 
+def lm_block_step(params: dict, cache: dict, tokens: Array, starts: Array,
+                  masked: Array, n_heads: int, top_k: int,
+                  spec: BlockSpec) -> tuple:
+    """One forward of a block of B positions a slot, the block-diffusion
+    sibling of ``lm_verify_step`` (W = B): tokens (S, B) int32, of which
+    the positions ``masked`` (S, B) bool enter as the mask token's
+    embedding, land at rows ``starts``..``starts + B - 1`` of the cache and
+    logits (S, B, V) come back with the updated cache. Under the spec's
+    block mask every query sees the cache up to its block's last row, the
+    rows this forward has just written included."""
+    with jax.named_scope("lm_embed"):
+        h = params["embed"][jnp.where(masked, spec.mask_token_id, tokens)]
+    cache, h = _cached_layers(params, cache, h, starts, n_heads, top_k,
+                              spec=spec)
+    return cache, _lm_head(params, h, spec)
+
+
+def unmask_most_confident(logits: Array, tokens: Array, masked: Array,
+                          n: int, key: Array, temps: Array) -> tuple:
+    """The choice a denoising forward makes, on the device: at every masked
+    position a token (``sample_tokens``) and its confidence, the softmax
+    probability of that token in float32; the ``n`` masked positions of
+    highest confidence (all that are left, if fewer; the earlier position
+    on a tie) take their token and leave the mask. Returns (tokens (S, B),
+    masked (S, B)) after the forward; a slot with nothing masked comes back
+    as it went in."""
+    with jax.named_scope(LM_UNMASK_SCOPE):
+        width = tokens.shape[1]
+        toks = jnp.stack(
+            [sample_tokens(logits[:, i, :], jax.random.fold_in(key, i),
+                           temps) for i in range(width)], axis=1)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        conf = jnp.take_along_axis(logp, toks[..., None], axis=-1)[..., 0]
+        order = jnp.argsort(jnp.where(masked, -conf, jnp.inf), axis=1,
+                            stable=True)
+        rank = jnp.argsort(order, axis=1, stable=True)
+        take = masked & (rank < n)
+        return jnp.where(take, toks, tokens), masked & ~take
+
+
+def make_block_step(n_heads: int, top_k: int, spec: BlockSpec,
+                    donate_cache: bool = True, params_transform=None):
+    """The block-diffusion engine's hot executable, ``jit_block_step``:
+    ``block_step(params, cache, tokens, starts, masked, temps, key,
+    step_idx) -> (cache, tokens, masked)``, shapes fixed at (S, B). One
+    program for both kinds of forward. A denoising forward (some position
+    of the slot masked) writes the block's K/V rows, provisional because
+    masked inputs made them, and unmasks ``B // D`` positions
+    (``unmask_most_confident``); a commit forward (none masked) writes the
+    block's final rows and changes nothing else. Both write first and read
+    back, as ``_decode_block`` does, so no second cache holds the
+    provisional rows: the same block's next forward overwrites them."""
+    transform = params_transform or (lambda p: p)
+    n = max(1, spec.block_length // spec.denoising_steps)
+
+    @partial(jax.jit, donate_argnums=(1,) if donate_cache else ())
+    def block_step(params, cache, tokens, starts, masked, temps, key,
+                   step_idx):
+        params = transform(params)
+        cache, logits = lm_block_step(params, cache, tokens, starts, masked,
+                                      n_heads, top_k, spec)
+        tokens, masked = unmask_most_confident(
+            logits, tokens, masked, n, jax.random.fold_in(key, step_idx),
+            temps)
+        return cache, tokens, masked
+
+    return block_step
+
+
 def make_chunk_prefill_step(n_heads: int, top_k: int = 2,
                             donate_cache: bool = True,
-                            params_transform=None):
+                            params_transform=None,
+                            spec: BlockSpec = FLAGSHIP_SPEC):
     """Chunked/suffix prefill executable (ISSUE 16): ``chunk(params,
     cache, tokens, start, last_idx, slot, temp, key, step_idx) -> (cache,
     tok)`` — ONE slot's tokens (1, W) written at absolute positions
@@ -1207,8 +1499,8 @@ def make_chunk_prefill_step(n_heads: int, top_k: int = 2,
             h = params["embed"][tokens]                   # (1, W, d)
         pos = jnp.asarray(start, jnp.int32)[None]         # (1,)
         cache, h = _cached_layers(params, cache, h, pos, n_heads, top_k,
-                                  slot0=slot)
-        logits = (h @ params["dec_w"] + params["dec_b"])[0]  # (W, V)
+                                  slot0=slot, spec=spec)
+        logits = _lm_head(params, h, spec)[0]             # (W, V)
         last = jax.lax.dynamic_index_in_dim(logits, last_idx, 0,
                                             keepdims=False)
         k = jax.random.fold_in(key, step_idx)
@@ -1266,20 +1558,32 @@ def lm_dims(params: dict) -> dict:
     ``n_heads``, which the head-split erases — that one travels in
     checkpoint meta (``lm_checkpoint_meta``) or a CLI flag."""
     vocab, d_model = params["embed"].shape
-    w1 = params["blocks"]["experts"]["w1"]
-    n_layers, n_experts, _, d_ff = w1.shape
+    experts = params["blocks"]["experts"]
+    w_in = experts["wg"] if "wg" in experts else experts["w1"]
+    n_layers, n_experts, _, d_ff = w_in.shape
     return {"vocab": int(vocab), "d_model": int(d_model),
             "n_layers": int(n_layers), "n_experts": int(n_experts),
             "d_ff": int(d_ff)}
 
 
-def lm_checkpoint_meta(params: dict, n_heads: int, top_k: int = 2) -> dict:
+def lm_checkpoint_meta(params: dict, n_heads: int, top_k: int = 2,
+                       spec: BlockSpec = FLAGSHIP_SPEC) -> dict:
     """Checkpoint ``meta`` block letting ``DecodeEngine.from_checkpoint``
     rebuild the decode path with zero side-channel config: pass as
     ``meta=lm_checkpoint_meta(...)`` (or merge the dict) to
-    ``Checkpointer.save``."""
-    return {"lm": {**lm_dims(params), "n_heads": int(n_heads),
-                   "top_k": int(top_k)}}
+    ``Checkpointer.save``. A block of another kind than the flagship's
+    travels as its spec's fields, the way it generates among them; a
+    flagship checkpoint's meta is what it always was."""
+    meta = {**lm_dims(params), "n_heads": int(n_heads), "top_k": int(top_k)}
+    if spec != FLAGSHIP_SPEC:
+        meta["spec"] = dict(spec._asdict())
+    return {"lm": meta}
+
+
+def spec_from_meta(lm_meta: dict) -> BlockSpec:
+    """The spec a checkpoint's ``meta["lm"]`` names (the flagship's where
+    it names none, as every checkpoint written before specs does)."""
+    return BlockSpec(**lm_meta.get("spec", {}))
 
 
 def lm_replay(n_heads: int, top_k: int = 2, aux_weight: float = 1e-2,

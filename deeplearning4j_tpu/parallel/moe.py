@@ -202,13 +202,16 @@ def _a2a_hierarchical(x, axis_name: str, outer: int, inner: int,
     return s.reshape((n_dev,) + x.shape[1:])
 
 
-def _routing(logits, top_k: int):
+def _routing(logits, top_k: int, renormalize: bool = True):
     """(N, E) logits → (idx (N,k), gates (N,k)). Gates are softmax probs of
-    the chosen experts, renormalized to sum to 1 when k > 1 (GShard)."""
+    the chosen experts, renormalized to sum to 1 when k > 1 (GShard) unless
+    the model's spec says its gates are not (``norm_topk_prob`` false). They
+    come in the logits' type: a spec that wants them in float32 hands
+    float32 logits."""
     probs = jax.nn.softmax(logits, axis=-1)
     _, idx = jax.lax.top_k(logits, top_k)  # (N, k)
     g = jnp.take_along_axis(probs, idx, axis=1)  # (N, k)
-    if top_k > 1:
+    if top_k > 1 and renormalize:
         g = g / jnp.maximum(g.sum(-1, keepdims=True), 1e-9)
     return idx, g
 

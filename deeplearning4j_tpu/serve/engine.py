@@ -38,6 +38,22 @@ Telemetry flows through the PR 2 registry under ``serve_*`` (queue depth,
 slot occupancy, token/request counters, prefill/decode/request latency
 histograms) and is served by ``UiServer`` at ``/api/serve``.
 
+Block-diffusion generation (ISSUE 28): a model whose ``BlockSpec`` says
+``generation="block_diffusion"`` is served by the same queue, slots,
+buckets, admission and retirement, with another tick. A sequence is laid out
+in blocks of B from position 0; admission prefills the prompt's whole blocks
+(the prefill stores and samples nothing) and the prompt's remainder opens
+the first generated block. A tick then runs ONE ``jit_block_step`` over
+every slot: for a slot with masked positions a denoising forward, which
+unmasks the ``B // D`` most confident of them on the device and leaves
+provisional K/V rows, else a commit forward, which leaves the finished
+block's final rows and moves the slot to its next block. A request is handed
+a block's tokens, in position order with the step's one stamp, by the
+forward that unmasks its last position: 0 to B tokens a slot a tick, through
+the accept path of the one-token tick. Every forward is appended to
+``ServeRequest.forwards``. Speculation, chunked prefill and the prefix cache
+are refused with such a spec.
+
 Request-scoped tracing (ISSUE 12): when a process tracer is configured
 (telemetry/trace.py), every request becomes a ``serve.request`` span with
 ``serve.queue_wait`` / ``serve.prefill`` / ``serve.decode`` /
@@ -101,13 +117,17 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.models.transformer_lm import (
+    FLAGSHIP_SPEC,
+    BlockSpec,
     draft_truncate_params,
     init_kv_cache,
     lm_dims,
+    make_block_step,
     make_chunk_prefill_step,
     make_decode_step,
     make_prefill_step,
     make_verify_step,
+    spec_from_meta,
 )
 from deeplearning4j_tpu.serve.prefix_cache import (
     PrefixPageCache,
@@ -174,6 +194,12 @@ class ServeRequest:
         # per-accepted-token arrival stamps (perf_counter seconds) — the
         # inter-token latency loadgen's p99 reads (chunked-prefill bench)
         self.t_tokens: List[float] = []
+        # block-diffusion generation: every forward of this request's slot,
+        # ``(stamp, block, kind, masked before, positions accepted,
+        # tokens)``: the block's index, "denoise" or "commit", a bool a
+        # position, the in-block positions that left the mask, and the
+        # block's B tokens after the forward (0 where still masked)
+        self.forwards: List[tuple] = []
 
     @property
     def latency_s(self) -> Optional[float]:
@@ -199,7 +225,8 @@ class DecodeEngine:
                  prefix_cache=False, prefix_page_tokens: int = 16,
                  prefix_cache_pages: int = 256,
                  prefill_chunk: Optional[int] = None,
-                 speculative=None, runprof=None, tuned=None):
+                 speculative=None, runprof=None, tuned=None,
+                 spec: Optional[BlockSpec] = None):
         from deeplearning4j_tpu.telemetry.registry import default_registry
 
         # tuned= (ISSUE 20): adopt the autotuner's "serve" seam —
@@ -230,9 +257,17 @@ class DecodeEngine:
                 f"{prefill_chunk}")
         self.dims = lm_dims(params)
         self.n_heads = int(n_heads)
-        if self.dims["d_model"] % self.n_heads:
+        # the kind of block the params are (models/transformer_lm.BlockSpec)
+        # and, with it, the way the model generates; ``self.spec`` below is
+        # the speculative-decoding config, an older name
+        self.block_spec = spec if spec is not None else FLAGSHIP_SPEC
+        self.block_mode = self.block_spec.generation == "block_diffusion"
+        if self.block_spec.head_dim is None and \
+                self.dims["d_model"] % self.n_heads:
             raise ValueError(
                 f"d_model {self.dims['d_model']} % n_heads {n_heads} != 0")
+        if self.block_mode:
+            self._check_block_mode(speculative, prefill_chunk, prefix_cache)
         self.n_slots = int(n_slots)
         self.max_len = int(max_len)
         self.top_k = int(top_k)
@@ -246,15 +281,24 @@ class DecodeEngine:
             default_registry()
         self.params = prepare_serve_params(params, serve_dtype)
         self.weight_bytes = params_nbytes(self.params)
-        head_dim = self.dims["d_model"] // self.n_heads
+        head_dim = self.block_spec.head_size(self.dims["d_model"],
+                                             self.n_heads)
+        n_kv = self.block_spec.kv_heads(self.n_heads)
         self._cache = init_kv_cache(self.dims["n_layers"], self.n_slots,
-                                    self.n_heads, head_dim, self.max_len,
+                                    n_kv, head_dim, self.max_len,
                                     dtype=activation_dtype(serve_dtype))
-        self._decode = make_decode_step(self.n_heads, self.top_k,
-                                        params_transform=dequantize_tree)
+        programs = dict(params_transform=dequantize_tree,
+                        spec=self.block_spec)
+        if self.block_mode:
+            # the one hot program of a block-diffusion model, in the decode
+            # step's place
+            self._block_step = make_block_step(self.n_heads, self.top_k,
+                                               **programs)
+        else:
+            self._decode = make_decode_step(self.n_heads, self.top_k,
+                                            **programs)
         self._prefill = make_prefill_step(self.n_heads, self.top_k,
-                                          attn_impl=attn_impl,
-                                          params_transform=dequantize_tree)
+                                          attn_impl=attn_impl, **programs)
         self._buckets = self._make_buckets(min_bucket)
         # --- serving fast path (ISSUE 16), every seam defaulting off ---
         self.prefill_chunk = (None if prefill_chunk is None
@@ -268,12 +312,14 @@ class DecodeEngine:
             self._prefix = prefix_cache or None
         # one chunk executable serves chunked prefill AND the
         # prefix-cache suffix path (compiles keyed by chunk width)
-        self._chunk = (make_chunk_prefill_step(
-            self.n_heads, self.top_k, params_transform=dequantize_tree)
-            if (self.prefill_chunk is not None or self._prefix is not None)
-            else None)
+        self._chunk = (make_chunk_prefill_step(self.n_heads, self.top_k,
+                                               **programs)
+                       if (self.prefill_chunk is not None
+                           or self._prefix is not None) else None)
         self._chunking: dict = {}       # slot -> pending chunk plan
-        self.spec = resolve_speculative(speculative)
+        # the environment's speculation switch is for one-token models
+        self.spec = (None if self.block_mode
+                     else resolve_speculative(speculative))
         if self.spec is not None:
             if self.spec.k + 1 >= max_len:
                 raise ValueError(
@@ -287,17 +333,14 @@ class DecodeEngine:
                                                       serve_dtype)
             self._draft_cache = init_kv_cache(
                 lm_dims(draft_raw)["n_layers"], self.n_slots,
-                self.n_heads, head_dim, self.max_len,
+                n_kv, head_dim, self.max_len,
                 dtype=activation_dtype(serve_dtype))
-            self._draft_decode = make_decode_step(
-                self.n_heads, self.top_k,
-                params_transform=dequantize_tree)
+            self._draft_decode = make_decode_step(self.n_heads, self.top_k,
+                                                  **programs)
             self._draft_prefill = make_prefill_step(
-                self.n_heads, self.top_k, attn_impl=attn_impl,
-                params_transform=dequantize_tree)
-            self._verify = make_verify_step(
-                self.n_heads, self.top_k,
-                params_transform=dequantize_tree)
+                self.n_heads, self.top_k, attn_impl=attn_impl, **programs)
+            self._verify = make_verify_step(self.n_heads, self.top_k,
+                                            **programs)
         self.spec_verify_steps = 0
         self.spec_accepted_total = 0
         self._spec_proposed_total = 0
@@ -345,6 +388,14 @@ class DecodeEngine:
             # serve_spec_accept_rate stays UNBORN until the warmup floor
             # of verify steps: the serve_spec_accept_collapse rule
             # (op "<") must read "not yet speculating" as no-data
+        if self.block_mode:
+            self._c_block_steps = reg.counter("serve_block_steps_total")
+            self._c_block_forwards = {
+                kind: reg.counter("serve_block_forwards_total",
+                                  {"kind": kind})
+                for kind in ("denoise", "commit")}
+            self._c_block_accepted = reg.counter(
+                "serve_block_tokens_accepted_total")
         self._key = jax.random.PRNGKey(seed)
         # the lockwatch seam (ISSUE 11): plain primitives unless the
         # watch is armed (lockwatch fixture / DL4J_TPU_LOCKWATCH=1)
@@ -356,6 +407,13 @@ class DecodeEngine:
         self._tokens = np.zeros((self.n_slots,), np.int32)
         self._positions = np.zeros((self.n_slots,), np.int32)
         self._temps = np.zeros((self.n_slots,), np.float32)
+        # block mode: ``_positions`` holds each slot's current block's first
+        # row, and these its B tokens and which of them are still masked.
+        # The bitmap is the engine's own record: a prompt may hold the mask
+        # token's id as a token
+        width = self.block_spec.block_length
+        self._block_tokens = np.zeros((self.n_slots, width), np.int32)
+        self._block_masked = np.zeros((self.n_slots, width), bool)
         self._rid = itertools.count()
         self._step_idx = 0
         self._tick = None  # the open ``tick`` phase; set under the lock
@@ -367,6 +425,41 @@ class DecodeEngine:
         self.decode_steps = 0
         self._occupancy_sum = 0
         self._t_first_activity: Optional[float] = None
+
+    def _check_block_mode(self, speculative, prefill_chunk,
+                          prefix_cache) -> None:
+        """A block-diffusion spec that cannot be served, and the fast paths
+        that assume one causal token a step, are refused at construction."""
+        bs = self.block_spec
+        if not (1 <= bs.denoising_steps <= bs.block_length) or \
+                bs.attn_mask != "block":
+            raise ValueError(
+                "block-diffusion generation needs attn_mask='block' and 1 <= "
+                f"denoising_steps <= block_length, got {bs}")
+        if bs.mask_token_id is None or not (
+                0 <= bs.mask_token_id < self.dims["vocab"]):
+            raise ValueError(
+                f"mask_token_id {bs.mask_token_id} is not a row of the "
+                f"embedding ({self.dims['vocab']} rows)")
+        if speculative:
+            raise ValueError(
+                "speculative= with a block-diffusion spec: a draft proposes "
+                "the next tokens of a causal sequence for a verify step that "
+                "accepts a prefix of them; a block-diffusion step has no "
+                "next token, it unmasks positions of a block in any order")
+        if prefill_chunk is not None:
+            raise ValueError(
+                "prefill_chunk= with a block-diffusion spec: chunks go "
+                "through the cached step a chunk at a time and end in a "
+                "sampled first token; this prompt pass stores whole blocks "
+                "that see each other across a chunk's edge and samples "
+                "nothing")
+        if prefix_cache:
+            raise ValueError(
+                "prefix_cache= with a block-diffusion spec: a full hit "
+                "leaves the last prompt token to a decode tick and a partial "
+                "one to a causal suffix chunk; a block-diffusion slot has "
+                "neither, and its prompt's remainder opens a generated block")
 
     # ------------------------------------------------------------ loading ----
     @classmethod
@@ -412,6 +505,7 @@ class DecodeEngine:
                 "n_heads is not recoverable from param shapes — save with "
                 "meta=lm_checkpoint_meta(params, n_heads) or pass n_heads=")
         kwargs.setdefault("top_k", int(lm_meta.get("top_k", 2)))
+        kwargs.setdefault("spec", spec_from_meta(lm_meta))
         kwargs.setdefault("weight_version", f"ckpt-step-{manifest.step}")
         return cls(params, int(n_heads), **kwargs)
 
@@ -474,6 +568,12 @@ class DecodeEngine:
                 "generation)")
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
+        width = self.block_spec.block_length
+        if self.block_mode and \
+                len(prompt) // width * width + width > self.max_len:
+            raise ValueError(
+                f"prompt length {len(prompt)} leaves no whole block of "
+                f"{width} below max_len = {self.max_len}")
         req = ServeRequest(next(self._rid), prompt, max_new_tokens,
                            temperature,
                            self.eos_id if eos_id is _UNSET else eos_id)
@@ -569,6 +669,34 @@ class DecodeEngine:
                 self._run_chunk(req, slot, plan, idx)
             return
         # ---- classic one-shot bucketed prefill ----
+        if not self.block_mode:
+            tok, t1 = self._prefill_prompt(req, slot)
+            self._complete_prefill(req, slot, tok, t1, mode="full")
+            return
+        # ---- block diffusion: the prompt's whole blocks are stored (its
+        # remainder's rows too, which its block's first forward overwrites
+        # before it reads them; a prompt shorter than a block stores
+        # nothing); the remainder sits, known, at the head of the first
+        # generated block ----
+        width = self.block_spec.block_length
+        stored = n // width * width
+        if stored:
+            self._prefill_prompt(req, slot)
+            self._h_prefill_ms.observe(req.prefill_ms, exemplar=req.trace_id)
+        self._finish_prefill_span(req, mode="blocks")
+        self._positions[slot] = stored
+        self._block_tokens[slot] = 0
+        self._block_tokens[slot, :n - stored] = req.prompt[stored:]
+        self._block_masked[slot] = True
+        self._block_masked[slot, :n - stored] = False
+        if req.span is not None:
+            req.decode_span = req.span.tracer.start_span(
+                "serve.decode", parent=req.span, attrs={"slot": slot})
+
+    def _prefill_prompt(self, req: ServeRequest, slot: int) -> tuple:
+        """One dispatch of the prompt, padded to its bucket, into ``slot``'s
+        page, fenced: (the token it sampled, the fence's stamp)."""
+        n = len(req.prompt)
         bucket = self.bucket_for(n)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = req.prompt
@@ -584,7 +712,7 @@ class DecodeEngine:
             tok = int(np.asarray(tok))  # graftlint: allow[blocking-under-lock] deliberate: the scheduler lock IS the serialization — slot state may only change together with the fenced prefill result
         req.prefill_suffix_ms += ph.ms
         req.prefill_ms += ph.ms
-        self._complete_prefill(req, slot, tok, ph.t1, mode="full")
+        return tok, ph.t1
 
     def _draft_admit(self, req: ServeRequest, slot: int, n: int) -> None:
         """Seed the DRAFT cache for an admitted slot (speculative only):
@@ -676,7 +804,7 @@ class DecodeEngine:
             # EOS retire the request inside this very call
             req.decode_span = req.span.tracer.start_span(
                 "serve.decode", parent=req.span, attrs={"slot": slot})
-        self._accept_token(req, tok, now)
+        self._accept_tokens(req, (tok,), now)
 
     def _finish_prefill_span(self, req: ServeRequest, mode: str) -> None:
         if req.prefill_span is None:
@@ -691,29 +819,36 @@ class DecodeEngine:
         req.prefill_span.end()
         req.prefill_span = None
 
-    def _accept_token(self, req: ServeRequest, tok: int, now: float) -> None:
-        """Record one sampled token for ``req`` and retire it at EOS /
-        max_new_tokens / cache exhaustion (iteration-level eviction)."""
-        if req.t_first is None:
+    def _accept_tokens(self, req: ServeRequest, toks: Sequence[int],
+                       now: float) -> None:
+        """Record the tokens a step gives ``req``, in position order with
+        the step's one stamp (one in the one-token tick, a whole block of
+        0 to B in block-diffusion generation), and retire it at EOS /
+        max_new_tokens / cache exhaustion (iteration-level eviction); what
+        follows the token that retires it is dropped."""
+        if req.t_first is None and toks:
             # stamped at the first accepted token — for the prefix-cache
             # full-hit path that is the shared decode tick, not a prefill
             req.t_first = now
-        if req.eos_id is not None and tok == req.eos_id:
-            self._finish(req, "eos", now)
-            return
-        req.generated.append(tok)
-        req.t_tokens.append(now)
-        if req.decode_span is not None:
-            req.decode_span.add_event("accept", token=tok,
-                                      n=len(req.generated))
-        self.tokens_total += 1
-        self._c_tokens.inc()
-        if len(req.generated) >= req.max_new_tokens:
-            self._finish(req, "max_new_tokens", now)
-        elif int(self._positions[req.slot]) >= self.max_len:
-            # the cache page is exhausted: this token was the last that fits
-            self._finish(req, "max_len", now)
-        else:
+        for tok in toks:
+            if req.eos_id is not None and tok == req.eos_id:
+                self._finish(req, "eos", now)
+                return
+            req.generated.append(tok)
+            req.t_tokens.append(now)
+            if req.decode_span is not None:
+                req.decode_span.add_event("accept", token=tok,
+                                          n=len(req.generated))
+            self.tokens_total += 1
+            self._c_tokens.inc()
+            if len(req.generated) >= req.max_new_tokens:
+                self._finish(req, "max_new_tokens", now)
+                return
+            if int(self._positions[req.slot]) >= self.max_len:
+                # the cache page is exhausted: this token was the last that
+                # fits
+                self._finish(req, "max_len", now)
+                return
             self._tokens[req.slot] = tok
 
     def _finish(self, req: ServeRequest, reason: str, now: float) -> None:
@@ -760,6 +895,8 @@ class DecodeEngine:
             self._tokens[req.slot] = 0
             self._positions[req.slot] = 0
             self._temps[req.slot] = 0.0
+            self._block_tokens[req.slot] = 0
+            self._block_masked[req.slot] = False
             req.slot = None
         completed = self._c_completed.get(reason)
         if completed is None:
@@ -841,6 +978,9 @@ class DecodeEngine:
                     # k+1 draft dispatches interleaved with their fences
                     # and the acceptance: no finer split
                     decode_ms = self._spec_step(active, step_span)
+            elif active and self.block_mode:
+                decode = self._block_tick(active, tick)
+                decode_ms = decode.ms
             elif active:
                 with _trace.phase("tick.decode", tick.tick,
                                   occupancy=len(active)) as decode:
@@ -862,7 +1002,8 @@ class DecodeEngine:
                         if req.decode_span is not None:
                             req.decode_ms += decode_ms
                         self._positions[slot] += 1
-                        self._accept_token(req, int(toks[slot]), decode.t1)
+                        self._accept_tokens(req, (int(toks[slot]),),
+                                            decode.t1)
             occupancy_after = sum(r is not None for r in self._slots)
             if active:
                 self._g_active_slots.set(float(occupancy_after))
@@ -895,6 +1036,69 @@ class DecodeEngine:
                 trace_id=(step_span.trace_id
                           if step_span is not None else None)))
         return emitted
+
+    def _block_tick(self, active: List[ServeRequest], tick):
+        """The block-diffusion tick (called under the scheduler lock): ONE
+        ``jit_block_step`` over every slot inside ``tick.decode``, one
+        fence, then ``tick.accept``. A live slot's forward is a denoising
+        one while any position of its block is masked, else the commit.
+        Returns the ``tick.decode`` phase."""
+        width = self.block_spec.block_length
+        before = self._block_masked  # the step hands back new arrays
+        slots = [r.slot for r in active]
+        denoising = before[slots].any(axis=1)
+        n_denoise = int(denoising.sum())
+        with _trace.phase("tick.decode", tick.tick, occupancy=len(active),
+                          kind="block", denoise=n_denoise,
+                          commit=len(active) - n_denoise) as decode:
+            self._cache, toks, masked = self._block_step(
+                self.params, self._cache, self._block_tokens,
+                self._positions, self._block_masked, self._temps, self._key,
+                self._step_idx)
+            self._step_idx += 1
+            # enqueue back; the device runs until the fence
+            decode.attrs["t_disp"] = time.perf_counter()
+            toks, masked = jax.device_get((toks, masked))  # graftlint: allow[blocking-under-lock] deliberate: acceptance must see the fenced step's tokens and bitmap, exactly like the decode tick
+            # the host's own copies: the next block's state is written here
+            toks, masked = toks.copy(), masked.copy()
+            decode.attrs["accepted"] = int(
+                (before[slots] & ~masked[slots]).sum())
+        self._block_tokens, self._block_masked = toks, masked
+        self._h_decode_step_ms.observe(decode.ms)
+        self.decode_steps += 1
+        self._occupancy_sum += len(active)
+        self._c_block_steps.inc()
+        self._c_block_forwards["denoise"].inc(n_denoise)
+        self._c_block_forwards["commit"].inc(len(active) - n_denoise)
+        self._c_block_accepted.inc(decode.attrs["accepted"])
+        now = decode.t1
+        with _trace.phase("tick.accept", tick.tick):
+            # plain lists, made once a tick: the records are Python's
+            starts = self._positions.tolist()
+            was, left, held = before.tolist(), masked.tolist(), toks.tolist()
+            for req, slot, denoise in zip(active, slots, denoising.tolist()):
+                if req.decode_span is not None:
+                    req.decode_ms += decode.ms
+                start = starts[slot]
+                req.forwards.append((
+                    now, start // width, "denoise" if denoise else "commit",
+                    tuple(was[slot]),
+                    tuple(i for i in range(width)
+                          if was[slot][i] and not left[slot][i]),
+                    tuple(held[slot])))
+                if denoise:
+                    if not any(left[slot]):
+                        # the block is whole: the request gets what of it
+                        # lies past its prompt
+                        first = max(0, len(req.prompt) - start)
+                        self._accept_tokens(req, held[slot][first:], now)
+                elif start + 2 * width > self.max_len:
+                    # no room for another block: the page is exhausted
+                    self._finish(req, "max_len", now)
+                else:
+                    self._positions[slot] = start + width
+                    toks[slot], masked[slot] = 0, True
+        return decode
 
     def _spec_step(self, active: List[ServeRequest], step_span) -> float:
         """One speculative iteration (called under the scheduler lock):
@@ -962,8 +1166,10 @@ class DecodeEngine:
                                           proposed=k,
                                           emitted=len(emitted))
             for j, tok in enumerate(emitted):
+                # a token at a time: the page-exhaustion rule reads the
+                # position each one leaves
                 self._positions[slot] = p + j + 1
-                self._accept_token(req, tok, now)
+                self._accept_tokens(req, (tok,), now)
                 if req.done.is_set():
                     break  # retired mid-run; trailing tokens discarded
         if self.spec_verify_steps >= 8:
@@ -1093,7 +1299,8 @@ class DecodeEngine:
                         / max(1, self._spec_proposed_total)),
                 } if self.spec is not None else None),
                 "model": dict(self.dims, n_heads=self.n_heads,
-                              top_k=self.top_k),
+                              top_k=self.top_k,
+                              generation=self.block_spec.generation),
             }
 
     def metrics_record(self) -> dict:

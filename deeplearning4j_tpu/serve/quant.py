@@ -73,7 +73,8 @@ class QuantTensor:
 # (models/transformer_lm.init_lm_params); the last two axes are
 # (contraction, output-channel), whatever stacking axes precede them
 _MATMUL_KEYS = frozenset(
-    {"wq", "wk", "wv", "wo", "router", "w1", "w2", "dec_w", "embed"})
+    {"wq", "wk", "wv", "wo", "router", "w1", "w2", "wg", "wu", "wd", "dec_w",
+     "embed"})
 
 
 def _quantize_leaf(path, w):

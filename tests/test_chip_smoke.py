@@ -58,6 +58,19 @@ def test_save_and_serve_phases(trained, tmp_path):
     assert got["requests"] == 2 and got["prefill_buckets_used"] == [8]
 
 
+def test_blockdiff_phase_agrees_with_the_reference():
+    got = chip_smoke.phase_blockdiff(chip_smoke.BLOCKDIFF,
+                                     chip_smoke.TOL_BLOCKDIFF)
+    assert got["requests"] == len(chip_smoke.BLOCKDIFF["prompt_lens"])
+    # on the CPU the float32 program picks the reference's own tokens
+    assert got["compared"]["widest_logit_gap"] < 1e-3
+    assert got["compared"]["schedule_faults"] == 0
+    with pytest.raises(chip_smoke.SmokeFailure, match="schedule_faults"):
+        chip_smoke.phase_blockdiff(chip_smoke.BLOCKDIFF,
+                                   dict(chip_smoke.TOL_BLOCKDIFF,
+                                        schedule_faults=-1.0))
+
+
 def test_kernel_phase_interprets_on_the_cpu():
     got = chip_smoke.phase_kernels(
         dict(dense=(16, 128, 128), lstm=((8, 128),), flash=None))
