@@ -53,7 +53,14 @@ SERVE = dict(slots=8, max_len=2048, max_new_tokens=32,
 # (m, k, n) of fused_dense; (batch, hidden) of lstm_gates, the second at the
 # largest hidden the gate admits; (B, H, T, D) of the flash kernel
 KERNELS = dict(dense=(512, 1024, 1024), lstm=((256, 512), (256, 2048)),
-               flash=(4, 4, 2048, 128))
+               flash=(4, 4, 2048, 128),
+               # the routed expert layer at the benchmark's flagship widths:
+               # (rows, type, gradients too) of the sat cell's 2,048-row
+               # prefill, and the gradients once at the fewest rows that
+               # route (no training program calls the routed form)
+               routed=dict(d_model=2048, d_ff=1024, n_experts=64, top_k=8,
+                           calls=((2048, "bfloat16", False),
+                                  (512, "float32", True))))
 LEGACY_VOCAB = 512
 # the benchmark's block-diffusion cell at its rehearsal size: prompts of
 # every remainder mod the block length (one shorter than a block), two
@@ -408,8 +415,64 @@ def _compare(name: str, fn, ref_fn, args, tol: float, compiled: bool) -> dict:
             "tol": tol}
 
 
+def _rel_l2(got, ref) -> float:
+    import numpy as np
+
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    return float(np.linalg.norm(got - ref) / max(np.linalg.norm(ref), 1e-30))
+
+
+def _routed_against_dense(shape: dict, tol: float) -> dict:
+    """``routed_moe`` against ``dense_moe``, the all-experts form it stands
+    in for, at shapes the chooser routes: values, and where a call says so
+    the gradient in the input, the router and every expert leaf. Both at the
+    chip's default precision, so both route alike; errors are norms over a
+    whole array, which one token routed elsewhere on a tie does not decide."""
+    import jax
+
+    from deeplearning4j_tpu.models import transformer_lm as lm
+
+    d, f, n_experts, top_k = (shape[k] for k in
+                              ("d_model", "d_ff", "n_experts", "top_k"))
+    keys = iter(jax.random.split(jax.random.PRNGKey(5), 8))
+    normal = lambda *s: jax.random.normal(next(keys), s)  # noqa: E731
+    router = normal(d, n_experts) / d ** 0.5
+    experts = {"w1": normal(n_experts, d, f) / d ** 0.5,
+               "b1": 0.1 * normal(n_experts, f),
+               "w2": normal(n_experts, f, d) / f ** 0.5,
+               "b2": 0.1 * normal(n_experts, d)}
+    report = {}
+    for n, dtype, with_grads in shape["calls"]:
+        name = f"routed_moe_{n}_{dtype}"
+        check(lm._routes(n, top_k, n_experts),
+              f"{name}: the chooser would not route this call")
+        args = jax.tree_util.tree_map(
+            lambda a: a.astype(dtype), (router, experts, normal(n, d)))
+
+        def run(form):
+            fn = lambda *a: form(*a, top_k)  # noqa: E731
+            if not with_grads:
+                return jax.jit(fn)(*args), ()
+            out, grads = _lower_out_and_grads(fn, args).compile()(*args)
+            return out, jax.tree_util.tree_leaves(grads)
+
+        out, grads = run(lm.routed_moe)
+        ref, ref_grads = run(lm.dense_moe)
+        err = _rel_l2(out, ref)
+        gerr = max((_rel_l2(g, r) for g, r in zip(grads, ref_grads)),
+                   default=0.0)
+        check(err <= tol and gerr <= tol,
+              f"{name}: error {err:.3g} (forward) {gerr:.3g} (gradient) "
+              f"over tolerance {tol:g}")
+        report[name] = {"branch": "routed", "fwd_err": float(f"{err:.3g}"),
+                        "grad_err": float(f"{gerr:.3g}"), "tol": tol}
+    return report
+
+
 def phase_kernels(shapes: dict) -> dict:
-    """F: each Pallas kernel at a shape that takes its Pallas branch."""
+    """F: each Pallas kernel at a shape that takes its Pallas branch, and the
+    routed expert layer against the all-experts one."""
     import jax
     import jax.numpy as jnp
 
@@ -452,6 +515,8 @@ def phase_kernels(shapes: dict) -> dict:
             lambda q, k, v: attention_core(q, k, v, causal=True, impl="flash"),
             lambda q, k, v: dense_attention(q, k, v, causal=True),
             (q, kk, v), TOL_MATMUL, compiled)
+    if shapes.get("routed"):
+        report.update(_routed_against_dense(shapes["routed"], TOL_MATMUL))
     return report
 
 

@@ -223,20 +223,194 @@ def expert_fn(p: dict, t: Array) -> Array:
     return jax.nn.relu(t @ p["w1"] + p["b1"]) @ p["w2"] + p["b2"]
 
 
+def _route(router_w: Array, x: Array, top_k: int, spec: BlockSpec) -> tuple:
+    """A token's experts and their gates, (idx (N, k), gates (N, k)): the
+    one routing of both forms of the one-chip expert layer."""
+    logits = (jnp.matmul(x, router_w, preferred_element_type=jnp.float32)
+              if spec.accum_f32 else x @ router_w)
+    return _routing(logits, top_k, spec.norm_topk_prob)
+
+
+def _gate_matrix(idx: Array, gates: Array, n_experts: int) -> Array:
+    """(N, E): a token's gate at each expert it chose, 0 elsewhere."""
+    onehot = jax.nn.one_hot(idx, n_experts)  # (N, k, E)
+    return jnp.sum(gates[..., None] * onehot, axis=1)
+
+
 def dense_moe(router_w: Array, experts: dict, x: Array,
               top_k: int = 2, spec: BlockSpec = FLAGSHIP_SPEC) -> Array:
     """Differentiable single-device MoE (every expert on every token,
     gate-combined; no capacity drops) — the parity oracle for moe_apply
-    with ample capacity, and the FFN of the pp-staged path where the
-    expert axis is not sharded."""
-    logits = (jnp.matmul(x, router_w, preferred_element_type=jnp.float32)
-              if spec.accum_f32 else x @ router_w)
-    idx, gates = _routing(logits, top_k, spec.norm_topk_prob)
+    with ample capacity and for ``routed_moe``, the FFN of the one-chip
+    train step and of the pp-staged path, and the form of the serving
+    programs' expert layer where rows an expert are few (``moe_ffn``)."""
+    idx, gates = _route(router_w, x, top_k, spec)
     y_all = jax.vmap(lambda p: expert_fn(p, x))(experts)  # (E, N, d)
-    n_experts = router_w.shape[1]
-    onehot = jax.nn.one_hot(idx, n_experts)  # (N, k, E)
-    g = jnp.sum(gates[..., None] * onehot, axis=1)  # (N, E)
+    g = _gate_matrix(idx, gates, router_w.shape[1])
     return jnp.einsum("ne,end->nd", g, y_all)
+
+
+# Rows an expert (N * top_k // E, static at trace time) from which the
+# one-chip expert layer sorts its routes and runs grouped matmuls instead of
+# every expert on every token. Below it the layer is a read of every
+# expert's weights and ``dense_moe`` is the cheapest way to do that. One
+# layer on the v5e, all-experts / routed in ms at 16, 32, 64, 128, 256 and
+# 512 rows an expert (PERF.md section 3: PR 29's sweep, re-read in PR 30):
+#   flagship widths, bfloat16, forward: 0.83/1.03 0.95/1.14 1.76/1.28
+#     4.09/1.85 7.76/2.31 15.4/4.97
+#   flagship widths, float32, with the backward: 6.6/5.0 7.4/5.5 8.5/6.2
+#     14.7/9.6 26.3/15.5 50.1/24.8
+#   SDAR's widths, bfloat16, forward: 1.96/2.20 3.80/2.41 7.52/2.81
+#     18.8/3.61 37.0/6.63 (none at 512)
+# 64 is the first step at which the routed form wins at both widths in
+# every mode; at 16 (the decode and block steps) it loses in both forwards.
+ROUTED_MIN_ROWS_PER_EXPERT = 64
+# And experts a route (E // top_k) from which it does: every expert on every
+# token computes E / k times the required work, on dense matmuls that reach
+# 70% of the chip's peak where the grouped ones reach a third. At E / k = 2
+# the all-experts form is 1.2 to 1.4 times faster (E 4, k 2 at 4,096 rows:
+# 0.63 against 0.78 ms forward, 2.06 against 2.81 with the backward), at 3
+# the two tie, at 4 the routed form wins (E 32, k 8: 1.96 against 1.26 and
+# 6.96 against 6.07; Mixtral's widths, E 8, k 2: 42.1 against 16.8 and 158
+# against 111).
+ROUTED_MIN_EXPERTS_PER_ROUTE = 4
+
+
+@jax.custom_vjp
+def _permute_rows(a: Array, perm: Array, inverse: Array) -> Array:
+    """``a[perm]`` for a permutation whose inverse the caller has: the
+    cotangent goes back through ``inverse`` as a second gather, where the
+    transpose of a plain gather is a scatter-add that cannot know its rows
+    are distinct."""
+    return a[perm]
+
+
+_permute_rows.defvjp(
+    lambda a, perm, inverse: (a[perm], (perm, inverse)),
+    lambda res, ct: (_permute_rows(ct, res[1], res[0]), None, None))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _route_rows(x: Array, order: Array, inverse: Array, top_k: int) -> Array:
+    """(N, d) token rows → (N * k, d), one a route, in the order ``order``
+    (a permutation of the routes, ``inverse`` its inverse; route r is token
+    r // k). The cotangent is un-sorted and summed over a token's k routes."""
+    return x[order // top_k]
+
+
+_route_rows.defvjp(
+    lambda x, order, inverse, top_k: (x[order // top_k], (order, inverse)),
+    lambda top_k, res, ct: (
+        jnp.sum(_permute_rows(ct, res[1], res[0])
+                .reshape(-1, top_k, ct.shape[-1]), axis=1), None, None))
+
+
+def _grouped_matmul(rows: Array, w: Array, sizes: Array,
+                    layer: Optional[Array] = None) -> Array:
+    """(M, K) rows sorted by group @ (G, K, N) → (M, N): group g's
+    ``sizes[g]`` consecutive rows through ``w[g]``. The megablox Pallas
+    kernel, differentiable through its ``custom_vjp`` (a second grouped
+    matmul for the rows, the transposed one for the weights); interpreted on
+    the CPU backend like the repo's other Pallas kernels. One tiling, sized
+    by the element so that all three kernels' blocks fit VMEM in either
+    type (one layer's forward at 256 rows an expert in bfloat16 on the v5e:
+    2.7 to 3.0 ms over five tilings with tm 128 or 256, 18.6 at the
+    kernel's default of 128 cubed; float32 with the backward at 512 rows:
+    28.7 ms at this one, 31.3 and 33.3 at two others; tk 1024 and tn 1024
+    in float32 overflow VMEM in the transposed kernel; PR 29's sweep).
+
+    With ``layer``, ``w`` is every layer's (L, G, K, N) and the kernel reads
+    that layer's groups where they lie: the other layers' groups are empty,
+    and an empty group is never visited."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from deeplearning4j_tpu.ops.pallas_kernels import _interpret
+
+    if layer is not None:
+        n_groups = w.shape[1]
+        w = w.reshape((-1,) + w.shape[2:])
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((w.shape[0],), sizes.dtype), sizes,
+            (layer * n_groups,))
+    (m, k), n = rows.shape, w.shape[2]
+    dtype = jnp.result_type(rows.dtype, w.dtype)
+    tm = min(256, -(-m // 8) * 8)
+    padded = -(-m // tm) * tm  # rows past the last group are not computed
+    if padded != m:
+        rows = jnp.pad(rows, ((0, padded - m), (0, 0)))
+    out = gmm(rows, w, sizes, dtype,
+              (tm, min(1024, k), min(2048 // dtype.itemsize, n)),
+              interpret=_interpret())
+    return out[:m]
+
+
+def routed_moe(router_w: Array, experts: dict, x: Array,
+               top_k: int = 2, spec: BlockSpec = FLAGSHIP_SPEC,
+               layer: Optional[Array] = None) -> Array:
+    """``dense_moe``'s mathematics with only the routed work done: the
+    N * k routes sorted by expert, each expert's matrices applied to its own
+    rows as grouped matmuls, the rows un-sorted, scaled by their gate and
+    summed over k in float32. Every route of the top-k is computed: no
+    capacity, no drop. With ``layer``, ``experts`` holds every layer's
+    leaves stacked, as ``params["blocks"]`` keeps them, and the matrices
+    are read in place (``_grouped_matmul``)."""
+    idx, gates = _route(router_w, x, top_k, spec)
+    n, n_experts = x.shape[0], router_w.shape[1]
+    with jax.named_scope("moe_routed_sort"):
+        flat = idx.reshape(-1)
+        # routes by expert (stable: a token's rows keep their order)
+        expert_of, order = jax.lax.sort(
+            (flat, jnp.arange(flat.shape[0], dtype=flat.dtype)), num_keys=1)
+        inverse = jnp.argsort(order)
+        sizes = jnp.sum(jax.nn.one_hot(flat, n_experts, dtype=jnp.int32),
+                        axis=0)
+        rows = _route_rows(x, order, inverse, top_k)
+    with jax.named_scope("moe_routed_experts"):
+        dot = partial(_grouped_matmul, sizes=sizes, layer=layer)
+        bias = lambda name: (experts[name] if layer is None  # noqa: E731
+                             else experts[name][layer])
+        if "wg" in experts:
+            y = dot(jax.nn.silu(dot(rows, experts["wg"]))
+                    * dot(rows, experts["wu"]), experts["wd"])
+        elif "b1" not in experts:
+            y = dot(jax.nn.relu(dot(rows, experts["w1"])), experts["w2"])
+        else:
+            # a row's bias as a one-hot matmul, exact at "highest": its
+            # transpose is a matmul too, where a gather's is a scatter-add
+            b1 = jnp.matmul(jax.nn.one_hot(expert_of, n_experts,
+                                           dtype=experts["b1"].dtype),
+                            bias("b1"), precision="highest")
+            y = dot(jax.nn.relu(dot(rows, experts["w1"]) + b1),
+                    experts["w2"])
+    with jax.named_scope("moe_routed_combine"):
+        # float32 out, as dense_moe's gate-combine gives: callers cast back
+        y = _permute_rows(y, inverse, order).reshape(n, top_k, -1)
+        out = jnp.sum(y.astype(jnp.float32)
+                      * gates[..., None].astype(jnp.float32), axis=1)
+        if "b2" in experts:
+            # the gated sum of the second biases, once a token
+            out = out + _gate_matrix(idx, gates, n_experts) @ bias("b2")
+        return out
+
+
+def _routes(n_rows: int, top_k: int, n_experts: int) -> bool:
+    """Whether a call of these static shapes takes the routed form."""
+    return (n_rows * top_k // n_experts >= ROUTED_MIN_ROWS_PER_EXPERT
+            and n_experts >= ROUTED_MIN_EXPERTS_PER_ROUTE * top_k)
+
+
+def moe_ffn(router_w: Array, experts: dict, x: Array,
+            top_k: int = 2, spec: BlockSpec = FLAGSHIP_SPEC,
+            layer: Optional[Array] = None) -> Array:
+    """The one-chip expert layer, its form chosen from the static shapes of
+    the call (``_routes``): routed where rows an expert are many and a route
+    leaves most experts out, else every expert on every token. ``layer``
+    as in ``routed_moe``."""
+    if _routes(x.shape[0], top_k, router_w.shape[1]):
+        return routed_moe(router_w, experts, x, top_k, spec, layer)
+    if layer is not None:
+        experts = jax.tree_util.tree_map(lambda a: a[layer], experts)
+    return dense_moe(router_w, experts, x, top_k, spec)
 
 
 def _attn_block(params: dict, h: Array, n_heads: int, attn_core) -> Array:
@@ -390,6 +564,10 @@ def dense_loss_fn(n_heads: int, top_k: int = 2, aux_weight: float = 1e-2,
         attn_core=lambda q, k, v: attention_core(q, k, v, causal=True,
                                                  impl=attn_impl,
                                                  block_q=bq, block_k=bk),
+        # dense_moe and not moe_ffn, though a 4,096-row step would route: the
+        # routed train step trains 1.33 times faster and was refused for its
+        # set-up (six Mosaic calls to trace, lower and load: setup_s +4.1 s
+        # against a bound of 2.0 s in train-1chip-seq4k; ledger, PR 29)
         moe_fn=lambda rw, ex, x: dense_moe(rw, ex, x, top_k),
         aux_weight=aux_weight,
     )
@@ -1083,12 +1261,14 @@ def _prefill_core(spec: BlockSpec, attn_impl: Optional[str]):
 
 
 def _decoder_block_kv(layer_params: dict, h: Array, n_heads: int, attn_core,
-                      top_k: int, spec: BlockSpec = FLAGSHIP_SPEC) -> tuple:
-    """``_decoder_block`` with the dense MoE FFN, additionally returning the
-    layer's projected K/V (B, H_kv, T, Dh) for cache seeding. The op
+                      top_k: int, spec: BlockSpec = FLAGSHIP_SPEC,
+                      layer: Optional[Array] = None) -> tuple:
+    """``_decoder_block`` with the one-chip MoE FFN, additionally returning
+    the layer's projected K/V (B, H_kv, T, Dh) for cache seeding. The op
     sequence is IDENTICAL to _attn_block + _decoder_block's dense path —
     prefill logits must stay bit-identical to lm_forward's (pinned in
-    tests/test_serve.py)."""
+    tests/test_serve.py). With ``layer``, ``layer_params["experts"]`` is
+    every layer's, stacked (``_prefill_hidden``)."""
     with jax.named_scope("lm_attn"):
         hn = _norm(layer_params, "ln", h, spec)
         q, k, v = _qkv(layer_params, hn, n_heads, spec,
@@ -1099,18 +1279,21 @@ def _decoder_block_kv(layer_params: dict, h: Array, n_heads: int, attn_core,
         # f32
         h = h + (_merge_heads(attn_core(q, k, v))
                  @ layer_params["wo"]).astype(h.dtype)
-    return _dense_moe_ffn(layer_params, h, top_k, spec), k, v
+    return _dense_moe_ffn(layer_params, h, top_k, spec, layer), k, v
 
 
 def _dense_moe_ffn(layer_params: dict, h: Array, top_k: int,
-                   spec: BlockSpec = FLAGSHIP_SPEC) -> Array:
+                   spec: BlockSpec = FLAGSHIP_SPEC,
+                   layer: Optional[Array] = None) -> Array:
     """The serving blocks' FFN half under ``lm_moe``: second layer norm,
-    the dense MoE, the residual in the carry's dtype."""
+    the one-chip expert layer (``moe_ffn``: routed where rows an expert
+    are many, every expert on every token where they are few), the residual
+    in the carry's dtype."""
     with jax.named_scope("lm_moe"):
         h2 = _norm(layer_params, "ln2", h, spec)
         flat = h2.reshape(-1, h2.shape[-1])
-        moe_out = dense_moe(layer_params["router"], layer_params["experts"],
-                            flat, top_k, spec)
+        moe_out = moe_ffn(layer_params["router"], layer_params["experts"],
+                          flat, top_k, spec, layer)
         return h + moe_out.reshape(h.shape).astype(h.dtype)
 
 
@@ -1121,12 +1304,27 @@ def _prefill_hidden(params: dict, tokens: Array, n_heads: int, top_k: int,
     with jax.named_scope("lm_embed"):
         h = params["embed"][tokens]
 
-    def step(h, layer_params):
+    # The routed form's grouped matmul is a Mosaic call, and a Mosaic call
+    # cannot fuse the scan's slice of a stacked leaf: sliced by the scan,
+    # every expert's weights are copied once a layer (1.3 ms of a 3.6 ms
+    # layer at the 2,048 bucket on the v5e, PR 29). So where the layer
+    # routes, the experts stay whole outside the scan and a layer reads its
+    # own in place; where it does not, the scan is the one it always was.
+    blocks = params["blocks"]
+    xs = (blocks, None)
+    if _routes(tokens.size, top_k, blocks["router"].shape[-1]):
+        xs = ({name: leaf for name, leaf in blocks.items()
+               if name != "experts"}, jnp.arange(lm_n_layers(params)))
+
+    def step(h, xs):
+        layer_params, layer = xs
+        if layer is not None:
+            layer_params = dict(layer_params, experts=blocks["experts"])
         h, k, v = _decoder_block_kv(layer_params, h, n_heads, core, top_k,
-                                    spec)
+                                    spec, layer)
         return h, (k, v)
 
-    h, (ks, vs) = jax.lax.scan(step, h, params["blocks"])
+    h, (ks, vs) = jax.lax.scan(step, h, xs)
     return h, ks, vs
 
 

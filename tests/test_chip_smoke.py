@@ -79,6 +79,22 @@ def test_kernel_phase_interprets_on_the_cpu():
                         "lstm_gates_8x128"}
 
 
+def test_kernel_phase_holds_the_routed_experts_to_the_dense_ones():
+    """The routed stage at toy widths that the chooser routes: values in
+    both types, gradients in float32."""
+    got = chip_smoke._routed_against_dense(
+        dict(d_model=16, d_ff=32, n_experts=8, top_k=2,
+             calls=((512, "bfloat16", False), (512, "float32", True))), 2e-2)
+    assert set(got) == {"routed_moe_512_bfloat16", "routed_moe_512_float32"}
+    assert got["routed_moe_512_float32"]["grad_err"] < 1e-4
+    with pytest.raises(chip_smoke.SmokeFailure, match="would not route"):
+        chip_smoke._routed_against_dense(
+            dict(d_model=16, d_ff=32, n_experts=8, top_k=2,
+                 calls=((8, "float32", False),)), 2e-2)
+    assert chip_smoke.KERNELS["routed"]["calls"] == (
+        (2048, "bfloat16", False), (512, "float32", True))
+
+
 def test_compile_cache_helper_places_and_respects():
     from deeplearning4j_tpu.utils.compile_cache import ensure_compile_cache
 
