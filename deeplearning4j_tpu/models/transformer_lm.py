@@ -413,41 +413,147 @@ def moe_ffn(router_w: Array, experts: dict, x: Array,
     return dense_moe(router_w, experts, x, top_k, spec)
 
 
-def _attn_block(params: dict, h: Array, n_heads: int, attn_core) -> Array:
+# ---- the block's parts, each reading the spec at trace time; under the
+# default spec each emits the operations the flagship's block always had ----
+
+def _rmsnorm(x: Array, g: Array, eps: float) -> Array:
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (y * g).astype(x.dtype)
+
+
+def _norm(p: dict, name: str, x: Array, spec: BlockSpec) -> Array:
+    if spec.norm == "layernorm":
+        return _layernorm(x, p[name + "_g"], p[name + "_b"])
+    return _rmsnorm(x, p[name + "_g"], spec.norm_eps)
+
+
+def _rope(x: Array, positions: Array, theta: float) -> Array:
+    """Rotary positions, rotate-half over the head size, no scaling. x:
+    (B, H, T, Dh); positions: (T,) or one row a batch row, (B, T)."""
+    hd = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    ang = jnp.concatenate([ang, ang], axis=-1)            # (..., T, Dh)
+    if positions.ndim == 2:
+        ang = ang[:, None]                                # (B, 1, T, Dh)
+    x32 = x.astype(jnp.float32)
+    rot = jnp.concatenate([-x32[..., hd // 2:], x32[..., :hd // 2]], -1)
+    return (x32 * jnp.cos(ang) + rot * jnp.sin(ang)).astype(x.dtype)
+
+
+def _qkv(p: dict, hn: Array, n_heads: int, spec: BlockSpec,
+         positions: Optional[Array]) -> tuple:
+    """The three projections of the normed input as heads: q (B, H, T, Dh),
+    k and v (B, H_kv, T, Dh), with the spec's q/k norm and, at
+    ``positions`` (read under a rotary spec alone), its rotary term."""
+    n_kv = spec.kv_heads(n_heads)
+    q = _split_heads(hn @ p["wq"], n_heads)
+    k = _split_heads(hn @ p["wk"], n_kv)
+    v = _split_heads(hn @ p["wv"], n_kv)
+    if spec.qk_norm:
+        q = _rmsnorm(q, p["q_g"], spec.norm_eps)
+        k = _rmsnorm(k, p["k_g"], spec.norm_eps)
+    if spec.rope_theta is not None:
+        q = _rope(q, positions, spec.rope_theta)
+        k = _rope(k, positions, spec.rope_theta)
+    return q, k, v
+
+
+def _scores(q: Array, k: Array, spec: BlockSpec) -> Array:
+    kw = {"preferred_element_type": jnp.float32} if spec.accum_f32 else {}
+    return jnp.einsum("shqd,shkd->shqk", q, k, **kw) / jnp.sqrt(
+        q.shape[-1] * 1.0)
+
+
+def _lm_head(params: dict, h: Array, spec: BlockSpec) -> Array:
+    if spec.final_norm:
+        h = _norm(params, "lnf", h, spec)
+    logits = (jnp.matmul(h, params["dec_w"],
+                         preferred_element_type=jnp.float32)
+              if spec.accum_f32 else h @ params["dec_w"])
+    return logits + params["dec_b"] if spec.bias else logits
+
+
+def _block(p: dict, h: Array, n_heads: int, spec: BlockSpec, attend, ffn,
+           rows=None) -> tuple:
+    """The decoder block, the one every program runs, on h (B, T, d) →
+    (h, kept, flat). What differs between training, the prompt pass and the
+    cached step is handed in: ``attend(q, k, v) -> (out, kept)``, the
+    attention of the caller's program and what that program keeps of the
+    layer (nothing in training, the layer's K/V in the prompt pass, the
+    carried cache in the cached step); ``ffn(router_w, experts, flat)``, its
+    expert layer; ``rows()``, the query rows' positions (B, T) where they
+    are not 0..T-1 (read under a rotary spec alone, and there, where the
+    projections are made: a program keeps the order it was compiled in).
+    ``flat`` is the (B·T, d) pre-expert activations, the load-balance aux
+    term's input."""
     with jax.named_scope("lm_attn"):
-        hn = _layernorm(h, params["ln_g"], params["ln_b"])
-        q = _split_heads(hn @ params["wq"], n_heads)
-        k = _split_heads(hn @ params["wk"], n_heads)
-        v = _split_heads(hn @ params["wv"], n_heads)
-        return h + _merge_heads(attn_core(q, k, v)) @ params["wo"]
-
-
-def _decoder_block(layer_params: dict, h: Array, n_heads: int, attn_core,
-                   moe_fn) -> tuple:
-    """One decoder block on (B, T, d) → (h, moe_in) with moe_in the
-    (B·T, d) pre-MoE activations (the load-balance aux input)."""
-    h = _attn_block(layer_params, h, n_heads, attn_core)
-    # at the call site, so that the scope is entered once and the mesh
+        hn = _norm(p, "ln", h, spec)
+        positions = None
+        if spec.rope_theta is not None:
+            positions = rows() if rows else jnp.arange(h.shape[1])
+        q, k, v = _qkv(p, hn, n_heads, spec, positions)
+        out, kept = attend(q, k, v)
+        # .astype keeps the carry's dtype stable under serve_dtype="bf16"
+        # (float32 score math widens the core's output); identity at f32
+        h = h + (_merge_heads(out) @ p["wo"]).astype(h.dtype)
+    # at this one site, so that the scope is entered once and the mesh
     # path's moe_apply lies inside it with its moe_all2all_* scopes
     with jax.named_scope("lm_moe"):
-        h2 = _layernorm(h, layer_params["ln2_g"], layer_params["ln2_b"])
+        h2 = _norm(p, "ln2", h, spec)
         flat = h2.reshape(-1, h2.shape[-1])
-        moe_out = moe_fn(layer_params["router"], layer_params["experts"],
-                         flat)
-        return h + moe_out.reshape(h.shape), flat
+        moe_out = ffn(p["router"], p["experts"], flat)
+        return h + moe_out.reshape(h.shape).astype(h.dtype), kept, flat
+
+
+def _stacked_layers(blocks: dict, h: Array, layer_fn,
+                    experts_whole: bool = False) -> tuple:
+    """The layer loop that stacks: ONE ``lax.scan`` of ``layer_fn(
+    layer_params, h, layer) -> (h, out)`` over the stacked block params,
+    ``out`` stacked by layer (training's pre-expert activations, the prompt
+    pass's K/V, nothing in a pipeline stage). Compile time stays O(1) in
+    depth and a layer's collectives trace once. ``layer`` is None unless
+    ``experts_whole``."""
+    xs = (blocks, None)
+    if experts_whole:
+        # The routed form's grouped matmul is a Mosaic call, and a Mosaic
+        # call cannot fuse the scan's slice of a stacked leaf: sliced by the
+        # scan, every expert's weights are copied once a layer (1.3 ms of a
+        # 3.6 ms layer at the 2,048 bucket on the v5e, PR 29). So where the
+        # layer routes, the experts stay whole outside the scan and a layer
+        # reads its own in place; where it does not, the scan is the one it
+        # always was.
+        xs = ({name: leaf for name, leaf in blocks.items()
+               if name != "experts"}, jnp.arange(blocks["router"].shape[0]))
+
+    def step(h, xs):
+        layer_params, layer = xs
+        if layer is not None:
+            layer_params = dict(layer_params, experts=blocks["experts"])
+        return layer_fn(layer_params, h, layer)
+
+    return jax.lax.scan(step, h, xs)
+
+
+def _train_layer(n_heads: int, attn_core, moe_fn, spec: BlockSpec):
+    """``layer_fn`` of the training form: the builder's core with nothing
+    kept, its expert layer, ``flat`` handed back."""
+    def layer_fn(layer_params, h, layer):
+        h, _, flat = _block(layer_params, h, n_heads, spec,
+                            lambda q, k, v: (attn_core(q, k, v), None), moe_fn)
+        return h, flat
+
+    return layer_fn
 
 
 def _lm_hidden(params: dict, tokens: Array, n_heads: int, attn_core,
-               moe_fn) -> tuple:
+               moe_fn, spec: BlockSpec = FLAGSHIP_SPEC) -> tuple:
     """``lm_forward`` up to the decoder: (h (B, T, d), moe_in)."""
     with jax.named_scope("lm_embed"):
         h = params["embed"][tokens]  # (B, T, d)
-
-    def step(h, layer_params):
-        h, flat = _decoder_block(layer_params, h, n_heads, attn_core, moe_fn)
-        return h, flat
-
-    return jax.lax.scan(step, h, params["blocks"])
+    return _stacked_layers(params["blocks"], h,
+                           _train_layer(n_heads, attn_core, moe_fn, spec))
 
 
 def lm_forward(params: dict, tokens: Array, n_heads: int, attn_core,
@@ -457,11 +563,9 @@ def lm_forward(params: dict, tokens: Array, n_heads: int, attn_core,
     ``attn_core(q, k, v) -> out`` and ``moe_fn(router_w, experts, flat)``
     supply the parallel strategy; every projection/norm is strategy-agnostic
     and sharded by GSPMD from the argument shardings. The layer stack runs
-    as ONE ``lax.scan`` over the stacked per-layer params — compile time
-    stays O(1) in depth and the per-layer collectives (ring ppermute, MoE
-    psum) trace once."""
+    as one scan over the stacked per-layer params (``_stacked_layers``)."""
     h, moe_ins = _lm_hidden(params, tokens, n_heads, attn_core, moe_fn)
-    return h @ params["dec_w"] + params["dec_b"], moe_ins
+    return _lm_head(params, h, FLAGSHIP_SPEC), moe_ins
 
 
 def _lm_loss_terms(params: dict, h: Array, moe_ins: Array, targets: Array,
@@ -469,8 +573,7 @@ def _lm_loss_terms(params: dict, h: Array, moe_ins: Array, targets: Array,
     """The decoder matmul to V, the NLL and the load-balance aux term, all
     under ``lm_loss``: (loss, task, aux)."""
     with jax.named_scope("lm_loss"):
-        logits = h @ params["dec_w"] + params["dec_b"]
-        logp = jax.nn.log_softmax(logits, axis=-1)
+        logp = jax.nn.log_softmax(_lm_head(params, h, FLAGSHIP_SPEC), axis=-1)
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         task = jnp.mean(nll)
         aux = jnp.mean(jax.vmap(load_balance_loss)(
@@ -1086,12 +1189,9 @@ def make_pp_stages(params: dict, n_heads: int, n_stages: int = 2,
     moe = moe_fn or (lambda rw, ex, x: dense_moe(rw, ex, x, top_k))
 
     def stage_fn(p, x):
-        def step(h, layer_params):
-            h, _ = _decoder_block(layer_params, h, n_heads, core, moe)
-            return h, None
-
-        h, _ = jax.lax.scan(step, x, p)
-        return h
+        layer = _train_layer(n_heads, core, moe, FLAGSHIP_SPEC)
+        return _stacked_layers(
+            p, x, lambda *args: (layer(*args)[0], None))[0]
 
     return per_stage, stage_fn
 
@@ -1139,10 +1239,8 @@ def make_pp_loss(stage_fn, mesh: Mesh, pipe_axis: str,
 # ---------------------------------------------------------------- serving ----
 #
 # ISSUE 10: the decode-mode forward behind deeplearning4j_tpu/serve/. Two
-# entry points share the training model's exact per-position math
-# (_layernorm / projections / dense_moe op-for-op, so prefill logits are
-# BIT-identical to lm_forward's and greedy decode parity against the
-# recompute-per-token oracle is pinned in tests/test_serve.py):
+# entry points run the training model's block (``_block``) with another
+# attention and the one-chip expert layer handed in:
 #
 # - ``lm_prefill``: the full-prompt pass through the attn_impl seam (dense
 #   or blockwise flash — the long-prompt path), additionally returning every
@@ -1179,68 +1277,6 @@ def init_kv_cache(n_layers: int, n_slots: int, n_kv_heads: int,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-# ---- the block's parts, each reading the spec at trace time; under the
-# default spec each emits the operations the flagship's block always had ----
-
-def _rmsnorm(x: Array, g: Array, eps: float) -> Array:
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (y * g).astype(x.dtype)
-
-
-def _norm(p: dict, name: str, x: Array, spec: BlockSpec) -> Array:
-    if spec.norm == "layernorm":
-        return _layernorm(x, p[name + "_g"], p[name + "_b"])
-    return _rmsnorm(x, p[name + "_g"], spec.norm_eps)
-
-
-def _rope(x: Array, positions: Array, theta: float) -> Array:
-    """Rotary positions, rotate-half over the head size, no scaling. x:
-    (B, H, T, Dh); positions: (T,) or one row a batch row, (B, T)."""
-    hd = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = positions.astype(jnp.float32)[..., None] * inv
-    ang = jnp.concatenate([ang, ang], axis=-1)            # (..., T, Dh)
-    if positions.ndim == 2:
-        ang = ang[:, None]                                # (B, 1, T, Dh)
-    x32 = x.astype(jnp.float32)
-    rot = jnp.concatenate([-x32[..., hd // 2:], x32[..., :hd // 2]], -1)
-    return (x32 * jnp.cos(ang) + rot * jnp.sin(ang)).astype(x.dtype)
-
-
-def _qkv(p: dict, hn: Array, n_heads: int, spec: BlockSpec,
-         positions: Optional[Array]) -> tuple:
-    """The three projections of the normed input as heads: q (B, H, T, Dh),
-    k and v (B, H_kv, T, Dh), with the spec's q/k norm and, at
-    ``positions`` (read under a rotary spec alone), its rotary term."""
-    n_kv = spec.kv_heads(n_heads)
-    q = _split_heads(hn @ p["wq"], n_heads)
-    k = _split_heads(hn @ p["wk"], n_kv)
-    v = _split_heads(hn @ p["wv"], n_kv)
-    if spec.qk_norm:
-        q = _rmsnorm(q, p["q_g"], spec.norm_eps)
-        k = _rmsnorm(k, p["k_g"], spec.norm_eps)
-    if spec.rope_theta is not None:
-        q = _rope(q, positions, spec.rope_theta)
-        k = _rope(k, positions, spec.rope_theta)
-    return q, k, v
-
-
-def _scores(q: Array, k: Array, spec: BlockSpec) -> Array:
-    kw = {"preferred_element_type": jnp.float32} if spec.accum_f32 else {}
-    return jnp.einsum("shqd,shkd->shqk", q, k, **kw) / jnp.sqrt(
-        q.shape[-1] * 1.0)
-
-
-def _lm_head(params: dict, h: Array, spec: BlockSpec) -> Array:
-    if spec.final_norm:
-        h = _norm(params, "lnf", h, spec)
-    logits = (jnp.matmul(h, params["dec_w"],
-                         preferred_element_type=jnp.float32)
-              if spec.accum_f32 else h @ params["dec_w"])
-    return logits + params["dec_b"] if spec.bias else logits
-
-
 def _prefill_core(spec: BlockSpec, attn_impl: Optional[str]):
     """``attn_core(q, k, v)`` of the prompt pass: the selection seam's
     causal core, or under the block mask (i sees j iff j // B <= i // B)
@@ -1260,72 +1296,27 @@ def _prefill_core(spec: BlockSpec, attn_impl: Optional[str]):
     return core
 
 
-def _decoder_block_kv(layer_params: dict, h: Array, n_heads: int, attn_core,
-                      top_k: int, spec: BlockSpec = FLAGSHIP_SPEC,
-                      layer: Optional[Array] = None) -> tuple:
-    """``_decoder_block`` with the one-chip MoE FFN, additionally returning
-    the layer's projected K/V (B, H_kv, T, Dh) for cache seeding. The op
-    sequence is IDENTICAL to _attn_block + _decoder_block's dense path —
-    prefill logits must stay bit-identical to lm_forward's (pinned in
-    tests/test_serve.py). With ``layer``, ``layer_params["experts"]`` is
-    every layer's, stacked (``_prefill_hidden``)."""
-    with jax.named_scope("lm_attn"):
-        hn = _norm(layer_params, "ln", h, spec)
-        q, k, v = _qkv(layer_params, hn, n_heads, spec,
-                       jnp.arange(h.shape[1])
-                       if spec.rope_theta is not None else None)
-        # .astype keeps the scan carry dtype stable under serve_dtype="bf16"
-        # (the dense core's f32 score scale widens its output); identity at
-        # f32
-        h = h + (_merge_heads(attn_core(q, k, v))
-                 @ layer_params["wo"]).astype(h.dtype)
-    return _dense_moe_ffn(layer_params, h, top_k, spec, layer), k, v
-
-
-def _dense_moe_ffn(layer_params: dict, h: Array, top_k: int,
-                   spec: BlockSpec = FLAGSHIP_SPEC,
-                   layer: Optional[Array] = None) -> Array:
-    """The serving blocks' FFN half under ``lm_moe``: second layer norm,
-    the one-chip expert layer (``moe_ffn``: routed where rows an expert
-    are many, every expert on every token where they are few), the residual
-    in the carry's dtype."""
-    with jax.named_scope("lm_moe"):
-        h2 = _norm(layer_params, "ln2", h, spec)
-        flat = h2.reshape(-1, h2.shape[-1])
-        moe_out = moe_ffn(layer_params["router"], layer_params["experts"],
-                          flat, top_k, spec, layer)
-        return h + moe_out.reshape(h.shape).astype(h.dtype)
-
-
 def _prefill_hidden(params: dict, tokens: Array, n_heads: int, top_k: int,
                     attn_impl: Optional[str], spec: BlockSpec) -> tuple:
-    """The prompt pass up to the head: (h (B, T_pad, d), ks, vs)."""
+    """The prompt pass up to the head: (h (B, T_pad, d), (ks, vs)), the
+    block with the one-chip expert layer (``moe_ffn``), every layer's
+    projected K/V (B, H_kv, T_pad, Dh) kept to seed the cache."""
     core = _prefill_core(spec, attn_impl)
     with jax.named_scope("lm_embed"):
         h = params["embed"][tokens]
 
-    # The routed form's grouped matmul is a Mosaic call, and a Mosaic call
-    # cannot fuse the scan's slice of a stacked leaf: sliced by the scan,
-    # every expert's weights are copied once a layer (1.3 ms of a 3.6 ms
-    # layer at the 2,048 bucket on the v5e, PR 29). So where the layer
-    # routes, the experts stay whole outside the scan and a layer reads its
-    # own in place; where it does not, the scan is the one it always was.
+    def layer_fn(layer_params, h, layer):
+        h, kv, _ = _block(
+            layer_params, h, n_heads, spec,
+            lambda q, k, v: (core(q, k, v), (k, v)),
+            partial(moe_ffn, top_k=top_k, spec=spec, layer=layer))
+        return h, kv
+
     blocks = params["blocks"]
-    xs = (blocks, None)
-    if _routes(tokens.size, top_k, blocks["router"].shape[-1]):
-        xs = ({name: leaf for name, leaf in blocks.items()
-               if name != "experts"}, jnp.arange(lm_n_layers(params)))
-
-    def step(h, xs):
-        layer_params, layer = xs
-        if layer is not None:
-            layer_params = dict(layer_params, experts=blocks["experts"])
-        h, k, v = _decoder_block_kv(layer_params, h, n_heads, core, top_k,
-                                    spec, layer)
-        return h, (k, v)
-
-    h, (ks, vs) = jax.lax.scan(step, h, xs)
-    return h, ks, vs
+    return _stacked_layers(
+        blocks, h, layer_fn,
+        experts_whole=_routes(tokens.size, top_k,
+                              blocks["router"].shape[-1]))
 
 
 def lm_prefill(params: dict, tokens: Array, n_heads: int, top_k: int = 2,
@@ -1339,8 +1330,8 @@ def lm_prefill(params: dict, tokens: Array, n_heads: int, top_k: int = 2,
     positions >= the real length produce garbage K/V that decode's position
     mask never reads. Under the block mask the same holds block for block:
     no block sees a later one."""
-    h, ks, vs = _prefill_hidden(params, tokens, n_heads, top_k, attn_impl,
-                                spec)
+    h, (ks, vs) = _prefill_hidden(params, tokens, n_heads, top_k, attn_impl,
+                                  spec)
     return _lm_head(params, h, spec), ks, vs
 
 
@@ -1371,20 +1362,20 @@ def _write_cache_rows(ck: Array, cv: Array, k_new: Array, v_new: Array,
     return jax.lax.fori_loop(0, positions.shape[0], one_slot, (ck, cv))
 
 
-def _decode_block(layer_params: dict, h: Array, ck: Array, cv: Array,
-                  layer: Array, slot0: Array, positions: Array, n_heads: int,
-                  top_k: int, spec: BlockSpec = FLAGSHIP_SPEC) -> tuple:
-    """One decoder block for W new tokens per slot. h: (S, W, d), the rows
-    of slots ``slot0``..``slot0 + S - 1`` (every slot from 0 in decode and
-    verify, the one slot of a prefill chunk); ck/cv: the WHOLE cache
-    leaves (L, n_slots, H_kv, T_max, Dh), of which this block touches those
-    slots' pages of layer ``layer`` alone. Writes this step's K/V at
-    ``positions``..``positions + W - 1`` FIRST, in place
+def _attend_cache(q: Array, k_new: Array, v_new: Array, ck: Array, cv: Array,
+                  layer: Array, slot0: Array, positions: Array, rows,
+                  spec: BlockSpec) -> tuple:
+    """``attend`` of the cached step, W new tokens a slot: q (S, H, W, Dh)
+    and the new rows (S, H_kv, W, Dh) of slots ``slot0``..``slot0 + S - 1``
+    (every slot from 0 in decode and verify, the one slot of a prefill
+    chunk); ck/cv the WHOLE cache leaves (L, n_slots, H_kv, T_max, Dh), of
+    which this touches those slots' pages of layer ``layer`` alone. Writes
+    the new K/V at ``positions``..``positions + W - 1`` FIRST, in place
     (``_write_cache_rows``), then slices the pages back out and attends
-    with the per-query mask ``index <= position + offset`` — so every
-    freshly written position is visible to the queries at or after it and
-    stale cache beyond them never is. Under the block mask a query sees up
-    to the last row of its own block of B: the block-diffusion step (W = B,
+    with the per-query mask ``index <= rows()`` — so every freshly written
+    position is visible to the queries at or after it and stale cache
+    beyond them never is. Under the block mask a query sees up to the last
+    row of its own block of B: the block-diffusion step (W = B,
     ``positions`` a block's first row) attends to the whole block it has
     just written, and what it wrote stays only until the same block's next
     forward overwrites it. The attention math mirrors
@@ -1393,44 +1384,33 @@ def _decode_block(layer_params: dict, h: Array, ck: Array, cv: Array,
     padded reduction is bitwise the oracle's unpadded one. W=1 is the
     decode hot path; W=k+1 is the speculative verify step (ISSUE 16) —
     the same math, so verify logits at offset i are exactly what i
-    sequential decode steps over the same tokens would produce."""
-    with jax.named_scope("lm_attn"):
-        hn = _norm(layer_params, "ln", h, spec)
-        # each query's row, made where it is used: the flagship's program
-        # keeps the order of operations it was compiled with
-        rows = lambda: (positions[:, None]  # noqa: E731
-                        + jnp.arange(h.shape[1])[None, :])        # (S, W)
-        # q (S, H, W, Dh); the new rows (S, H_kv, W, Dh)
-        q, k_new, v_new = _qkv(layer_params, hn, n_heads, spec,
-                               rows() if spec.rope_theta is not None
-                               else None)
-        with jax.named_scope("lm_cache_write"):
-            ck, cv = _write_cache_rows(ck, cv, k_new, v_new, layer, slot0,
-                                       positions)
-        pages = lambda c: jax.lax.dynamic_slice(  # noqa: E731
-            c, (layer, slot0, 0, 0, 0), (1, h.shape[0]) + c.shape[2:])[0]
-        ck_l, cv_l = pages(ck), pages(cv)              # (S, H_kv, T_max, Dh)
-        group = n_heads // ck_l.shape[1]
-        if group > 1:
-            # the query heads of one K/V head as further query rows of it
-            q = q.reshape(q.shape[0], ck_l.shape[1], -1, q.shape[-1])
-        scores = _scores(q, ck_l, spec)                # (S, H_kv, G*W, T_max)
-        seen = pos_q = rows()
-        if spec.attn_mask == "block":
-            seen = pos_q // spec.block_length * spec.block_length \
-                + (spec.block_length - 1)
-        if group > 1:
-            seen = jnp.tile(seen, (1, group))
-        mask = (jnp.arange(ck_l.shape[2])[None, None, None, :]
-                <= seen[:, None, :, None])
-        scores = jnp.where(mask, scores, -1e30)
-        o = jnp.einsum("shqk,shkd->shqd", jax.nn.softmax(scores, -1), cv_l)
-        if group > 1:
-            o = o.reshape(o.shape[0], n_heads, -1, o.shape[-1])
-        # f32 score math, carry-dtype residual (identity at f32:
-        # parity-safe)
-        h = h + (_merge_heads(o) @ layer_params["wo"]).astype(h.dtype)
-    return _dense_moe_ffn(layer_params, h, top_k, spec), ck, cv
+    sequential decode steps over the same tokens would produce. Returns
+    (out (S, H, W, Dh), (ck, cv))."""
+    n_slots, n_heads = q.shape[:2]
+    with jax.named_scope("lm_cache_write"):
+        ck, cv = _write_cache_rows(ck, cv, k_new, v_new, layer, slot0,
+                                   positions)
+    pages = lambda c: jax.lax.dynamic_slice(  # noqa: E731
+        c, (layer, slot0, 0, 0, 0), (1, n_slots) + c.shape[2:])[0]
+    ck_l, cv_l = pages(ck), pages(cv)                  # (S, H_kv, T_max, Dh)
+    group = n_heads // ck_l.shape[1]
+    if group > 1:
+        # the query heads of one K/V head as further query rows of it
+        q = q.reshape(n_slots, ck_l.shape[1], -1, q.shape[-1])
+    scores = _scores(q, ck_l, spec)                    # (S, H_kv, G*W, T_max)
+    seen = pos_q = rows()
+    if spec.attn_mask == "block":
+        seen = pos_q // spec.block_length * spec.block_length \
+            + (spec.block_length - 1)
+    if group > 1:
+        seen = jnp.tile(seen, (1, group))
+    mask = (jnp.arange(ck_l.shape[2])[None, None, None, :]
+            <= seen[:, None, :, None])
+    scores = jnp.where(mask, scores, -1e30)
+    o = jnp.einsum("shqk,shkd->shqd", jax.nn.softmax(scores, -1), cv_l)
+    if group > 1:
+        o = o.reshape(n_slots, n_heads, -1, o.shape[-1])
+    return o, (ck, cv)
 
 
 def _cached_layers(params: dict, cache: dict, h: Array, positions: Array,
@@ -1439,18 +1419,24 @@ def _cached_layers(params: dict, cache: dict, h: Array, positions: Array,
     """The one layer loop of decode, verify, chunked prefill and the
     block-diffusion step: h (S, W, d), the rows of slots
     ``slot0``..``slot0 + S - 1``, through every block, each attending over
-    the cache. The loop scans the stacked block params with the layer's
-    index and CARRIES ``(h, cache k, cache v)``, both leaves whole: a
-    scanned cache would be sliced a layer at a time on the way in and
-    stacked into a second cache on the way out, six whole-cache copies a
-    step for a few rows stored. Returns (cache, h)."""
+    the cache (``_attend_cache``). The loop scans the stacked block params
+    with the layer's index and CARRIES ``(h, cache k, cache v)``, both
+    leaves whole: a scanned cache would be sliced a layer at a time on the
+    way in and stacked into a second cache on the way out, six whole-cache
+    copies a step for a few rows stored. Returns (cache, h)."""
     slot0 = jnp.asarray(slot0, jnp.int32)
+    rows = lambda: (positions[:, None]  # noqa: E731
+                    + jnp.arange(h.shape[1])[None, :])            # (S, W)
 
     def step(carry, xs):
         h, ck, cv = carry
         layer_params, layer = xs
-        return _decode_block(layer_params, h, ck, cv, layer, slot0,
-                             positions, n_heads, top_k, spec), None
+        h, (ck, cv), _ = _block(
+            layer_params, h, n_heads, spec,
+            partial(_attend_cache, ck=ck, cv=cv, layer=layer, slot0=slot0,
+                    positions=positions, rows=rows, spec=spec),
+            partial(moe_ffn, top_k=top_k, spec=spec), rows)
+        return (h, ck, cv), None
 
     layers = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
     (h, ck, cv), _ = jax.lax.scan(
@@ -1530,8 +1516,8 @@ def make_prefill_step(n_heads: int, top_k: int = 2,
     def prefill(params, cache, tokens, last_idx, slot, temp, key, step_idx):
         params = transform(params)
         if stores_only:
-            _, ks, vs = _prefill_hidden(params, tokens, n_heads, top_k,
-                                        attn_impl, spec)
+            _, (ks, vs) = _prefill_hidden(params, tokens, n_heads, top_k,
+                                          attn_impl, spec)
         else:
             logits, ks, vs = lm_prefill(params, tokens, n_heads, top_k,
                                         attn_impl, spec)
@@ -1557,7 +1543,7 @@ def lm_verify_step(params: dict, cache: dict, tokens: Array,
     (S, W) int32 land at ``positions``..``positions + W - 1`` in the cache
     and per-position next-token logits (S, W, V) come back with the
     updated cache. Column 0 is the slot's pending token, columns 1..W-1
-    the draft's proposals; because ``_decode_block`` computes offset i's
+    the draft's proposals; because ``_attend_cache`` computes offset i's
     query against exactly the cache a sequential decode at position
     ``positions + i`` would see, logits[:, i] are token-identical to i
     single-token decode steps over the same inputs — ONE dispatch verifies
@@ -1650,7 +1636,7 @@ def make_block_step(n_heads: int, top_k: int, spec: BlockSpec,
     masked inputs made them, and unmasks ``B // D`` positions
     (``unmask_most_confident``); a commit forward (none masked) writes the
     block's final rows and changes nothing else. Both write first and read
-    back, as ``_decode_block`` does, so no second cache holds the
+    back, as ``_attend_cache`` does, so no second cache holds the
     provisional rows: the same block's next forward overwrites them."""
     transform = params_transform or (lambda p: p)
     n = max(1, spec.block_length // spec.denoising_steps)
@@ -1679,7 +1665,7 @@ def make_chunk_prefill_step(n_heads: int, top_k: int = 2,
     ``start``..``start + W - 1``, each query attending the slot's cache
     at ``index <= start + offset`` (so a chunk sees every earlier chunk
     AND any prefix-cache-seeded pages — the same write-then-mask math as
-    ``_decode_block``, token-identical to the one-shot ``lm_prefill``
+    ``_attend_cache``, token-identical to the one-shot ``lm_prefill``
     path). ``tok`` samples the logits at in-chunk index ``last_idx``; the
     engine uses it only from the final chunk (last_idx = prompt_len - 1 -
     start) and ignores it from earlier ones. Compiles are keyed by W

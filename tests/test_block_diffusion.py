@@ -109,6 +109,49 @@ def test_prefill_and_cached_step_match_the_reference(part):
     np.testing.assert_allclose(got[1], want[16:], atol=2e-5, rtol=0)
 
 
+@pytest.mark.parametrize("part", ["flagship"] + list(PARTS)
+                         + ["all_together"])
+def test_the_three_callers_of_the_one_block_agree(part):
+    """Hidden states of the same tokens through the block's three callers:
+    the training form (``_lm_hidden``, a dense core under the spec's mask,
+    ``dense_moe`` by name, as the loss builders hand them in), the prompt
+    pass, and the cached step fed the tokens in chunks of four. The first
+    two run the same operations, and under the flagship's spec agree to the
+    bit (the compiler may fuse another spec's differently, by a last
+    place); the cached step reduces over the padded cache."""
+    fields = {} if part == "flagship" else \
+        (ALL if part == "all_together" else PARTS[part])[0]
+    spec = lm.BlockSpec(**fields)
+    params = program_params(spec)
+    rows, length, width = 2, 24, 4
+    tokens = jnp.asarray(np.random.default_rng(2).integers(
+        0, V, size=(rows, length)), jnp.int32)
+
+    trained, moe_ins = lm._lm_hidden(
+        params, tokens, H, lm._prefill_core(spec, "dense"),
+        lambda rw, ex, x: lm.dense_moe(rw, ex, x, K, spec), spec)
+    prefilled, (ks, vs) = lm._prefill_hidden(params, tokens, H, K, "dense",
+                                             spec)
+    assert moe_ins.shape == (L, rows * length, D)
+    np.testing.assert_allclose(trained, prefilled, rtol=0,
+                               atol=0 if part == "flagship" else 1e-5)
+
+    cache = lm.init_kv_cache(L, rows, spec.kv_heads(H), spec.head_size(D, H),
+                             MAXLEN)
+    chunks = []
+    for start in range(0, length, width):
+        cache, h = lm._cached_layers(
+            params, cache, params["embed"][tokens[:, start:start + width]],
+            jnp.full((rows,), start, jnp.int32), H, K, spec=spec)
+        chunks.append(h)
+    np.testing.assert_allclose(jnp.concatenate(chunks, axis=1), prefilled,
+                               atol=1e-5, rtol=0)
+    np.testing.assert_allclose(cache["k"][:, :, :, :length], ks, atol=1e-5,
+                               rtol=0)
+    np.testing.assert_allclose(cache["v"][:, :, :, :length], vs, atol=1e-5,
+                               rtol=0)
+
+
 def test_block_step_with_masked_positions_matches_the_reference(sdar):
     params, weights = sdar
     tokens = np.random.default_rng(2).integers(0, V, size=12)

@@ -26,7 +26,7 @@ PROGRAMS = sorted(WIDTH)
 def _oracle_block(layer_params, h, ck, cv, positions):
     """The decode block over ONE layer's slab ck/cv (S, H, T_max, Dh): the
     program as it stood before ISSUE 26."""
-    hn = lm._layernorm(h, layer_params["ln_g"], layer_params["ln_b"])
+    hn = lm._norm(layer_params, "ln", h, lm.FLAGSHIP_SPEC)
     q = lm._split_heads(hn @ layer_params["wq"], H)
     k_new = lm._split_heads(hn @ layer_params["wk"], H)
     v_new = lm._split_heads(hn @ layer_params["wv"], H)
@@ -43,7 +43,11 @@ def _oracle_block(layer_params, h, ck, cv, positions):
     scores = jnp.where(mask, scores, -1e30)
     o = jnp.einsum("shqk,shkd->shqd", jax.nn.softmax(scores, -1), cv)
     h = h + (lm._merge_heads(o) @ layer_params["wo"]).astype(h.dtype)
-    return lm._dense_moe_ffn(layer_params, h, TOP_K), ck, cv
+    h2 = lm._norm(layer_params, "ln2", h, lm.FLAGSHIP_SPEC)
+    flat = h2.reshape(-1, h2.shape[-1])
+    out = lm.moe_ffn(layer_params["router"], layer_params["experts"], flat,
+                     TOP_K)
+    return h + out.reshape(h.shape).astype(h.dtype), ck, cv
 
 
 def _oracle_layers(params, cache, h, positions, slot=None):
