@@ -82,6 +82,12 @@ LM_SCOPES = ("lm_embed", "lm_attn", "lm_cache_write", "lm_moe", "lm_loss",
 # has no scope of its own: it is 0.5% of a block step on the v5e (PR 28).
 LM_UNMASK_SCOPE = "lm_unmask"
 LM_SPEC_SCOPES = (LM_UNMASK_SCOPE,)
+# What only a spec with a layer pattern names: the state-space mixer (with
+# ``ssm_scan`` and ``ssm_step`` of models/ssm.py inside it) and the dense
+# gated FFN. Apart from LM_SCOPES for the same reason as LM_SPEC_SCOPES.
+LM_SSM_SCOPE = "lm_ssm"
+LM_MLP_SCOPE = "lm_mlp"
+LM_HYBRID_SCOPES = (LM_SSM_SCOPE, LM_MLP_SCOPE)
 
 
 class BlockSpec(NamedTuple):
@@ -99,7 +105,8 @@ class BlockSpec(NamedTuple):
     qk_norm: bool = False       # RMSNorm over each head of q and k, gains shared by heads
     n_kv_heads: Optional[int] = None    # K/V heads (the cache's); None: one a query head
     head_dim: Optional[int] = None      # None: d_model // n_heads
-    ffn: str = "relu"           # experts: "relu" (w1, w2) | "swiglu" (wg, wu, wd)
+    ffn: str = "relu"           # experts: "relu" (w1, w2) | "swiglu" (wg, wu, wd);
+    #                             "dense_swiglu": one gated FFN (w_in, w_out), no router
     norm_topk_prob: bool = True  # gates renormalised over the chosen k
     bias: bool = True           # expert and output biases
     accum_f32: bool = False     # router logits, scores and logits leave their matmul in float32
@@ -108,6 +115,21 @@ class BlockSpec(NamedTuple):
     generation: str = "autoregressive"  # | "block_diffusion" (serve/engine.py)
     denoising_steps: int = 1    # D: B // D positions unmasked a denoising forward
     mask_token_id: Optional[int] = None
+    # a hybrid of layer kinds (ISSUE 37): one period of "attention" and
+    # "mamba" (models/ssm.py) layers, repeated through the depth; None: every
+    # layer the attention block above
+    layer_pattern: Optional[tuple] = None
+    ssm_heads: int = 0          # H of the mixer, P each (d_inner = H * P)
+    ssm_head_dim: int = 0
+    ssm_state: int = 0          # N
+    ssm_groups: int = 1         # G groups of B and C
+    ssm_conv: int = 4           # K of the causal depthwise conv
+    ssm_chunk: int = 256        # rows a chunk of the prompt pass's scan
+    embedding_multiplier: float = 1.0   # on the embedding's rows
+    residual_multiplier: float = 1.0    # on both branches before their residual
+    attention_multiplier: Optional[float] = None  # softmax scale; None: 1/sqrt(Dh)
+    logits_scaling: float = 1.0  # logits divided by it
+    tied_head: bool = False     # the head is the embedding, transposed
 
     def kv_heads(self, n_heads: int) -> int:
         return self.n_kv_heads or n_heads
@@ -187,6 +209,9 @@ def init_lm_params(key: Array, vocab: int, d_model: int, n_heads: int,
         raise ValueError(f"rotary positions need an even head size, got {hd}")
     if n_layers < 1:
         raise ValueError(f"n_layers must be >= 1, got {n_layers}")
+    if spec.layer_pattern is not None:
+        return _init_hybrid(key, vocab, d_model, n_heads, d_ff, n_layers,
+                            spec, init_scale)
     ks = jax.random.split(key, 3 + n_layers)
     n = jax.random.normal
     s_d = 1.0 / (d_model ** 0.5) if init_scale is None else init_scale
@@ -208,8 +233,79 @@ def init_lm_params(key: Array, vocab: int, d_model: int, n_heads: int,
     return out
 
 
+def _init_hybrid_layer(key: Array, kind: str, d_model: int, n_heads: int,
+                       d_ff: int, spec: BlockSpec) -> dict:
+    """One layer of a pattern: the mixer of its kind (attention's four
+    matrices, or models/ssm.py's), the dense gated FFN, two RMSNorm gains.
+    Every matrix N(0, 1/fan_in)."""
+    from deeplearning4j_tpu.models import ssm
+
+    ks = jax.random.split(key, 3)
+    n = jax.random.normal
+    hd = spec.head_size(d_model, n_heads)
+    d_q, d_kv = n_heads * hd, spec.kv_heads(n_heads) * hd
+    p = {"ln_g": jnp.ones((d_model,))}
+    if kind == "mamba":
+        p["mixer"] = ssm.init_mixer(ks[0], d_model, spec)
+    else:
+        kq, kk, kv, ko = jax.random.split(ks[0], 4)
+        p["wq"] = n(kq, (d_model, d_q)) / (d_model ** 0.5)
+        p["wk"] = n(kk, (d_model, d_kv)) / (d_model ** 0.5)
+        p["wv"] = n(kv, (d_model, d_kv)) / (d_model ** 0.5)
+        p["wo"] = n(ko, (d_q, d_model)) / (d_q ** 0.5)
+    p["ln2_g"] = jnp.ones((d_model,))
+    p["mlp"] = {"w_in": n(ks[1], (d_model, 2 * d_ff)) / (d_model ** 0.5),
+                "w_out": n(ks[2], (d_ff, d_model)) / (d_ff ** 0.5)}
+    return p
+
+
+def _init_hybrid(key: Array, vocab: int, d_model: int, n_heads: int,
+                 d_ff: int, n_layers: int, spec: BlockSpec,
+                 init_scale: Optional[float]) -> dict:
+    """``init_lm_params`` under a layer pattern: layer i drawn from the
+    i-th layer key as its kind says, ``params["blocks"]`` one stack a kind
+    (``{"attention": (L_a, ...), "mamba": (L_m, ...)}``, layers in depth
+    order within each), the embedding at ``init_scale`` (None: 0.1), a final
+    RMSNorm, and an untied head only where the spec has one."""
+    pattern = spec.layer_pattern
+    if spec.norm != "rmsnorm" or spec.ffn != "dense_swiglu" or \
+            not spec.final_norm:
+        raise ValueError("a layer pattern takes norm='rmsnorm', "
+                         "ffn='dense_swiglu' and final_norm=True")
+    if n_layers % len(pattern) or not set(pattern) <= {"attention", "mamba"}:
+        raise ValueError(f"{n_layers} layers are not whole periods of "
+                         f"{pattern} (kinds: attention, mamba)")
+    ks = jax.random.split(key, 3 + n_layers)
+    layers = {kind: [] for kind in pattern}
+    for i in range(n_layers):
+        kind = pattern[i % len(pattern)]
+        layers[kind].append(_init_hybrid_layer(ks[3 + i], kind, d_model,
+                                               n_heads, d_ff, spec))
+    out = {"embed": jax.random.normal(ks[0], (vocab, d_model))
+           * (0.1 if init_scale is None else init_scale),
+           "blocks": {kind: jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                                   *stack)
+                      for kind, stack in layers.items()},
+           "lnf_g": jnp.ones((d_model,))}
+    if not spec.tied_head:
+        out["dec_w"] = jax.random.normal(ks[1], (d_model, vocab)) \
+            / (d_model ** 0.5)
+    return out
+
+
+def _pattern_counts(spec: BlockSpec, n_layers: int) -> dict:
+    """{kind: its layers in ``n_layers``} under the spec's pattern."""
+    pattern = spec.layer_pattern
+    return {kind: n_layers // len(pattern) * pattern.count(kind)
+            for kind in pattern}
+
+
 def lm_n_layers(params: dict) -> int:
-    return jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
+    blocks = params["blocks"]
+    if "attention" in blocks or "mamba" in blocks:  # a layer pattern's
+        return sum(jax.tree_util.tree_leaves(b)[0].shape[0]
+                   for b in blocks.values())
+    return jax.tree_util.tree_leaves(blocks)[0].shape[0]
 
 
 def expert_fn(p: dict, t: Array) -> Array:
@@ -491,21 +587,43 @@ def _qkv(p: dict, hn: Array, n_heads: int, spec: BlockSpec,
 
 def _scores(q: Array, k: Array, spec: BlockSpec) -> Array:
     kw = {"preferred_element_type": jnp.float32} if spec.accum_f32 else {}
-    return jnp.einsum("shqd,shkd->shqk", q, k, **kw) / jnp.sqrt(
-        q.shape[-1] * 1.0)
+    scores = jnp.einsum("shqd,shkd->shqk", q, k, **kw)
+    if spec.attention_multiplier is not None:
+        return scores * spec.attention_multiplier
+    return scores / jnp.sqrt(q.shape[-1] * 1.0)
 
 
 def _lm_head(params: dict, h: Array, spec: BlockSpec) -> Array:
     if spec.final_norm:
         h = _norm(params, "lnf", h, spec)
-    logits = (jnp.matmul(h, params["dec_w"],
-                         preferred_element_type=jnp.float32)
-              if spec.accum_f32 else h @ params["dec_w"])
+    w = params["embed"].T if spec.tied_head else params["dec_w"]
+    logits = (jnp.matmul(h, w, preferred_element_type=jnp.float32)
+              if spec.accum_f32 else h @ w)
+    if spec.logits_scaling != 1.0:
+        logits = logits / spec.logits_scaling
     return logits + params["dec_b"] if spec.bias else logits
 
 
+def _embed(params: dict, tokens: Array, spec: BlockSpec) -> Array:
+    """The embedding's rows of ``tokens`` under ``lm_embed``, times the
+    spec's multiplier (the layer-pattern paths'; the others index the
+    embedding where they are)."""
+    with jax.named_scope("lm_embed"):
+        h = params["embed"][tokens]
+        if spec.embedding_multiplier != 1.0:
+            h = h * spec.embedding_multiplier
+        return h
+
+
+def _residual(spec: BlockSpec):
+    """What a branch's output is multiplied by before its residual: nothing
+    at the default 1, so the default emits the operations it always did."""
+    r = spec.residual_multiplier
+    return (lambda y: y) if r == 1.0 else (lambda y: y * r)
+
+
 def _block(p: dict, h: Array, n_heads: int, spec: BlockSpec, attend, ffn,
-           rows=None) -> tuple:
+           rows=None, kind: str = "attention") -> tuple:
     """The decoder block, the one every program runs, on h (B, T, d) →
     (h, kept, flat). What differs between training, the prompt pass and the
     cached step is handed in: ``attend(q, k, v) -> (out, kept)``, the
@@ -516,17 +634,35 @@ def _block(p: dict, h: Array, n_heads: int, spec: BlockSpec, attend, ffn,
     are not 0..T-1 (read under a rotary spec alone, and there, where the
     projections are made: a program keeps the order it was compiled in).
     ``flat`` is the (B·T, d) pre-expert activations, the load-balance aux
-    term's input."""
-    with jax.named_scope("lm_attn"):
-        hn = _norm(p, "ln", h, spec)
-        positions = None
-        if spec.rope_theta is not None:
-            positions = rows() if rows else jnp.arange(h.shape[1])
-        q, k, v = _qkv(p, hn, n_heads, spec, positions)
-        out, kept = attend(q, k, v)
-        # .astype keeps the carry's dtype stable under serve_dtype="bf16"
-        # (float32 score math widens the core's output); identity at f32
-        h = h + (_merge_heads(out) @ p["wo"]).astype(h.dtype)
+    term's input.
+
+    Under a layer pattern (``kind``): a ``"mamba"`` layer's mixer is
+    ``attend(mixer params, normed h) -> (out, kept)`` under ``lm_ssm``
+    (models/ssm.py's prompt pass or cached step), the FFN is the dense
+    gated one under ``lm_mlp`` (``ffn`` unused, ``flat`` None), and each
+    branch is scaled by the spec's residual multiplier."""
+    branch = _residual(spec)
+    if kind == "mamba":
+        with jax.named_scope(LM_SSM_SCOPE):
+            out, kept = attend(p["mixer"], _norm(p, "ln", h, spec))
+            h = h + branch(out).astype(h.dtype)
+    else:
+        with jax.named_scope("lm_attn"):
+            hn = _norm(p, "ln", h, spec)
+            positions = None
+            if spec.rope_theta is not None:
+                positions = rows() if rows else jnp.arange(h.shape[1])
+            q, k, v = _qkv(p, hn, n_heads, spec, positions)
+            out, kept = attend(q, k, v)
+            # .astype keeps the carry's dtype stable under serve_dtype="bf16"
+            # (float32 score math widens the core's output); identity at f32
+            h = h + branch(_merge_heads(out) @ p["wo"]).astype(h.dtype)
+    if spec.ffn == "dense_swiglu":
+        with jax.named_scope(LM_MLP_SCOPE):
+            a, b = jnp.split(_norm(p, "ln2", h, spec) @ p["mlp"]["w_in"], 2,
+                             axis=-1)
+            y = (jax.nn.silu(a) * b) @ p["mlp"]["w_out"]
+            return h + branch(y).astype(h.dtype), kept, None
     # at this one site, so that the scope is entered once and the mesh
     # path's moe_apply lies inside it with its moe_all2all_* scopes
     with jax.named_scope("lm_moe"):
@@ -1316,6 +1452,9 @@ def _prefill_core(spec: BlockSpec, attn_impl: Optional[str]):
         if group > 1:
             k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
         if spec.attn_mask != "block":
+            if spec.attention_multiplier is not None:
+                # the seam's cores scale by 1/sqrt(Dh): the spec's instead
+                q = q * (spec.attention_multiplier * q.shape[-1] ** 0.5)
             return attention_core(q, k, v, causal=True, impl=attn_impl)
         blk = jnp.arange(q.shape[2]) // spec.block_length
         scores = jnp.where(blk[None, :] <= blk[:, None],
@@ -1359,16 +1498,149 @@ def lm_prefill(params: dict, tokens: Array, n_heads: int, top_k: int = 2,
     positions >= the real length produce garbage K/V that decode's position
     mask never reads. Under the block mask the same holds block for block:
     no block sees a later one."""
+    if spec.layer_pattern is not None:
+        raise ValueError("a layer pattern's prompt pass keeps recurrent "
+                         "state as well: make_prefill_step")
     h, (ks, vs) = _prefill_hidden(params, tokens, n_heads, top_k, attn_impl,
                                   spec)
     return _lm_head(params, h, spec), ks, vs
 
 
+# ---- a layer pattern (ISSUE 37): attention and state-space layers in one
+# model, each kind's layers stacked apart, one scan over the periods ----
+
+def _period_scan(blocks: dict, spec: BlockSpec, carry, layer_fn) -> tuple:
+    """The layer loop of a layer pattern: ONE ``lax.scan`` over the
+    periods, the period's layers in pattern order in its body, so that
+    compile time stays O(1) in depth. Layer j of a kind in period p is the
+    ``p * (that kind's count a period) + j``-th of its kind's stack, read
+    in place by index as ``_stacked_layers``' scan reads a layer.
+    ``layer_fn(kind, layer params, carry, index among its kind) -> (carry,
+    kept)``; returns (carry, {kind: kept stacked over that kind's layers
+    in depth order}) for the kinds that keep something."""
+    pattern = spec.layer_pattern
+    per = {kind: pattern.count(kind) for kind in pattern}
+    first = pattern[0]
+    periods = jax.tree_util.tree_leaves(blocks[first])[0].shape[0] \
+        // per[first]
+
+    def body(carry, period):
+        seen = dict.fromkeys(per, 0)
+        kept = {kind: [] for kind in per}
+        for kind in pattern:
+            index = period * per[kind] + seen[kind]
+            seen[kind] += 1
+            layer_params = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, index, 0,
+                                                       keepdims=False),
+                blocks[kind])
+            carry, out = layer_fn(kind, layer_params, carry, index)
+            kept[kind].append(out)
+        return carry, {kind: jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs), *outs)
+            for kind, outs in kept.items() if outs[0] is not None}
+
+    carry, kept = jax.lax.scan(body, carry,
+                               jnp.arange(periods, dtype=jnp.int32))
+    return carry, jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), kept)
+
+
+def _hybrid_prefill_hidden(params: dict, tokens: Array, n_heads: int,
+                           attn_impl: Optional[str], spec: BlockSpec,
+                           lengths) -> tuple:
+    """The prompt pass of a layer pattern up to the head: (h (B, T_pad, d),
+    {"attention": (ks, vs) (L_a, B, H_kv, T_pad, Dh), "mamba": (conv
+    (L_m, B, K - 1, conv_dim), state (L_m, B, H, P, N))}). ``lengths`` are
+    the real rows of each prompt: causal masking makes right-padding exact
+    for K/V, and the mixer zeroes dt and x past them so that the state it
+    keeps is the one at the last real row (models/ssm.py)."""
+    from deeplearning4j_tpu.models import ssm
+
+    core = _prefill_core(spec, attn_impl)
+    mixer = partial(ssm.mixer_prefill, spec=spec, lengths=lengths)
+
+    def layer_fn(kind, layer_params, h, _):
+        if kind == "mamba":
+            h, kept, _ = _block(layer_params, h, n_heads, spec, mixer, None,
+                                kind=kind)
+        else:
+            h, kept, _ = _block(layer_params, h, n_heads, spec,
+                                lambda q, k, v: (core(q, k, v), (k, v)), None)
+        return h, kept
+
+    return _period_scan(params["blocks"], spec, _embed(params, tokens, spec),
+                        layer_fn)
+
+
+def init_hybrid_cache(spec: BlockSpec, n_layers: int, n_slots: int,
+                      n_kv_heads: int, head_dim: int, max_len: int,
+                      dtype=jnp.float32) -> dict:
+    """The serving state of a layer pattern, zeroed: ``"k"``/``"v"`` (L_a,
+    S, H_kv, T_max, Dh) for its attention layers as ``init_kv_cache`` lays
+    them out, and for its state-space layers ``"conv"`` (L_m, S, K - 1,
+    conv_dim) in ``dtype`` and ``"ssm"`` (L_m, S, H, P, N) in float32 (the
+    state accumulates over a whole request). Every leaf is carried whole
+    through the cached step's loop and written in place."""
+    from deeplearning4j_tpu.models import ssm
+
+    counts = _pattern_counts(spec, n_layers)
+    w = ssm.widths(spec)
+    cache = init_kv_cache(counts.get("attention", 0), n_slots, n_kv_heads,
+                          head_dim, max_len, dtype)
+    cache["conv"] = jnp.zeros((counts.get("mamba", 0), n_slots,
+                               w["kernel"] - 1, w["conv_dim"]), dtype)
+    cache["ssm"] = jnp.zeros((counts.get("mamba", 0), n_slots, w["heads"],
+                              w["head_dim"], w["state"]), jnp.float32)
+    return cache
+
+
+def _hybrid_cached_layers(params: dict, cache: dict, h: Array,
+                          positions: Array, n_heads: int,
+                          spec: BlockSpec) -> tuple:
+    """``_cached_layers`` of a layer pattern, one new row a slot: h (S, 1,
+    d) through every layer, the carry ``(h, k, v, conv, ssm)`` with every
+    cache leaf whole. An attention layer attends over its pages
+    (``_attend_cache``, its rows written first); a state-space layer reads
+    its conv and recurrent state of every slot, updates both with the row
+    and writes them back in place (``ssm.mixer_step``)."""
+    from deeplearning4j_tpu.models import ssm
+
+    slot0 = jnp.zeros((), jnp.int32)
+    rows = lambda: (positions[:, None]  # noqa: E731
+                    + jnp.arange(h.shape[1])[None, :])
+
+    def layer_fn(kind, layer_params, carry, index):
+        h, ck, cv, conv, state = carry
+        if kind == "mamba":
+            h, (conv, state), _ = _block(
+                layer_params, h, n_heads, spec,
+                partial(ssm.mixer_step, spec=spec, conv_cache=conv,
+                        ssm_cache=state, layer=index), None, kind=kind)
+        else:
+            h, (ck, cv), _ = _block(
+                layer_params, h, n_heads, spec,
+                partial(_attend_cache, ck=ck, cv=cv, layer=index, slot0=slot0,
+                        positions=positions, rows=rows, spec=spec,
+                        layouts=_POSITIONS_MINOR),
+                None, rows)
+        return (h, ck, cv, conv, state), None
+
+    (h, ck, cv, conv, state), _ = _period_scan(
+        params["blocks"], spec,
+        (h, cache["k"], cache["v"], cache["conv"], cache["ssm"]), layer_fn)
+    return {"k": ck, "v": cv, "conv": conv, "ssm": state}, h
+
+
 _CACHE_LAYOUT = Layout(major_to_minor=(0, 1, 2, 3, 4))
+# the layer pattern's K/V leaves and chunks, positions minor
+_POSITIONS_MINOR = (Layout(major_to_minor=(0, 1, 2, 4, 3)),
+                    Layout(major_to_minor=(0, 1, 3, 2)))
 
 
 def _write_cache_rows(ck: Array, cv: Array, k_new: Array, v_new: Array,
-                      layer: Array, slot0: Array, positions: Array) -> tuple:
+                      layer: Array, slot0: Array, positions: Array,
+                      layout: Layout = _CACHE_LAYOUT) -> tuple:
     """Stores k_new/v_new (n, H, W, Dh) into the whole cache leaves ck/cv
     (L, S, H, T_max, Dh) at ``[layer, slot0 + i, :, positions[i] :
     positions[i] + W, :]``: one ``dynamic_update_slice`` a slot and leaf
@@ -1377,7 +1649,8 @@ def _write_cache_rows(ck: Array, cv: Array, k_new: Array, v_new: Array,
     row-major layout the cache arrives and leaves in. Left free, the v5e
     compiler re-lays a loop-carried cache with positions major to heads,
     which makes a one-row update contiguous and costs four whole-cache
-    transposing copies a step round the layer loop."""
+    transposing copies a step round the layer loop. ``layout`` is the one
+    the leaves arrive in where that is another (``_hybrid_cached_layers``)."""
     def one_slot(i, leaves):
         at = (layer, slot0 + i, 0, positions[i], 0)
         return tuple(
@@ -1385,7 +1658,7 @@ def _write_cache_rows(ck: Array, cv: Array, k_new: Array, v_new: Array,
                 jax.lax.dynamic_update_slice(
                     c, jax.lax.dynamic_slice_in_dim(new, i, 1)[None]
                     .astype(c.dtype), at),
-                _CACHE_LAYOUT)
+                layout)
             for c, new in zip(leaves, (k_new, v_new)))
 
     return jax.lax.fori_loop(0, positions.shape[0], one_slot, (ck, cv))
@@ -1393,7 +1666,7 @@ def _write_cache_rows(ck: Array, cv: Array, k_new: Array, v_new: Array,
 
 def _attend_cache(q: Array, k_new: Array, v_new: Array, ck: Array, cv: Array,
                   layer: Array, slot0: Array, positions: Array, rows,
-                  spec: BlockSpec) -> tuple:
+                  spec: BlockSpec, layouts: Optional[tuple] = None) -> tuple:
     """``attend`` of the cached step, W new tokens a slot: q (S, H, W, Dh)
     and the new rows (S, H_kv, W, Dh) of slots ``slot0``..``slot0 + S - 1``
     (every slot from 0 in decode and verify, the one slot of a prefill
@@ -1415,12 +1688,15 @@ def _attend_cache(q: Array, k_new: Array, v_new: Array, ck: Array, cv: Array,
     the same math, so verify logits at offset i are exactly what i
     sequential decode steps over the same tokens would produce. A cache of
     ``attn_chunks`` chunks is read by ``_attend_chunks`` instead, only up
-    to the chunk of the furthest row a query sees. Returns
+    to the chunk of the furthest row a query sees. ``layouts`` (the leaves',
+    a chunk's) pins the layout the leaves arrive in through the writes and
+    the chunk loop where it is not the row-major one (None). Returns
     (out (S, H, W, Dh), (ck, cv))."""
     n_slots, n_heads = q.shape[:2]
     with jax.named_scope("lm_cache_write"):
         ck, cv = _write_cache_rows(ck, cv, k_new, v_new, layer, slot0,
-                                   positions)
+                                   positions,
+                                   layouts[0] if layouts else _CACHE_LAYOUT)
     n_kv, n_chunks = ck.shape[2], attn_chunks(ck.shape[3])
     group = n_heads // n_kv
 
@@ -1436,7 +1712,7 @@ def _attend_cache(q: Array, k_new: Array, v_new: Array, ck: Array, cv: Array,
         if group > 1:
             q = q.reshape(n_slots, n_kv, -1, q.shape[-1])
         o = _attend_chunks(q, ck, cv, layer, slot0, visible(), n_chunks,
-                           spec)
+                           spec, layouts)
     else:
         pages = lambda c: jax.lax.dynamic_slice(  # noqa: E731
             c, (layer, slot0, 0, 0, 0), (1, n_slots) + c.shape[2:])[0]
@@ -1457,7 +1733,7 @@ def _attend_cache(q: Array, k_new: Array, v_new: Array, ck: Array, cv: Array,
 
 def _attend_chunks(q: Array, ck: Array, cv: Array, layer: Array,
                    slot0: Array, seen: Array, n_chunks: int,
-                   spec: BlockSpec) -> Array:
+                   spec: BlockSpec, layouts: Optional[tuple] = None) -> Array:
     """``_attend_cache``'s masked softmax read in chunks: q (S, H_kv, R, Dh)
     against layer ``layer``'s pages of slots ``slot0``..``slot0 + S - 1``,
     query row r seeing cache rows ``<= seen[:, r]``. A ``fori_loop`` takes
@@ -1470,7 +1746,12 @@ def _attend_chunks(q: Array, ck: Array, cv: Array, layer: Array,
     see at all adds exactly nothing (its terms are exact zeros and the
     rescale exactly 1), so a query's output does not depend on how many
     chunks its step reads: verify and decode, which read different numbers
-    of them, still compute the same thing."""
+    of them, still compute the same thing.
+
+    ``layouts`` (the leaves', a chunk's) pins both inside the loop: the
+    layer pattern's step, whose leaves arrive with positions minor (the
+    v5e's own layout for a head size of 64), re-lays both whole leaves
+    before the loop and back after it otherwise."""
     n_slots, n_kv, n_rows, head_dim = q.shape
     f32 = jnp.float32
     dtype = jnp.result_type(f32 if spec.accum_f32 else
@@ -1484,6 +1765,8 @@ def _attend_chunks(q: Array, ck: Array, cv: Array, layer: Array,
         k, v = (jax.lax.dynamic_slice(
             c, (layer, slot0, 0, start, 0),
             (1, n_slots, n_kv, ATTN_CHUNK, head_dim))[0] for c in (ck, cv))
+        if layouts:
+            k, v = (with_layout_constraint(c, layouts[1]) for c in (k, v))
         scores = jnp.where(start + index <= seen[:, None, :, None],
                            _scores(q, k, spec).astype(f32), -1e30)
         m_new = jnp.maximum(m, scores.max(-1))
@@ -1496,6 +1779,8 @@ def _attend_chunks(q: Array, ck: Array, cv: Array, layer: Array,
         return m_new, total, acc
 
     rows = (n_slots, n_kv, n_rows)
+    if layouts:
+        ck, cv = (with_layout_constraint(c, layouts[0]) for c in (ck, cv))
     _, total, acc = jax.lax.fori_loop(
         0, trips, chunk, (jnp.full(rows, -jnp.inf, f32), jnp.zeros(rows, f32),
                           jnp.zeros(rows + (head_dim,), f32)))
@@ -1541,7 +1826,14 @@ def lm_decode_step(params: dict, cache: dict, tokens: Array,
     with the updated cache. The cache rides through the layer loop as its
     carry (``_cached_layers``): layer l stores S rows at ``[l, s, :,
     positions[s], :]`` and every other element comes back untouched, in
-    the same buffers when the caller donated them."""
+    the same buffers when the caller donated them. Under a layer pattern
+    the cache holds each slot's recurrent state too, updated in place
+    (``_hybrid_cached_layers``)."""
+    if spec.layer_pattern is not None:
+        cache, h = _hybrid_cached_layers(
+            params, cache, _embed(params, tokens, spec)[:, None, :],
+            positions, n_heads, spec)
+        return cache, _lm_head(params, h, spec)[:, 0, :]
     with jax.named_scope("lm_embed"):
         h = params["embed"][tokens][:, None, :]           # (S, 1, d)
     cache, h = _cached_layers(params, cache, h, positions, n_heads, top_k,
@@ -1597,8 +1889,13 @@ def make_prefill_step(n_heads: int, top_k: int = 2,
     right-padded to the engine's bucket, so compiles are bounded by the
     bucket count (slot/last_idx are traced). Under block-diffusion
     generation the prompt pass only stores: the head is not run and the
-    token that comes back is 0, nothing to accept."""
+    token that comes back is 0, nothing to accept. Under a layer pattern
+    the slot's recurrent state is written beside its K/V pages
+    (``_make_hybrid_prefill``)."""
     transform = params_transform or (lambda p: p)
+    if spec.layer_pattern is not None:
+        return _make_hybrid_prefill(n_heads, attn_impl, donate_cache,
+                                    transform, spec)
     stores_only = spec.generation == "block_diffusion"
 
     @partial(jax.jit, donate_argnums=(1,) if donate_cache else ())
@@ -1621,6 +1918,34 @@ def make_prefill_step(n_heads: int, top_k: int = 2,
                                             keepdims=False)
         k = jax.random.fold_in(key, step_idx)
         return {"k": ck, "v": cv}, sample_tokens(last, k, temp)
+
+    return prefill
+
+
+def _make_hybrid_prefill(n_heads: int, attn_impl: Optional[str],
+                         donate_cache: bool, transform, spec: BlockSpec):
+    """``make_prefill_step`` of a layer pattern, the same signature: the
+    prompt pass with ``last_idx + 1`` real rows, the slot's K/V pages and
+    its conv and recurrent state written (every state row of the slot, so
+    nothing of an earlier request is left to read), the head on the last
+    real row alone."""
+    @partial(jax.jit, donate_argnums=(1,) if donate_cache else ())
+    def prefill(params, cache, tokens, last_idx, slot, temp, key, step_idx):
+        params = transform(params)
+        h, kept = _hybrid_prefill_hidden(params, tokens, n_heads, attn_impl,
+                                         spec, last_idx + 1)
+        fresh = dict(zip(("k", "v"), kept.get("attention", ())),
+                     **dict(zip(("conv", "ssm"), kept.get("mamba", ()))))
+        out = dict(cache)
+        with jax.named_scope("lm_cache_write"):
+            for name, new in fresh.items():
+                at = (0, slot) + (0,) * (cache[name].ndim - 2)
+                out[name] = jax.lax.dynamic_update_slice(
+                    cache[name], new.astype(cache[name].dtype), at)
+        last = jax.lax.dynamic_index_in_dim(h[0], last_idx, 0)
+        logits = _lm_head(params, last[None], spec)[0, 0]
+        return out, sample_tokens(logits, jax.random.fold_in(key, step_idx),
+                                  temp)
 
     return prefill
 
@@ -1831,6 +2156,11 @@ def lm_dims(params: dict) -> dict:
     ``n_heads``, which the head-split erases — that one travels in
     checkpoint meta (``lm_checkpoint_meta``) or a CLI flag."""
     vocab, d_model = params["embed"].shape
+    if "experts" not in params["blocks"]:  # a layer pattern: dense FFNs
+        d_ff = next(iter(params["blocks"].values()))["mlp"]["w_out"].shape[1]
+        return {"vocab": int(vocab), "d_model": int(d_model),
+                "n_layers": lm_n_layers(params), "n_experts": 0,
+                "d_ff": int(d_ff)}
     experts = params["blocks"]["experts"]
     w_in = experts["wg"] if "wg" in experts else experts["w1"]
     n_layers, n_experts, _, d_ff = w_in.shape

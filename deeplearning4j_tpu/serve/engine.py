@@ -114,6 +114,7 @@ from __future__ import annotations
 import itertools
 import threading
 import time
+from functools import partial
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -126,6 +127,7 @@ from deeplearning4j_tpu.models.transformer_lm import (
     BlockSpec,
     attn_chunk_reads,
     draft_truncate_params,
+    init_hybrid_cache,
     init_kv_cache,
     lm_dims,
     make_block_step,
@@ -272,6 +274,11 @@ class DecodeEngine:
                 self.dims["d_model"] % self.n_heads:
             raise ValueError(
                 f"d_model {self.dims['d_model']} % n_heads {n_heads} != 0")
+        # a layer pattern with state-space layers: each slot keeps recurrent
+        # state beside its K/V pages
+        self.ssm_mode = self.block_spec.layer_pattern is not None
+        if self.ssm_mode:
+            self._check_state_space(speculative, prefill_chunk, prefix_cache)
         if self.block_mode:
             self._check_block_mode(speculative, prefill_chunk, prefix_cache)
         self.n_slots = int(n_slots)
@@ -290,9 +297,11 @@ class DecodeEngine:
         head_dim = self.block_spec.head_size(self.dims["d_model"],
                                              self.n_heads)
         n_kv = self.block_spec.kv_heads(self.n_heads)
-        self._cache = init_kv_cache(self.dims["n_layers"], self.n_slots,
-                                    n_kv, head_dim, self.max_len,
-                                    dtype=activation_dtype(serve_dtype))
+        make_cache = (partial(init_hybrid_cache, self.block_spec)
+                      if self.ssm_mode else init_kv_cache)
+        self._cache = make_cache(self.dims["n_layers"], self.n_slots, n_kv,
+                                 head_dim, self.max_len,
+                                 dtype=activation_dtype(serve_dtype))
         programs = dict(params_transform=dequantize_tree,
                         spec=self.block_spec)
         if self.block_mode:
@@ -324,7 +333,8 @@ class DecodeEngine:
                            or self._prefix is not None) else None)
         self._chunking: dict = {}       # slot -> pending chunk plan
         # the environment's speculation switch is for one-token models
-        self.spec = (None if self.block_mode
+        # whose whole state is K/V
+        self.spec = (None if self.block_mode or self.ssm_mode
                      else resolve_speculative(speculative))
         if self.spec is not None:
             if self.spec.k + 1 >= max_len:
@@ -405,6 +415,15 @@ class DecodeEngine:
                 for kind in ("denoise", "commit")}
             self._c_block_accepted = reg.counter(
                 "serve_block_tokens_accepted_total")
+        # the phase attrs of a prefill dispatch: under a layer pattern the
+        # state-space layers it seeds
+        self._prefill_attrs = {}
+        if self.ssm_mode:
+            reg.gauge("serve_ssm_state_bytes").set(float(sum(
+                self._cache[name].nbytes for name in ("conv", "ssm"))))
+            self._c_ssm_writes = reg.counter("serve_ssm_state_writes_total")
+            self._prefill_attrs["ssm_layers"] = int(
+                self._cache["ssm"].shape[0])
         self._key = jax.random.PRNGKey(seed)
         # the lockwatch seam (ISSUE 11): plain primitives unless the
         # watch is armed (lockwatch fixture / DL4J_TPU_LOCKWATCH=1)
@@ -434,6 +453,33 @@ class DecodeEngine:
         self.decode_steps = 0
         self._occupancy_sum = 0
         self._t_first_activity: Optional[float] = None
+
+    def _check_state_space(self, speculative, prefill_chunk,
+                           prefix_cache) -> None:
+        """The fast paths that take a slot's state for its K/V pages alone
+        are refused under a layer pattern with state-space layers: each
+        would need the recurrent state snapshotted or carried across a
+        dispatch, which the engine does not do."""
+        if self.block_spec.generation != "autoregressive":
+            raise ValueError(
+                "block-diffusion generation with state-space layers: a "
+                "denoising forward rewrites a block's rows, and a recurrent "
+                "state cannot take back what a provisional row added")
+        if speculative:
+            raise ValueError(
+                "speculative= with state-space layers: a rejected proposal "
+                "has already advanced the recurrent state, and rolling it "
+                "back needs a snapshot of every slot's state a position")
+        if prefill_chunk is not None:
+            raise ValueError(
+                "prefill_chunk= with state-space layers: each chunk would "
+                "have to start from the state the previous chunk left, and "
+                "the chunk step carries K/V pages alone")
+        if prefix_cache:
+            raise ValueError(
+                "prefix_cache= with state-space layers: a cached prefix's "
+                "pages hold K/V alone, and the recurrent state at the "
+                "prefix's end would have to be stored with them")
 
     def _check_block_mode(self, speculative, prefill_chunk,
                           prefix_cache) -> None:
@@ -650,8 +696,10 @@ class DecodeEngine:
         # ---- full hit: the cached prefix covers every position the last
         # prompt token's decode tick doesn't write itself — NO flagship
         # prefill dispatch; the first token arrives from the shared
-        # decode step, exactly as if prefill had just run ----
-        if plen >= n - 1:
+        # decode step, exactly as if prefill had just run. Not with
+        # state-space layers: only a prefill overwrites the slot's state,
+        # so a one-token prompt is prefilled too ----
+        if plen >= n - 1 and not self.ssm_mode:
             self._tokens[slot] = req.prompt[-1]
             self._positions[slot] = n - 1
             self._finish_prefill_span(req, mode="cached_full")
@@ -712,12 +760,15 @@ class DecodeEngine:
         if req.prefill_span is not None:
             req.prefill_span.set_attr("bucket", bucket)
         with _trace.phase("tick.prefill", self._tick.tick, rid=req.rid,
-                          prompt_len=n, bucket=bucket) as ph:
+                          prompt_len=n, bucket=bucket,
+                          **self._prefill_attrs) as ph:
             self._cache, tok = self._prefill(
                 self.params, self._cache, padded, n - 1, slot,
                 np.float32(req.temperature), self._key, self._step_idx)
             self._step_idx += 1
             self._c_prefill_dispatches.inc()
+            if self.ssm_mode:  # the slot's state overwritten whole
+                self._c_ssm_writes.inc()
             tok = int(np.asarray(tok))  # graftlint: allow[blocking-under-lock] deliberate: the scheduler lock IS the serialization — slot state may only change together with the fenced prefill result
         req.prefill_suffix_ms += ph.ms
         req.prefill_ms += ph.ms

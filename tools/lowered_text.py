@@ -39,6 +39,7 @@ import json
 import os
 import subprocess
 import sys
+from functools import partial
 
 HERE = os.path.abspath(__file__)
 REPO_ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -86,7 +87,12 @@ def serve_programs(cell: dict, model) -> dict:
             init_scale=d.get("init_scale")), s["serve_dtype"]),
         model.seed_key(0))
     n_slots, max_len = int(s["n_slots"]), int(s["max_len"])
-    cache = jax.eval_shape(lambda: lm.init_kv_cache(
+    # a layer pattern's cache holds recurrent state beside K/V (a tree
+    # before patterns has no such field)
+    make_cache = (partial(lm.init_hybrid_cache, spec)
+                  if getattr(spec, "layer_pattern", None) is not None
+                  else lm.init_kv_cache)
+    cache = jax.eval_shape(lambda: make_cache(
         d["n_layers"], n_slots, spec.kv_heads(d["n_heads"]),
         spec.head_size(d["d_model"], d["n_heads"]), max_len,
         dtype=activation_dtype(s["serve_dtype"])))
