@@ -23,6 +23,11 @@ zeroed past its length, so the state crosses the padding unchanged
 the prompt's last real token; the conv state is that prompt's last K - 1
 rows of ``xBC`` before the conv (zeros where the prompt is shorter).
 
+The cached step is one Pallas kernel (``_state_step``) that reads each
+slot's state of the layer once, advances it in place and forms ``S_t C_t``
+from the same tile (XLA's form read the state a second time for the
+product with C).
+
 Named scopes: ``ssm_scan`` covers the chunked scan of the prompt pass,
 ``ssm_step`` the cached step's read of a slot's state, its update, its
 output and its write back; both lie inside the block's ``lm_ssm``.
@@ -30,9 +35,15 @@ output and its write back; both lie inside the block's ``lm_ssm``.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 from jax.experimental.layout import Layout, with_layout_constraint
+from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning4j_tpu.ops import pallas_kernels
 
 Array = jax.Array
 
@@ -237,6 +248,65 @@ def _advance(state: Array, decay: Array, update: Array) -> Array:
     return state * decay + update
 
 
+# Most heads of one slot a grid step of ``_state_step`` (the block is the
+# largest divisor of a group's heads up to this): a (64, 64, 128) float32
+# block is 2 MB, in and out double-buffered 8.9 MB of VMEM, and Granite's 48
+# slots are 48 steps a layer. At Granite's widths on a v5e (48 slots, the
+# layer loop over all 36 layers) a layer took 0.409, 0.345, 0.339 and 0.338
+# ms at 8, 16, 32 and 64 heads (493 to 596 GB/s of state read and written);
+# 2 and 4 slots a step at 64 heads 0.341 (VMEM limit raised); the update
+# alone without y 0.336, XLA's update alone 0.341, XLA's update and its
+# product with C 0.474.
+STATE_HEAD_BLOCK = 64
+
+
+def _state_step_kernel(layer_ref, state_ref, decay_ref, dtx_ref, b_ref,
+                       c_ref, state_out_ref, y_ref):
+    """One slot's block of heads: state (hb, P, N) advanced by decay (hb,
+    1) and (dt x) (hb, P) times B (1, N), written back, and y (hb, P) its
+    sum over N against C (1, N), in float32 on the VPU."""
+    del layer_ref  # read by the index maps
+    state = _advance(state_ref[...], decay_ref[...][..., None],
+                     dtx_ref[...][..., None] * b_ref[...][None])
+    state_out_ref[...] = state
+    y_ref[...] = jnp.sum(state * c_ref[...][None], axis=-1)
+
+
+def _state_step(ssm_cache: Array, layer: Array, decay: Array, dtx: Array,
+                b: Array, c: Array) -> tuple:
+    """The one-row update of layer ``layer`` of the whole leaf ``ssm_cache``
+    (L, S, H, P, N), in place, and its output: decay (S, H), dtx (S, H, P),
+    b and c (S, G, N), all float32. Every (slot, block of heads) tile of the
+    layer is read once and written once; the other layers are not touched.
+    Each block's last two dimensions are whole dimensions of its array, so
+    Mosaic takes any widths. Returns (the leaf, y (S, H, P))."""
+    _, s, h, p, n = ssm_cache.shape
+    g = b.shape[1]
+    hb = math.gcd(STATE_HEAD_BLOCK, h // g)
+    blocks_a_group = h // g // hb
+    tile = pl.BlockSpec((None, None, hb, p, n),
+                        lambda i, j, layer: (layer[0], i, j, 0, 0))
+    rows = lambda width: pl.BlockSpec(  # noqa: E731
+        (None, None, hb, width), lambda i, j, layer: (i, j, 0, 0))
+    group = pl.BlockSpec((None, None, 1, n), lambda i, j, layer: (
+        i, j // blocks_a_group, 0, 0))
+    cache, y = pl.pallas_call(
+        _state_step_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(s, h // hb),
+            in_specs=[tile, rows(1), rows(p), group, group],
+            out_specs=[tile, rows(p)]),
+        out_shape=[jax.ShapeDtypeStruct(ssm_cache.shape, _F32),
+                   jax.ShapeDtypeStruct((s, h // hb, hb, p), _F32)],
+        input_output_aliases={1: 0},
+        interpret=pallas_kernels._interpret(),
+        name="ssm_state_step",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), ssm_cache,
+      decay.reshape(s, h // hb, hb, 1), dtx.reshape(s, h // hb, hb, p),
+      b[:, :, None], c[:, :, None])
+    return cache, y.reshape(s, h, p)
+
+
 def mixer_step(p: dict, u: Array, spec, conv_cache: Array, ssm_cache: Array,
                layer: Array) -> tuple:
     """The mixer for one new row a slot: u (S, 1, d); ``conv_cache`` (L, S,
@@ -254,15 +324,9 @@ def mixer_step(p: dict, u: Array, spec, conv_cache: Array, ssm_cache: Array,
     x, b, c = _split_xbc(_conv(window, p["conv_w"], p["conv_b"], 1)[:, 0], w)
     dt, a = _dt_a(p, dt, w)
     with jax.named_scope(SSM_STEP_SCOPE):
-        state = jax.lax.dynamic_index_in_dim(ssm_cache, layer, 0,
-                                             keepdims=False)
-        state = state.reshape(s, g, r, w["head_dim"], w["state"])
-        state = _advance(state, jnp.exp(dt * a)[..., None, None],
-                         (dt[..., None] * x)[..., None]
-                         * b[:, :, None, None, :])
-        y = jnp.einsum("sgrpn,sgn->sgrp", state, c, precision=_HIGHEST)
-        ssm_cache = _write_layer(ssm_cache, state.reshape(
-            (s,) + ssm_cache.shape[2:]), layer)
+        ssm_cache, y = _state_step(ssm_cache, layer, jnp.exp(dt * a),
+                                   dt[..., None] * x, b, c)
+        y = y.reshape(x.shape)
     y = y + p["D"].astype(_F32).reshape(g, r)[..., None] * x
     out = _gated_out(p, y.reshape(s, w["d_inner"]), z, spec.norm_eps,
                      u.dtype)
