@@ -392,6 +392,9 @@ class DecodeEngine:
             self._runprof._registry = self.registry
         if self._runprof is not None:
             self._runprof.arm("serve_decode")
+        # a collection stops the scheduler with the device idle, and no
+        # Python frame shows it: each one is a host.gc phase in the ring
+        _trace.install_gc_phases()
         if self.spec is not None:
             self._c_spec_verify_steps = reg.counter(
                 "serve_spec_verify_steps_total")
@@ -762,14 +765,18 @@ class DecodeEngine:
         with _trace.phase("tick.prefill", self._tick.tick, rid=req.rid,
                           prompt_len=n, bucket=bucket,
                           **self._prefill_attrs) as ph:
-            self._cache, tok = self._prefill(
-                self.params, self._cache, padded, n - 1, slot,
-                np.float32(req.temperature), self._key, self._step_idx)
+            with _trace.phase("tick.dispatch", self._tick.tick,
+                              program="jit_prefill"):
+                self._cache, tok = self._prefill(
+                    self.params, self._cache, padded, n - 1, slot,
+                    np.float32(req.temperature), self._key, self._step_idx)
             self._step_idx += 1
             self._c_prefill_dispatches.inc()
             if self.ssm_mode:  # the slot's state overwritten whole
                 self._c_ssm_writes.inc()
-            tok = int(np.asarray(tok))  # graftlint: allow[blocking-under-lock] deliberate: the scheduler lock IS the serialization — slot state may only change together with the fenced prefill result
+            with _trace.phase("tick.fence", self._tick.tick,
+                              program="jit_prefill"):
+                tok = int(np.asarray(tok))  # graftlint: allow[blocking-under-lock] deliberate: the scheduler lock IS the serialization — slot state may only change together with the fenced prefill result
         req.prefill_suffix_ms += ph.ms
         req.prefill_ms += ph.ms
         return tok, ph.t1
@@ -824,15 +831,19 @@ class DecodeEngine:
         with _trace.phase("tick.prefill", self._tick.tick, rid=req.rid,
                           prompt_len=len(req.prompt),
                           bucket=toks.shape[1]) as ph:
-            self._cache, tok = self._chunk(
-                self.params, self._cache, toks, np.int32(start),
-                np.int32(last_idx), np.int32(slot),
-                np.float32(req.temperature), self._key, self._step_idx)
+            with _trace.phase("tick.dispatch", self._tick.tick,
+                              program="jit_chunk"):
+                self._cache, tok = self._chunk(
+                    self.params, self._cache, toks, np.int32(start),
+                    np.int32(last_idx), np.int32(slot),
+                    np.float32(req.temperature), self._key, self._step_idx)
             self._step_idx += 1
             self._c_prefill_dispatches.inc()
             req.prefill_chunks += 1
-            if final:
-                tok = int(np.asarray(tok))  # graftlint: allow[blocking-under-lock] deliberate: same fencing contract as the classic prefill — slot state changes only with the fenced result
+            if final:  # a chunk before it is left to run: no fence
+                with _trace.phase("tick.fence", self._tick.tick,
+                                  program="jit_chunk"):
+                    tok = int(np.asarray(tok))  # graftlint: allow[blocking-under-lock] deliberate: same fencing contract as the classic prefill — slot state changes only with the fenced result
         req.prefill_suffix_ms += ph.ms
         req.prefill_ms += ph.ms
         if final:
@@ -987,13 +998,15 @@ class DecodeEngine:
 
         The iteration is one ``tick`` phase (telemetry/trace.py) with its
         children ``tick.admit`` > ``tick.prefill``, ``tick.decode`` and
-        ``tick.accept``. That record is the tick's only clock: the decode
-        histogram, the ``engine.step`` span and the runprof timing below
-        all read it."""
+        ``tick.accept``; each fenced dispatch holds a ``tick.dispatch``
+        (the jitted call) and a ``tick.fence`` (the wait for its result).
+        That record is the tick's only clock: the decode histogram, the
+        ``engine.step`` span and the runprof timing below all read it."""
         tracer = _trace.get_tracer()
         step_span = (tracer.start_span("engine.step", parent=False)
                      if tracer is not None else None)
         decode = None       # the tick.decode phase, where a step ran
+        dispatch = None     # its tick.dispatch, where it has one
         decode_ms = 0.0
         with _trace.phase("tick") as tick, self._lock:
             self._tick = tick
@@ -1036,24 +1049,28 @@ class DecodeEngine:
                                   occupancy=len(active),
                                   speculative=True) as decode:
                     # k+1 draft dispatches interleaved with their fences
-                    # and the acceptance: no finer split
+                    # and the acceptance: no finer split (no benchmark
+                    # cell turns speculation on)
                     decode_ms = self._spec_step(active, step_span)
             elif active and self.block_mode:
-                decode = self._block_tick(active, tick)
+                decode, dispatch = self._block_tick(active, tick)
                 decode_ms = decode.ms
             elif active:
                 chunks = self._count_attn_chunks(self._positions)
                 with _trace.phase("tick.decode", tick.tick,
                                   occupancy=len(active),
                                   chunks=chunks) as decode:
-                    self._cache, toks = self._decode(
-                        self.params, self._cache, self._tokens,
-                        self._positions, self._temps, self._key,
-                        self._step_idx)
+                    with _trace.phase("tick.dispatch", tick.tick,
+                                      program="jit_step") as dispatch:
+                        self._cache, toks = self._decode(
+                            self.params, self._cache, self._tokens,
+                            self._positions, self._temps, self._key,
+                            self._step_idx)
                     self._step_idx += 1
                     # enqueue back; the device runs until the fence
-                    decode.attrs["t_disp"] = time.perf_counter()
-                    toks = np.asarray(toks)  # graftlint: allow[blocking-under-lock] deliberate: retirement must see the fenced decode tokens; submit() blocks here only between decode steps
+                    with _trace.phase("tick.fence", tick.tick,
+                                      program="jit_step"):
+                        toks = np.asarray(toks)  # graftlint: allow[blocking-under-lock] deliberate: retirement must see the fenced decode tokens; submit() blocks here only between decode steps
                 decode_ms = decode.ms
                 self._h_decode_step_ms.observe(decode_ms)
                 self.decode_steps += 1
@@ -1088,13 +1105,13 @@ class DecodeEngine:
             # whole measured wall goes to the device phase. The host phase
             # is the tick's scheduler work (admission, chunked prefill,
             # retirement): everything outside the decode dispatch + fence
-            t_disp = decode.attrs.get("t_disp", decode.t0)
+            dispatch_ms = dispatch.ms if dispatch is not None else 0.0
             self._runprof.record(StepTiming(
                 label="serve_decode", t_unix=time.time(),
                 wall_ms=decode_ms,
                 host_ms=max(0.0, tick.ms - decode_ms),
-                dispatch_ms=(t_disp - decode.t0) * 1000.0,
-                device_ms=decode_ms - (t_disp - decode.t0) * 1000.0,
+                dispatch_ms=dispatch_ms,
+                device_ms=decode_ms - dispatch_ms,
                 trace_id=(step_span.trace_id
                           if step_span is not None else None)))
         return emitted
@@ -1112,7 +1129,7 @@ class DecodeEngine:
         ``jit_block_step`` over every slot inside ``tick.decode``, one
         fence, then ``tick.accept``. A live slot's forward is a denoising
         one while any position of its block is masked, else the commit.
-        Returns the ``tick.decode`` phase."""
+        Returns the ``tick.decode`` phase and its ``tick.dispatch``."""
         width = self.block_spec.block_length
         before = self._block_masked  # the step hands back new arrays
         slots = [r.slot for r in active]
@@ -1121,14 +1138,17 @@ class DecodeEngine:
         with _trace.phase("tick.decode", tick.tick, occupancy=len(active),
                           kind="block", denoise=n_denoise,
                           commit=len(active) - n_denoise) as decode:
-            self._cache, toks, masked = self._block_step(
-                self.params, self._cache, self._block_tokens,
-                self._positions, self._block_masked, self._temps, self._key,
-                self._step_idx)
+            with _trace.phase("tick.dispatch", tick.tick,
+                              program="jit_block_step") as dispatch:
+                self._cache, toks, masked = self._block_step(
+                    self.params, self._cache, self._block_tokens,
+                    self._positions, self._block_masked, self._temps,
+                    self._key, self._step_idx)
             self._step_idx += 1
             # enqueue back; the device runs until the fence
-            decode.attrs["t_disp"] = time.perf_counter()
-            toks, masked = jax.device_get((toks, masked))  # graftlint: allow[blocking-under-lock] deliberate: acceptance must see the fenced step's tokens and bitmap, exactly like the decode tick
+            with _trace.phase("tick.fence", tick.tick,
+                              program="jit_block_step"):
+                toks, masked = jax.device_get((toks, masked))  # graftlint: allow[blocking-under-lock] deliberate: acceptance must see the fenced step's tokens and bitmap, exactly like the decode tick
             # the host's own copies: the next block's state is written here
             toks, masked = toks.copy(), masked.copy()
             decode.attrs["accepted"] = int(
@@ -1168,7 +1188,7 @@ class DecodeEngine:
                 else:
                     self._positions[slot] = start + width
                     toks[slot], masked[slot] = 0, True
-        return decode
+        return decode, dispatch
 
     def _spec_step(self, active: List[ServeRequest], step_span) -> float:
         """One speculative iteration (called under the scheduler lock):

@@ -45,6 +45,7 @@ a dict lookup and a no-op context manager.
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
 import json
 import math
@@ -463,8 +464,21 @@ def maybe_span(name: str, parent=None,
 # window. One record per span, read by every consumer: the engine's
 # histogram, its ``engine.step`` span and its runprof timing take their
 # numbers from it and stamp nothing themselves.
+#
+# The ring holds a whole benchmark window and its drain at the fastest
+# tick: a decode tick writes up to 6 entries (``tick``, ``tick.admit``,
+# ``tick.decode`` with ``tick.dispatch`` and ``tick.fence`` inside it,
+# ``tick.accept``), an 8 ms step makes at most 125 ticks a second, and 45 s
+# of window plus 60 s of drain at that rate is 105 s x 750 = 79k entries;
+# 2^17 leaves the rest for prefills and ``host.gc``; 16,384 held 33 s at
+# four entries a tick. A full ring is some 32 MB. Its dicts hold atoms and
+# go untracked by the garbage collector, but a tuple that holds a dict
+# stays tracked, so a full collection walks every entry: in a chat
+# serving process on the v5e's host one took some 47 ms with the ring
+# empty and 64 with it full, and at that cell's rate the process ran no
+# full collection in 150 s.
 
-PHASE_RING = 16_384
+PHASE_RING = 1 << 17
 
 
 class PhaseRing:
@@ -519,6 +533,32 @@ class phase:
     @property
     def ms(self) -> float:
         return (self.t1 - self.t0) * 1000.0
+
+
+_gc_open: List[phase] = []  # the collection running now, if any
+
+
+def _gc_phase(stage: str, info: Dict) -> None:
+    """The ``gc.callbacks`` hook: one ``host.gc`` phase a collection, of
+    its own tick, attrs generation and collected. A collection stops every
+    thread, and CPython starts no second one before the first has
+    stopped, so one open phase is all there can be."""
+    if stage == "start":
+        p = phase("host.gc", generation=info["generation"])
+        p.__enter__()
+        _gc_open.append(p)
+    elif _gc_open:
+        p = _gc_open.pop()
+        p.attrs["collected"] = info["collected"]
+        p.__exit__(None, None, None)
+
+
+def install_gc_phases() -> None:
+    """Record every garbage collection of the process as a ``host.gc``
+    phase (its ``TraceAnnotation`` too), from now on. Once a process:
+    a second call installs nothing."""
+    if _gc_phase not in gc.callbacks:
+        gc.callbacks.append(_gc_phase)
 
 
 def phases_between(lo: float, hi: float) -> Tuple[List[tuple], bool]:

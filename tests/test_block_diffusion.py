@@ -332,6 +332,27 @@ def test_the_tick_names_the_block_step_and_its_scopes(sdar):
         assert scope in text, scope
 
 
+def test_each_block_tick_holds_one_dispatch_and_one_fence(sdar):
+    """Inside every block tick's ``tick.decode``: the jitted call under
+    ``tick.dispatch``, then the copy of its tokens and bitmap under
+    ``tick.fence``, both named by the program the device trace counts."""
+    from deeplearning4j_tpu.telemetry import trace
+
+    eng = engine(sdar[0])
+    t0 = time.perf_counter()
+    eng.generate([1, 2, 3, 4, 5], max_new_tokens=4)
+    entries, _ = trace.phases_between(t0, time.perf_counter())
+    decodes = [e for e in entries if e[0] == "tick.decode"]
+    assert decodes
+    for _, tick, lo, hi, _ in decodes:
+        inside = sorted((e[2], e[0], e[4]["program"]) for e in entries
+                        if e[0] in ("tick.dispatch", "tick.fence")
+                        and e[1] == tick and lo <= e[2] and e[3] <= hi)
+        assert [x[1:] for x in inside] == [
+            ("tick.dispatch", "jit_block_step"),
+            ("tick.fence", "jit_block_step")]
+
+
 # ------------------------------------------------------------ checkpoints ----
 
 def test_a_checkpoint_carries_the_spec_into_the_engine(sdar, tmp_path):

@@ -7,6 +7,7 @@ tools/trace_report.py reconstruction — including the partial-round case
 a kill -9 leaves behind (begin records with no end).
 """
 
+import gc
 import json
 import os
 import subprocess
@@ -467,11 +468,33 @@ class TestTraceparent:
 # ------------------------------------------------- tick phases (ISSUE 25) ----
 
 @pytest.fixture
-def phase_ring(monkeypatch):
+def gc_held():
+    """Automatic garbage collections held off for the test: once an engine
+    has installed the ``host.gc`` hook, a collection in the middle of a
+    test would add a phase to the ring it reads."""
+    enabled = gc.isenabled()
+    gc.disable()
+    yield
+    if enabled:
+        gc.enable()
+
+
+@pytest.fixture
+def phase_ring(monkeypatch, gc_held):
     """A ring of this test's own in place of the process's."""
     ring = tr.PhaseRing()
     monkeypatch.setattr(tr, "_phase_ring", ring)
     return ring
+
+
+def _fenced(entries, parent):
+    """The ``tick.dispatch`` and ``tick.fence`` phases of ``parent``'s tick
+    that lie inside it, in the order they ran."""
+    _, tick, t0, t1, _ = parent
+    return sorted((e for e in entries
+                   if e[0] in ("tick.dispatch", "tick.fence")
+                   and e[1] == tick and t0 <= e[2] and e[3] <= t1),
+                  key=lambda e: e[2])
 
 
 def _tiny_engine(**kw):
@@ -531,7 +554,7 @@ class TestPhases:
                                        10.0 - 4.0 - 3.0 - 0.5, 10.0, 1.0])
 
     def test_phases_between_clips_and_reports_a_wrapped_ring(
-            self, monkeypatch):
+            self, monkeypatch, gc_held):
         ring = tr.PhaseRing(maxlen=3)
         monkeypatch.setattr(tr, "_phase_ring", ring)
         for i in range(3):
@@ -607,7 +630,8 @@ class TestPhases:
                 == names.count("tick.prefill")
             decode = next(e for e in kids if e[0] == "tick.decode")
             assert decode[4]["occupancy"] == attrs["occupancy"]
-            assert decode[2] <= decode[4]["t_disp"] <= decode[3]
+            assert [e[4]["program"] for e in _fenced(entries, decode)] == [
+                "jit_step", "jit_step"]
         assert sum(t[4]["admitted"] for t in ticks) == 3
         assert sum(t[4]["retired"] for t in ticks) == 3
         prefills = [e for e in entries if e[0] == "tick.prefill"]
@@ -645,8 +669,9 @@ class TestPhases:
         assert [t.wall_ms for t in timings] == pytest.approx(ms)
         for t, e in zip(timings, decodes):
             tick = ticks[e[1]]
+            dispatch, _ = _fenced(entries, e)
             assert t.dispatch_ms == pytest.approx(
-                (e[4]["t_disp"] - e[2]) * 1000.0)
+                (dispatch[3] - dispatch[2]) * 1000.0)
             assert t.dispatch_ms + t.device_ms == pytest.approx(t.wall_ms)
             assert t.host_ms == pytest.approx(
                 (tick[3] - tick[2]) * 1000.0 - t.wall_ms)
@@ -656,3 +681,57 @@ class TestPhases:
             round(x, 3) for x in ms]
         assert [s["attrs"]["occupancy"] for s in steps] == [
             e[4]["occupancy"] for e in decodes]
+
+    @pytest.mark.parametrize("kind", ["decode", "prefill", "chunk"])
+    def test_each_fenced_dispatch_holds_one_dispatch_and_one_fence(
+            self, phase_ring, kind):
+        """The jitted call under ``tick.dispatch`` and the wait for its
+        result under ``tick.fence``, both inside the phase that fences
+        them, named by the program the device trace counts; a chunk before
+        the last is left to run, so it holds a dispatch alone."""
+        eng = _tiny_engine(**({"prefill_chunk": 4} if kind == "chunk"
+                              else {}))
+        eng.generate(list(range(1, 11)), max_new_tokens=3)
+        entries, _ = tr.phases_between(0.0, float("inf"))
+        parents = [e for e in entries if e[0] == (
+            "tick.decode" if kind == "decode" else "tick.prefill")]
+        program = {"decode": "jit_step", "prefill": "jit_prefill",
+                   "chunk": "jit_chunk"}[kind]
+        want = [("tick.dispatch", program), ("tick.fence", program)]
+        # 10 positions in chunks of 4: starts 0 and 4, the last shifted to 6
+        assert len(parents) == (3 if kind == "chunk" else
+                                2 if kind == "decode" else 1)
+        for i, parent in enumerate(parents):
+            got = [(e[0], e[4]["program"]) for e in _fenced(entries, parent)]
+            final = kind != "chunk" or i == len(parents) - 1
+            assert got == (want if final else want[:1])
+        # one dispatch a prefill, chunk or decode step, and no other
+        assert sum(e[0] == "tick.dispatch" for e in entries) == sum(
+            e[0] in ("tick.prefill", "tick.decode") for e in entries)
+
+    def test_a_collection_under_the_hook_is_one_host_gc_phase(
+            self, phase_ring):
+        tr.install_gc_phases()
+        tr.install_gc_phases()
+        assert gc.callbacks.count(tr._gc_phase) == 1
+        lo = time.perf_counter()
+        gc.collect()
+        entries, _ = tr.phases_between(lo, time.perf_counter())
+        assert [e[0] for e in entries] == ["host.gc"]
+        _, tick, t0, t1, attrs = entries[0]
+        assert attrs["generation"] == 2 and attrs["collected"] >= 0
+        assert lo <= t0 <= t1
+        # a tick id of its own: it joins no tick
+        with tr.phase("tick") as after:
+            pass
+        assert tick < after.tick
+
+    def test_the_ring_holds_a_window_of_phases(self, phase_ring):
+        """25,000 phases, more than the 16,384 the ring once held: a
+        window at the fastest tick writes some 80,000."""
+        lo = time.perf_counter()
+        for i in range(25_000):
+            with tr.phase("tick.decode", i):
+                pass
+        entries, wrapped = tr.phases_between(lo, time.perf_counter())
+        assert not wrapped and len(entries) == 25_000
